@@ -179,11 +179,10 @@ def _traced_defs(ctx: FileContext, extra_names: Iterable[str] = ()
       "shard_map is used only through the parallel/collectives.py shim",
       "the raw entry point moved (jax.experimental.shard_map -> "
       "jax.shard_map) and its replication flag was renamed (check_rep -> "
-      "check_vma) across the jax versions this code runs under; a direct "
-      "use works on ONE version and breaks on the next (ROADMAP 'jax "
-      "version skew'). Unlike the old regex lint, mentions in docstrings "
-      "and strings do not count — only real imports, attribute accesses, "
-      "and kwargs.")
+      "check_vma) across jax versions, and every body here needs the "
+      "check OFF; one wrapper is the one place that knows both. Mentions "
+      "in docstrings and strings do not count — only real imports, "
+      "attribute accesses, and kwargs.")
 def check_shard_map_shim(ctx: FileContext) -> List[Finding]:
     if ctx.relpath.endswith(SHARD_MAP_SHIM):
         return []
@@ -427,9 +426,8 @@ def check_axis_name_registry(ctx: FileContext) -> List[Finding]:
 # Rule 5: os._exit only in resilience/heartbeat.py
 # ---------------------------------------------------------------------------
 
-# The one sanctioned home of the abrupt-exit primitive (hard_exit): the
-# deathwatch abort (a clean teardown through a dead socket IS the hang
-# being escaped) and preemption's hard deadline route through it.
+# The one sanctioned home of the abrupt-exit primitive (hard_exit):
+# preemption's hard deadline routes through it.
 # Matched on exact trailing path COMPONENTS, not a string suffix — a
 # future `myresilience/heartbeat.py` must not inherit the exemption.
 OS_EXIT_HOME = ("resilience", "heartbeat.py")
@@ -437,14 +435,13 @@ OS_EXIT_HOME = ("resilience", "heartbeat.py")
 
 @rule("no-bare-os-exit", "ast",
       "os._exit appears only in resilience/heartbeat.py (hard_exit)",
-      "an abrupt exit while this process holds the server-side TPU grant "
-      "wedges the chip for every later process (observed live: a "
-      "claim-holder killed without teardown left the device pool stuck "
-      "for hours). The legitimate abrupt exits — the relay deathwatch, "
-      "preemption's zombie-prevention deadline — live behind "
+      "an abrupt exit while this process holds the chip skips the "
+      "runtime's release of it, and skips every flush and checkpoint "
+      "barrier the process owes. The one legitimate abrupt exit — "
+      "preemption's zombie-prevention deadline — lives behind "
       "resilience/heartbeat.py's hard_exit, which documents when an "
       "abrupt exit is allowed and what cleanup it owes first; a bare "
-      "os._exit anywhere else is a new stuck-grant hazard.")
+      "os._exit anywhere else is an unaccounted one.")
 def check_no_bare_os_exit(ctx: FileContext) -> List[Finding]:
     if tuple(ctx.relpath.replace("\\", "/").split("/")[-2:]) == OS_EXIT_HOME:
         return []
@@ -457,8 +454,8 @@ def check_no_bare_os_exit(ctx: FileContext) -> List[Finding]:
             if resolved == "os._exit":
                 out.append(Finding(
                     "no-bare-os-exit",
-                    "os._exit outside resilience/heartbeat.py — abrupt "
-                    "claim-holder death wedges the server-side TPU grant; "
+                    "os._exit outside resilience/heartbeat.py — an abrupt "
+                    "exit skips the chip's release and every owed flush; "
                     "use resilience.heartbeat.hard_exit (or the preemption "
                     "guard's deadline) so the exit is accounted for",
                     ctx.loc(node)))

@@ -1,7 +1,7 @@
 """Concurrency-discipline rules: the host-side control plane, linted.
 
 The serving/resilience/telemetry layers are thread-heavy by design (worker
-replicas, heartbeat relays, metrics servers), and the last two PRs each
+replicas, metrics servers), and the last two PRs each
 shipped a hand-found race fix. These rules turn the locking discipline
 into checked annotations instead of review folklore:
 

@@ -5,11 +5,10 @@ hook since ISSUE 12 — a zero-arg callable returning the fleet's current
 replica capacity — but until now nothing real was plugged into it. Two
 feeds live here:
 
-* :func:`heartbeat_capacity_probe` — capacity read off the relay/port
-  registry `resilience.heartbeat` already maintains: each registered
-  port vouches for an equal share of the fleet, so ``total * up_ports //
-  n_ports``. This is the CPU-mesh-honest probe: the registry is the one
-  liveness source bench, train, and the deathwatch already share.
+* :func:`heartbeat_capacity_probe` — capacity read off a list of TCP
+  ports (`resilience.heartbeat.registry_snapshot`): each listed port
+  vouches for an equal share of the fleet, so ``total * up_ports //
+  n_ports``.
 * :class:`FileCapacityFeed` — the documented interface stub for
   EXTERNAL feeds (GKE node-pool state, GCE preemption notices): any
   zero-arg callable returning an int is a valid probe, and the file
@@ -28,30 +27,28 @@ lose/sync/restore bookkeeping.
 from __future__ import annotations
 
 import os
-from typing import Callable, Optional, Sequence
+from typing import Callable, Sequence
 
 from ..resilience import heartbeat
 
 
-def heartbeat_capacity_probe(total: int,
-                             ports: Optional[Sequence[int]] = None,
+def heartbeat_capacity_probe(total: int, ports: Sequence[int],
                              timeout: float = 0.2) -> Callable[[], int]:
-    """A probe reading capacity off the heartbeat relay registry.
+    """A probe reading capacity off the liveness of ``ports``.
 
     ``total`` is the full-fleet replica count the watch was built with;
-    each registered port (default: `heartbeat.relay_ports`) vouches for
-    an equal share, so 2 of 3 ports up on an 8-replica fleet reads as
-    ``8 * 2 // 3 = 5``. With every port dark the probe reads 0 — the
-    watch's clamp and grow-threshold logic decide what to do with it.
+    each listed port vouches for an equal share, so 2 of 3 ports up on an
+    8-replica fleet reads as ``8 * 2 // 3 = 5``. With every port dark the
+    probe reads 0 — the watch's clamp and grow-threshold logic decide
+    what to do with it.
     """
     if total < 0:
         raise ValueError("total capacity must be >= 0")
-    fixed = list(ports) if ports is not None else None
+    plist = list(ports)
 
     def probe() -> int:
-        plist = fixed if fixed is not None else heartbeat.relay_ports()
         if not plist:
-            return total  # nothing registered: no evidence of loss
+            return total  # nothing listed: no evidence of loss
         snapshot = heartbeat.registry_snapshot(plist, timeout=timeout)
         up = sum(1 for alive in snapshot.values() if alive)
         return (total * up) // len(plist)

@@ -26,7 +26,6 @@ tiny images) run higher.
 from __future__ import annotations
 
 import math
-import os
 from typing import Any, Optional
 
 import jax
@@ -48,23 +47,21 @@ CHIP_PEAK_TFLOPS_BF16 = {
     "TPU v6e": 918.0,
 }
 
-PEAK_ENV_VAR = "DPT_CHIP_PEAK_TFLOPS"
-
-
 def chip_peak_tflops(device: Optional[jax.Device] = None) -> Optional[float]:
-    """Per-device peak dense bf16 TFLOP/s, or None when unknown.
-
-    ``DPT_CHIP_PEAK_TFLOPS`` overrides the lookup (new chip generations land
-    before this table learns about them).
-    """
-    override = os.environ.get(PEAK_ENV_VAR)
-    if override:
-        return float(override)
+    """Per-device peak dense bf16 TFLOP/s. None only for a non-TPU device
+    (the CPU test backend, where MFU means nothing); a TPU whose
+    ``device_kind`` is missing from the table raises — an unknown chip is
+    an error to fix in the table, never an MFU silently left out."""
     if device is None:
         device = jax.devices()[0]
     if device.platform != "tpu":
-        return None  # CPU/GPU test backends: MFU not meaningful here
-    return CHIP_PEAK_TFLOPS_BF16.get(device.device_kind)
+        return None
+    if device.device_kind not in CHIP_PEAK_TFLOPS_BF16:
+        raise KeyError(
+            f"no peak TFLOP/s on record for TPU device_kind "
+            f"{device.device_kind!r}; add it to CHIP_PEAK_TFLOPS_BF16 "
+            f"(known: {sorted(CHIP_PEAK_TFLOPS_BF16)})")
+    return CHIP_PEAK_TFLOPS_BF16[device.device_kind]
 
 
 def xla_flops_per_step(compiled) -> Optional[float]:
@@ -74,8 +71,6 @@ def xla_flops_per_step(compiled) -> Optional[float]:
         cost = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(cost, (list, tuple)):  # older jax returned [dict]
-        cost = cost[0] if cost else {}
     flops = cost.get("flops")
     if flops is None or flops <= 0:
         return None
@@ -123,6 +118,17 @@ def _jaxpr_flops(jaxpr) -> float:
         elif name == "while":
             # trip count unknown statically; count one iteration (lower bound)
             total += _jaxpr_flops(eqn.params["body_jaxpr"].jaxpr)
+        elif name == "shard_map":
+            # the body is ONE shard's program: the whole call costs it once
+            # per shard, i.e. times the size of every mesh axis that splits
+            # an operand (axes no operand is split over only replicate the
+            # same work, which a model-FLOPs count takes once)
+            split = {a for spec in eqn.params["in_specs"] for entry in spec
+                     if entry is not None
+                     for a in (entry if isinstance(entry, tuple)
+                               else (entry,))}
+            shards = math.prod(eqn.params["mesh"].shape[a] for a in split)
+            total += shards * _jaxpr_flops(eqn.params["jaxpr"])
         elif name == "pallas_call":
             # Prefer the kernel author's exact CostEstimate: our flash
             # kernels pass causal-aware counts (live diagonal blocks only).

@@ -55,16 +55,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..runtime import honor_platform_env
-
-honor_platform_env()  # allow JAX_PLATFORMS=cpu virtual-mesh runs
-
-
+from ..runtime import require_backend
 # One measurement harness shared with bench.py (experiments/harness.py) so
 # the headline bench and these tables stay comparable — including the
 # image-vs-LM dispatch (harness.build_trainer / make_synth_batch), so the
 # same --model string measures the same config everywhere.
-from .harness import build_trainer, is_lm_model, make_synth_batch, timed_steps  # noqa: E402
+from .harness import build_trainer, is_lm_model, make_synth_batch, timed_steps
 
 # CI smoke runs shrink LM architectures (full-size bert/gpt2 on the CPU test
 # mesh costs minutes per build); real measurements never set this.
@@ -725,7 +721,7 @@ def main(argv=None):
           "tp": run_tp, "pipeline": run_pipeline}[args.experiment]
     print(f"# {args.experiment} — {args.model}, "
           f"{'bf16' if args.bf16 else 'fp32'}, "
-          f"{len(jax.devices())} device(s) [{jax.default_backend()}]\n")
+          f"{len(jax.devices())} device(s) [{require_backend()}]\n")
     rows = fn(args)
     _emit(rows, args.csv)
 

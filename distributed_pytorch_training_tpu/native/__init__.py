@@ -3,9 +3,11 @@
 The native library is the TPU-side stand-in for the C++ machinery the
 reference gets from its dependency stack — DataLoader worker prefetch and
 image-op decode (/root/reference/train_ddp.py:131-148; SURVEY.md §2b). It is
-built lazily with g++ on first use and cached next to the sources; every
-entry point has a NumPy fallback so the framework keeps working where no
-toolchain exists (`is_available()` reports which path is live).
+built lazily with g++ on first use and cached next to the sources under a
+file name that carries the source's hash (so a stale or foreign ``.so`` left
+in ``lib/`` by a copy of the tree is never loaded); every entry point has a
+NumPy fallback so the framework keeps working where no toolchain exists.
+`is_available()` reports which path is live, and the first load logs it.
 
 Set ``DPT_TPU_NATIVE=0`` to force the NumPy fallbacks (used by parity tests).
 """
@@ -13,6 +15,8 @@ Set ``DPT_TPU_NATIVE=0`` to force the NumPy fallbacks (used by parity tests).
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import tempfile
@@ -24,18 +28,27 @@ import numpy as np
 
 _SRC = Path(__file__).parent / "src" / "dpt_native.cpp"
 _LIB_DIR = Path(__file__).parent / "lib"
-_LIB = _LIB_DIR / "libdpt_native.so"
+
+logger = logging.getLogger(__name__)
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
-    """Compile the shared library if missing or older than its source."""
+def lib_path() -> Path:
+    """``lib/libdpt_native-<sha256(source)[:16]>.so``: the name IS the
+    staleness check — mtimes mean nothing after a tree copy."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _LIB_DIR / f"libdpt_native-{digest}.so"
+
+
+def _build(lib: Path) -> Optional[str]:
+    """Compile the shared library unless the file for this source already
+    exists. Returns None on success, else why it could not be built."""
+    if lib.exists():
+        return None
     try:
-        if _LIB.exists() and _LIB.stat().st_mtime >= _SRC.stat().st_mtime:
-            return True
         _LIB_DIR.mkdir(parents=True, exist_ok=True)
         # Build to a temp name, then atomic-rename: concurrent processes
         # (multi-host launch) race benignly.
@@ -49,13 +62,13 @@ def _build() -> bool:
             res = subprocess.run(cmd, capture_output=True, text=True,
                                  timeout=120)
             if res.returncode != 0:
-                return False
-            os.replace(tmp, _LIB)
-            return True
+                return f"g++ rc={res.returncode}: {res.stderr.strip()[-200:]}"
+            os.replace(tmp, lib)
+            return None
         finally:
             Path(tmp).unlink(missing_ok=True)
-    except (OSError, subprocess.SubprocessError):
-        return False
+    except (OSError, subprocess.SubprocessError) as e:
+        return f"{type(e).__name__}: {e}"
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -69,13 +82,21 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         if os.environ.get("DPT_TPU_NATIVE", "1") == "0":
+            logger.info("native data library: off (DPT_TPU_NATIVE=0), "
+                        "NumPy path")
             return None
-        if not _build():
+        path = lib_path()
+        problem = _build(path)
+        if problem is None:
+            try:
+                lib = ctypes.CDLL(str(path))
+            except OSError as e:
+                problem = f"dlopen failed: {e}"
+        if problem is not None:
+            logger.warning("native data library unavailable (%s) — NumPy "
+                           "path", problem)
             return None
-        try:
-            lib = ctypes.CDLL(str(_LIB))
-        except OSError:
-            return None
+        logger.info("native data library: %s", path.name)
 
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i32p = ctypes.POINTER(ctypes.c_int32)
@@ -99,6 +120,12 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def is_available() -> bool:
     return _load() is not None
+
+
+def describe() -> str:
+    """Which host data path is live, for banners: the built library's file
+    name, or ``numpy``."""
+    return lib_path().name if is_available() else "numpy"
 
 
 def _ptr(a: np.ndarray, ctype):

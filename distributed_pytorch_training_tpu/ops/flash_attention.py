@@ -525,7 +525,8 @@ def _as_kv_valid(mask, batch: int, sk: int) -> Optional[jnp.ndarray]:
     return None
 
 
-def make_flash_attention_fn(causal: bool, block_q: int = 512, block_k: int = 512):
+def make_flash_attention_fn(causal: bool, block_q: int = 512,
+                            block_k: int = 512, mesh=None):
     """Adapter matching models.layers' `attention_fn(q, k, v, mask, dtype)`.
 
     Causal structure is handled inside the kernel via block skipping (faster
@@ -534,7 +535,23 @@ def make_flash_attention_fn(causal: bool, block_q: int = 512, block_k: int = 512
     so real padded batches (BERT MLM) keep the flash path. Any other mask
     shape falls back to the XLA einsum path rather than erroring: the fast
     path must cover all data, and general (Sq, Sk)-structured masks have no
-    blockwise formulation here."""
+    blockwise formulation here.
+
+    ``mesh``: GSPMD cannot partition a Mosaic kernel ("Mosaic kernels
+    cannot be automatically partitioned" — a lowering error on any
+    multi-device TPU program), so on a mesh of more than one device the
+    call runs per shard inside a `shard_map` over the layout GSPMD already
+    gives attention operands: batch over the batch axes, heads over
+    ``model``. Traced from inside an explicit shard_map step (every axis
+    already manual, operands already per-shard) it is the plain call."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.collectives import shard_map
+    from ..parallel.mesh import BATCH_AXES, MODEL, batch_shard_count
+
+    def kernel(q, k, v, kv_valid):
+        return flash_attention(q, k, v, causal, None, block_q, block_k,
+                               kv_valid)
 
     def attention_fn(q, k, v, mask=None, dtype=jnp.float32):
         kv_valid = _as_kv_valid(mask, q.shape[0], k.shape[1])
@@ -546,7 +563,20 @@ def make_flash_attention_fn(causal: bool, block_q: int = 512, block_k: int = 512
                                        bool))[None, None]
                 mask = mask.astype(bool) & cm
             return dot_product_attention(q, k, v, mask=mask, dtype=dtype)
-        return flash_attention(q, k, v, causal, None, block_q, block_k,
-                               kv_valid).astype(dtype)
+        if mesh is None or mesh.size == 1 \
+                or jax.sharding.get_abstract_mesh().manual_axes:
+            return kernel(q, k, v, kv_valid).astype(dtype)
+        # an axis that does not divide its dimension (the B=1 init trace)
+        # stays unsplit: every device then runs the whole (tiny) call
+        b_axes = BATCH_AXES if q.shape[0] % batch_shard_count(mesh) == 0 \
+            else None
+        h_axis = MODEL if q.shape[2] % mesh.shape[MODEL] == 0 else None
+        spec = P(b_axes, None, h_axis, None)
+        masks = () if kv_valid is None else (kv_valid,)
+        return shard_map(
+            lambda q, k, v, kv_valid=None: kernel(q, k, v, kv_valid),
+            mesh=mesh,
+            in_specs=(spec, spec, spec) + (P(b_axes, None),) * len(masks),
+            out_specs=spec)(q, k, v, *masks).astype(dtype)
 
     return attention_fn

@@ -109,13 +109,34 @@ def _fit_block(s: int, n: int = 1) -> Tuple[int, int]:
     return block, -(-s // block) * block
 
 
+# A whole-rows tile above this is split along the row axis as well: the
+# int8 KV-page scatter quantizes (layers * positions * heads, head_dim)
+# matrices — tens of thousands of SHORT rows — and an un-split (n, 128)
+# tile of that outgrows VMEM (Mosaic: "ran out of memory in memory space
+# vmem" from n ~ 36k on v5e). Wire buckets (n <= the replica count) never
+# reach it, so their compiled programs are what they were.
+_ROW_SPLIT_BYTES = 4 * 1024 * 1024
+
+
+def _fit_rows(n: int, block_c: int) -> Tuple[int, int]:
+    """(block_r, padded_n): all rows in one tile while the fp32 tile stays
+    under `_ROW_SPLIT_BYTES`; otherwise ~`_TILE_BUDGET_BYTES` row blocks,
+    a multiple of 32 rows (the int8 sublane tile). Rows are independent
+    (one scale per row), so splitting them changes no value; the wrappers
+    zero-pad to ``padded_n`` and slice the padding rows off."""
+    if n * block_c * 4 <= _ROW_SPLIT_BYTES:
+        return n, n
+    block_r = max(32, _TILE_BUDGET_BYTES // (block_c * 4) // 32 * 32)
+    return block_r, -(-n // block_r) * block_r
+
+
 # ---------------------------------------------------------------------------
 # fused quantize: running absmax pass + scale/round/clip pass, one launch
 # ---------------------------------------------------------------------------
 
 
 def _quantize_kernel(x_ref, q_ref, s_ref, amax_scr, *, nblocks: int):
-    phase, j = pl.program_id(0), pl.program_id(1)
+    phase, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when((phase == 0) & (j == 0))
     def _init():
@@ -152,30 +173,37 @@ def quantize_int8_rows_fused(rows: jnp.ndarray
     reference) — same grid, same scale arithmetic, same round/clip."""
     n, s = rows.shape
     block_c, padded = _fit_block(s, n)
+    block_r, padded_n = _fit_rows(n, block_c)
     nblocks = padded // block_c
-    x = rows if padded == s else jnp.pad(rows, ((0, 0), (0, padded - s)))
+    x = rows if (padded, padded_n) == (s, n) else jnp.pad(
+        rows, ((0, padded_n - n), (0, padded - s)))
     q, scales = pl.pallas_call(
         functools.partial(_quantize_kernel, nblocks=nblocks),
-        grid=(2, nblocks),
-        in_specs=[pl.BlockSpec((n, block_c), lambda phase, j: (0, j))],
+        # row blocks outermost: each runs its own two phases over the lane
+        # blocks, with the (block_r, 1) running-absmax scratch re-zeroed at
+        # the start of its phase 0
+        grid=(padded_n // block_r, 2, nblocks),
+        in_specs=[pl.BlockSpec((block_r, block_c),
+                               lambda i, phase, j: (i, j))],
         out_shape=[
-            jax.ShapeDtypeStruct((n, padded), jnp.int8),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((padded_n, padded), jnp.int8),
+            jax.ShapeDtypeStruct((padded_n, 1), jnp.float32),
         ],
         out_specs=[
-            pl.BlockSpec((n, block_c), lambda phase, j: (0, j)),
-            pl.BlockSpec((n, 1), lambda phase, j: (0, 0)),
+            pl.BlockSpec((block_r, block_c), lambda i, phase, j: (i, j)),
+            pl.BlockSpec((block_r, 1), lambda i, phase, j: (i, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((n, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_r, 1), jnp.float32)],
         cost_estimate=pl.CostEstimate(
             # two streaming passes (abs/max + div/round/clip), ~4 vector
             # ops per element; no transcendentals, no MXU
-            flops=8 * n * padded, transcendentals=0,
-            bytes_accessed=2 * n * padded * 4 + n * padded + n * 4),
+            flops=8 * padded_n * padded, transcendentals=0,
+            bytes_accessed=(2 * padded_n * padded * 4 + padded_n * padded
+                            + padded_n * 4)),
         interpret=_interpret(),
         name="fused_quantize_int8_rows",
     )(x)
-    return q[:, :s], scales[:, 0]
+    return q[:n, :s], scales[:n, 0]
 
 
 # ---------------------------------------------------------------------------

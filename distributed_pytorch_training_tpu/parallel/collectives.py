@@ -30,7 +30,6 @@ Two distinct layers, never to be confused:
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Any, Callable, Optional, Sequence, Tuple, Union
 
 import jax
@@ -41,29 +40,14 @@ from jax.sharding import Mesh
 
 AxisName = Union[str, Sequence[str]]
 
-try:  # jax >= 0.6 exposes shard_map at the top level
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # older jax keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
 def shard_map(f: Callable, mesh: Mesh, in_specs: Any, out_specs: Any):
-    """`jax.shard_map` across jax versions, with replication checking off.
-
-    One compat point for every shard_map in the repo: the entry point moved
-    (experimental -> top level) and the check flag was renamed
-    (``check_rep`` -> ``check_vma``) across the jax versions this code runs
-    under. Checking is disabled because the bodies here use collectives
-    whose replication the checker cannot always prove (psum_scatter /
-    all_gather chains)."""
-    params = inspect.signature(_shard_map_impl).parameters
-    kwargs = {}
-    if "check_vma" in params:
-        kwargs["check_vma"] = False
-    elif "check_rep" in params:
-        kwargs["check_rep"] = False
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+    """`jax.shard_map` with replication checking off — the one entry point
+    for every shard_map in the repo (the ``shard-map-shim-only`` rule).
+    Checking is disabled because the bodies here use collectives whose
+    replication the checker cannot always prove (psum_scatter / all_gather
+    chains)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _axes_present(axis_name: AxisName, mesh: Optional[Mesh]) -> bool:
@@ -143,10 +127,7 @@ def ppermute_ring(x: Any, axis_name: str, *, shift: int = 1) -> Any:
     attention (KV blocks circulate over the ICI ring). No NCCL analogue in the
     reference (max sequence there is a 32x32 image); this is the long-context
     primitive SURVEY.md §5 requires."""
-    if hasattr(lax, "axis_size"):
-        n = lax.axis_size(axis_name)
-    else:  # older jax: psum of a Python literal constant-folds to the size
-        n = int(lax.psum(1, axis_name))
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 

@@ -239,6 +239,11 @@ def build_mesh(
     sizes = spec.resolved(len(devices))
     shape = tuple(sizes[a] for a in AXIS_ORDER)
 
+    # A layout the topology helpers refuse is an error on a TPU (a plain
+    # reshape there would put DCN- or ICI-crossing collectives on the wrong
+    # axes without saying so); the reshape is for CPU test meshes, whose
+    # devices have no topology to respect.
+    on_tpu = devices[0].platform == "tpu"
     n_slices = _slice_count(devices)
     if n_slices > 1:
         per, dcn = dcn_factors(sizes, n_slices)  # raises on un-splittable
@@ -249,15 +254,17 @@ def build_mesh(
                 devices=list(devices))
             return Mesh(_unwrap_devices(dev_array), AXIS_ORDER)
         except (ValueError, AssertionError, NotImplementedError) as e:
+            if on_tpu:
+                raise
             logging.getLogger(__name__).warning(
                 "hybrid mesh construction failed (%s); falling back to the "
-                "single-slice layout — DCN-crossing collectives may land on "
-                "model/seq axes", e)
+                "single-slice layout (CPU test mesh)", e)
 
     try:
         dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
     except (ValueError, AssertionError, NotImplementedError):
-        # Non-TPU backends (CPU test meshes) or odd shapes: plain reshape.
+        if on_tpu:
+            raise
         dev_array = np.asarray(list(devices)).reshape(shape)
     return Mesh(_unwrap_devices(dev_array), AXIS_ORDER)
 

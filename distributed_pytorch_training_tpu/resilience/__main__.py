@@ -338,8 +338,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                       "crash@step=3,torn_ckpt@save=2,"
                       "crash_during_save@save=2,sigterm@step=6")
 
-    # The zero1/grad_sync trick reused: chaos runs on the 8-device virtual
-    # CPU mesh unless a real accelerator is already up.
+    # The zero1/grad_sync trick reused: a CPU run asked for by name
+    # (JAX_PLATFORMS=cpu) gets the 8-device virtual mesh.
     from ..analysis.__main__ import _ensure_test_mesh
     _ensure_test_mesh()
 
@@ -402,7 +402,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     # full recompile of the resized step.
     from ..runtime import enable_persistent_compile_cache
 
-    enable_persistent_compile_cache(Path(ckpt_dir) / ".jax_cache")
+    enable_persistent_compile_cache()
     # async saves ON (the production default): the schedule's
     # crash_during_save fault dies on the background writer and must
     # surface at the next save/wait barrier inside the recovery scope.

@@ -1,8 +1,7 @@
-"""Capacity watcher: the grow-side analog of the Deathwatch (ISSUE 12).
+"""Capacity watcher: the grow side of elasticity (ISSUE 12).
 
-The Deathwatch (heartbeat.py) notices capacity LEAVING — a dead relay, a
-lost replica — and turns it into a prompt, recoverable exit. Nothing in
-the stack noticed capacity COMING BACK: a run that shrank 8 -> 4 after a
+A replica death shrinks the run (resilience/elastic.py); nothing in the
+stack noticed capacity COMING BACK: a run that shrank 8 -> 4 after a
 preemption stayed shrunk forever, paying double per-device batch (and the
 matching step-time) long after the preempted chips returned. The
 :class:`CapacityWatch` closes that half:

@@ -12,8 +12,8 @@ count*, not a shrunken in-process mesh. This module is the external half:
   child flushes carries them in its cause, telemetry/flight.py);
 * **watch the exit code**: rc=0 with the target step reached is
   completion; rc=0 short of it is a drained preemption (train.py's
-  SIGTERM drain checkpoints and exits clean); rc=70 is the Deathwatch
-  contract (heartbeat.py); anything else is a crash. Progress is probed
+  SIGTERM drain checkpoints and exits clean); anything else is a crash.
+  Progress is probed
   from the checkpoint directory's integrity MANIFESTS alone
   (:func:`checkpoint_progress`) — the orchestrator is jax/orbax-free by
   design, it must never initialize a backend;
@@ -59,7 +59,6 @@ from ..telemetry.flight import FLEET_GENERATION_ENV, FLEET_RANK_ENV
 from ..telemetry.metrics_http import METRICS_PORT_ENV
 from ..telemetry.recorder import stream_filename
 from .elastic import plan_elastic_world
-from .heartbeat import DEATHWATCH_EXIT_CODE
 
 # FLEET_GENERATION_ENV / FLEET_RANK_ENV are telemetry/flight.py's (one
 # definition: the reader of the stamp owns the names) — re-exported here
@@ -133,7 +132,7 @@ class FleetLaunch:
     peer_rcs: List[int] = dataclasses.field(default_factory=list)
     rc: Optional[int] = None
     seconds: float = 0.0
-    outcome: str = "launched"   # completed | drained | crashed | relay_death
+    outcome: str = "launched"   # completed | drained | crashed
     step_after: int = -1
     log_path: str = ""
     # live observability (ISSUE 14): the largest step seen in the child's
@@ -312,8 +311,6 @@ class FleetOrchestrator:
         if rc == 0:
             return ("completed" if step_after >= self.target_step
                     else "drained")
-        if rc == DEATHWATCH_EXIT_CODE:
-            return "relay_death"
         return "crashed"
 
     def _scrape_metrics(self, port: int) -> Optional[str]:
@@ -426,6 +423,10 @@ class FleetOrchestrator:
     def run(self) -> FleetReport:
         report = FleetReport(target_step=self.target_step)
         self.log_dir.mkdir(parents=True, exist_ok=True)
+        if self.set_child_devices:
+            self.log("fleet: CPU harness — every child is pinned to a "
+                     "virtual CPU mesh (JAX_PLATFORMS=cpu); no child "
+                     "touches an accelerator")
         federation = None
         if self.federation_port and self.metrics_port:
             from ..telemetry.metrics_http import FederationServer
@@ -647,6 +648,10 @@ class ServingFleet:
 
     def start(self) -> None:
         self.log_dir.mkdir(parents=True, exist_ok=True)
+        if self.set_child_devices:
+            self.log("serving fleet: CPU harness — every replica is pinned "
+                     "to a virtual CPU mesh (JAX_PLATFORMS=cpu); one chip "
+                     "per replica is not built")
         if self.federation_port and self.metrics_ports:
             from ..telemetry.metrics_http import FederationServer
 
@@ -879,7 +884,7 @@ def _compare_final_checkpoints(real_dir: str, control_dir: str,
 def check_fleet_flights(flight_dir, launches: List[dict],
                         ignore=None) -> dict:
     """One flight per ABNORMAL child exit, attributable by generation:
-    a crashed/relay-death child must leave exactly one flight stamped
+    a crashed child must leave exactly one flight stamped
     ``[fleet gen=G ...]`` whose cause matches a crash; a drained child
     exactly one whose cause names the preemption. A completed child must
     leave none. ``ignore`` holds flight paths that existed BEFORE this
@@ -903,7 +908,7 @@ def check_fleet_flights(flight_dir, launches: List[dict],
         gen = str(launch["generation"])
         mine = [f for f in flights if f["generation"] == gen]
         outcome = launch["outcome"]
-        if outcome in ("crashed", "relay_death", "drained"):
+        if outcome in ("crashed", "drained"):
             if len(mine) != 1:
                 problems.append(
                     f"generation {gen} ({outcome}) left {len(mine)} "

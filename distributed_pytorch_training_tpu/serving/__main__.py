@@ -43,10 +43,15 @@ Commands:
       --federation-port serves the ONE merged /metrics dashboard
       (resilience/fleet.py::ServingFleet).
 
-Health/drain: the resilience Deathwatch watches the relay ports exactly as
-train.py's does (opt-in via DPT_RELAY_PORTS); SIGTERM closes the queue,
-DRAINS it (accepted requests complete, new ones are refused), flushes a
-telemetry flight, and exits 0. Any abnormal exit flushes a flight too.
+Drain: SIGTERM closes the queue, DRAINS it (accepted requests complete, new
+ones are refused), flushes a telemetry flight, and exits 0. Any abnormal
+exit flushes a flight too.
+
+Platform: like train.py, a CPU run must be asked for by name
+(JAX_PLATFORMS=cpu, which also gets the 8-device virtual mesh); otherwise
+the backend must be a TPU or the CLI raises (runtime.require_backend).
+`fleet` children are pinned to virtual CPU meshes by the launcher
+(resilience/fleet.py) — a CPU harness, one chip per replica is not built.
 
 Checkpoint templates: orbax restores against the training run's full
 TrainState structure, so a checkpoint written under --zero1 /
@@ -183,9 +188,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = p.parse_args(argv)
     buckets = _parse_buckets(args.buckets)
 
-    # Standalone CPU runs get the 8-device virtual mesh (the analysis CLI's
-    # recipe — serving shares it so `serving smoke` exercises real
-    # cross-device batch sharding with no TPU).
+    # A CPU run asked for by name gets the 8-device virtual mesh (the
+    # analysis CLI's recipe — `serving smoke` then exercises real
+    # cross-device batch sharding with no TPU); anything else must be a TPU.
     from ..analysis.__main__ import _ensure_test_mesh
 
     _ensure_test_mesh()
@@ -193,8 +198,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     import jax
 
     from .. import telemetry
-    from ..resilience.heartbeat import Deathwatch
+    from ..runtime import require_backend
     from ..utils.logging import log_main
+
+    backend = require_backend()
+    log_main(f"serving: backend={backend}, {len(jax.devices())}x "
+             f"{jax.devices()[0].device_kind}")
 
     tele_rank = telemetry.rank_identity(jax.process_index())
     if not args.no_telemetry and telemetry.should_stream(tele_rank):
@@ -216,13 +225,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         # None on a bind failure (stderr-noted): the live surface never
         # takes the serving process down. backend stamps dpt_build_info
         # (the federated-scrape identity satellite, ISSUE 15).
-        import jax
-
         if telemetry.start_metrics_server(
                 metrics_port, telemetry.get(),
-                backend=jax.default_backend()) is not None:
+                backend=backend) is not None:
             log_main(f"serving: /metrics + /healthz on :{metrics_port}")
-    Deathwatch.arm(log=log_main)
 
     try:
         return _run(args, buckets)
@@ -261,20 +267,14 @@ def _run(args, buckets) -> int:
     train_config = TrainConfig(
         seed=0, zero1=args.zero1, fsdp_explicit=args.fsdp_explicit,
         wire_dtype=args.wire_dtype, bucket_cap_mb=args.bucket_cap_mb)
-    # Warm-restart compilation cache, keyed by (topology, config): a
-    # restarted or autoscaled serving replica re-AOT-compiles its whole
-    # bucket ladder — with the persistent cache on, those compiles load
-    # from disk instead (the engine's per-program `compile` telemetry
-    # spans are the cold-vs-warm instrument). DPT_COMPILE_CACHE tri-state;
-    # "auto" refuses XLA:CPU (unsafe reloads — runtime.dist docstring).
-    from ..runtime import compile_cache_dir, enable_persistent_compile_cache
+    # Warm-restart compilation cache: a restarted or autoscaled serving
+    # replica re-AOT-compiles its whole bucket ladder — with the persistent
+    # cache on, those compiles load from disk instead (the engine's
+    # per-program `compile` telemetry spans are the cold-vs-warm
+    # instrument). runtime.dist owns where it lives; "auto" refuses XLA:CPU.
+    from ..runtime import enable_persistent_compile_cache
 
-    enable_persistent_compile_cache(compile_cache_dir(
-        Path(args.output_dir) / ".jax_cache",
-        topology=f"{jax.default_backend()}-{len(jax.devices())}dev"
-                 + (f"-{args.mesh.replace('=', '').replace(',', '-')}"
-                    if args.mesh else ""),
-        config_tag=f"{args.model}-{args.serve_dtype}-rows{args.rows}"))
+    enable_persistent_compile_cache()
 
     if args.command == "serve":
         return _serve(args, buckets, overrides, train_config)

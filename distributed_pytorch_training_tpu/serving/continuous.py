@@ -135,6 +135,15 @@ class SlotEngine(InferenceEngine):
         # back (tiny: one (L, rows, H, D) per k/v per token).
         n_shards = batch_shard_count(mesh)
         self._row_sharded = n_shards > 1 and config.rows % n_shards == 0
+        # The int8 page codec's kernel choice, settled here where the mesh
+        # is known: the engine's programs are GSPMD programs, and GSPMD
+        # cannot partition a Mosaic kernel (a lowering error on any
+        # multi-device TPU program), so on a mesh of more than one device
+        # "auto" means the XLA-composed codec — same grid, same page bytes.
+        # An explicit True is kept and fails loudly at lowering there.
+        self._fused_quantize = (
+            False if config.fused_quantize is None and mesh.size > 1
+            else config.fused_quantize)
         self.reset_state()
 
     def _validate_rows(self, n_shards: int) -> None:
@@ -251,7 +260,7 @@ class SlotEngine(InferenceEngine):
             v_seqs = jnp.stack([c[1][0] for c in cache])
             new_pool = scatter_paged_prefill(pool, page_row, k_seqs,
                                              v_seqs, length,
-                                             fused=cfg.fused_quantize)
+                                             fused=self._fused_quantize)
             out_row = jnp.zeros((cfg.max_new_tokens,), jnp.int32)
             out_row = out_row.at[0].set(t0)
             control = dict(control)
@@ -271,7 +280,7 @@ class SlotEngine(InferenceEngine):
 
     def _make_paged_decode(self) -> Callable:
         rows = self.config.rows
-        fused = self.config.fused_quantize
+        fused = self._fused_quantize
 
         def decode(served, pool, control, page_table):
             params = self._dequant(served)
@@ -418,7 +427,7 @@ class SlotEngine(InferenceEngine):
         the in-window causal prefix, so its logits (and written k/v) are
         bitwise the full prefill's rows (the window parity pin)."""
         cfg: PagedServeConfig = self.config
-        fused = cfg.fused_quantize
+        fused = self._fused_quantize
 
         def resume(served, pool, control, page_table, ids, start, length,
                    slot, want, key, temp, top_p):

@@ -10,9 +10,9 @@ instrument feeds ONE stream:
   (``telemetry_rank0.jsonl``, fsync'd on a cadence) AND kept in a bounded
   in-memory ring buffer;
 * the **flight recorder** (:mod:`.flight`) — on any abnormal exit
-  (Deathwatch lethal probe, Supervisor retry/abort, chaos crash/sigterm,
-  unhandled exception) the ring's last N events + the exit cause are
-  flushed to ``flight_<ts>.json``, so every rc=70 / rc!=0 leaves a
+  (Supervisor retry/abort, chaos crash/sigterm, unhandled exception)
+  the ring's last N events + the exit cause are flushed to
+  ``flight_<ts>.json``, so every rc!=0 leaves a
   postmortem artifact even when the JSONL's tail was lost;
 * the **anomaly watchdog** (:mod:`.watchdog`) — non-finite loss,
   step-time spikes vs a rolling median, loader-stall detection, each an

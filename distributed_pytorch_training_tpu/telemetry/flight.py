@@ -3,14 +3,12 @@
 On any abnormal exit the ring buffer's last N events + the exit cause are
 written to ``flight_<ts>_<seq>.json`` in the telemetry directory —
 explicitly fsync'd, so it survives the process dying immediately after.
-Every rc=70 / rc!=0 path in the stack flushes one:
+Every rc!=0 path in the stack flushes one:
 
-* ``resilience/heartbeat.py`` — the Deathwatch lethal probe, right before
-  ``hard_exit`` (cause names the dead relay ports);
 * ``resilience/supervisor.py`` — every restart (cause = the caught step/
   save failure, so an injected ``crash@step=3`` reads back verbatim),
-  torn-checkpoint skips, the preemption (SIGTERM) drain, relay-death
-  abort, and retry exhaustion;
+  torn-checkpoint skips, the preemption (SIGTERM) drain, and retry
+  exhaustion;
 * ``train.py`` — unhandled exceptions, via the explicit ``except
   BaseException`` clause in ``main()`` (NOT :func:`install_excepthook`:
   the flush must run BEFORE ``finally: telemetry.reset()`` closes the

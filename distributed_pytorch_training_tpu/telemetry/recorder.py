@@ -27,8 +27,7 @@ OS-buffered lines, and the flight recorder's explicitly-fsync'd
 ``flight_*.json`` carries the ring's tail regardless.
 
 This module imports neither jax nor anything from the package that does:
-arming telemetry must never initialize a backend (the heartbeat
-constraint), and the CLI must read streams on machines with no accelerator
+arming telemetry must never initialize a backend, and the CLI must read streams on machines with no accelerator
 stack at all. Process-0 gating is therefore the CALLER's job — train.py
 gates on :func:`should_stream` (rank 0 always; other ranks only under the
 ``--telemetry-all-ranks`` / ``DPT_TELEMETRY_ALL_RANKS`` opt-in, so the
@@ -201,9 +200,8 @@ class Recorder:
     """Append-only JSONL + bounded ring buffer of typed events.
 
     ``path=None`` keeps a ring-only recorder (tests; flight-only use).
-    All emit paths are thread-safe: the checkpoint writer thread, the
-    loader producer thread, and the deathwatch thread all emit into the
-    same stream as the main loop.
+    All emit paths are thread-safe: the checkpoint writer thread and the
+    loader producer thread emit into the same stream as the main loop.
     """
 
     def __init__(self, path: Optional[str] = None, ring_size: int = 512,

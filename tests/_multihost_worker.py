@@ -7,7 +7,6 @@ Every assertion here runs in BOTH processes; any failure exits non-zero and
 the parent test fails.
 """
 
-import os
 import sys
 from pathlib import Path
 
@@ -15,16 +14,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    # Older jax: the option doesn't exist; fall back to the XLA flag (must
-    # land before the backend initializes).
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=2").strip()
+jax.config.update("jax_num_cpu_devices", 2)  # parent sets JAX_PLATFORMS=cpu
 
 import flax.linen as nn
 import jax.numpy as jnp

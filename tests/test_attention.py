@@ -140,6 +140,56 @@ class TestFlashAttention:
         np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
 
 
+    def test_adapter_on_a_mesh_runs_per_shard_and_matches(self, devices):
+        """GSPMD cannot partition a Mosaic kernel (a lowering error on any
+        multi-device TPU program), so given a mesh the adapter runs the
+        kernel per shard inside a shard_map — batch over the batch axes,
+        heads over ``model`` — under the implicit (jit) step, and stays the
+        plain call when traced from inside an explicit shard_map step.
+        Values and gradients match the un-wrapped call; the kv_valid form
+        rides the same wrapper."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from distributed_pytorch_training_tpu.parallel.collectives import (
+            shard_map,
+        )
+        from distributed_pytorch_training_tpu.parallel.mesh import BATCH_AXES
+
+        mesh = build_mesh(MeshSpec(data=4, model=2), devices=devices)
+        plain = make_flash_attention_fn(causal=True)
+        meshed = make_flash_attention_fn(causal=True, mesh=mesh)
+        q, k, v = _rand_qkv(b=8, s=64)
+
+        def loss(fn):
+            return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+        jaxpr = str(jax.make_jaxpr(meshed)(q, k, v))
+        assert "shard_map" in jaxpr
+        got = jax.jit(jax.grad(loss(meshed), argnums=(0, 1, 2)))(q, k, v)
+        want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=2e-5)
+        assert got[0].sharding.is_equivalent_to(
+            NamedSharding(mesh, P(BATCH_AXES, None, "model", None)), 4)
+
+        # key-padding mask + a batch the shards do not divide (init's B=1)
+        mask = jnp.asarray(np.arange(64)[None, :] < 50)[:, None, None, :]
+        np.testing.assert_allclose(
+            np.asarray(meshed(q[:1], k[:1], v[:1], mask=mask)),
+            np.asarray(plain(q[:1], k[:1], v[:1], mask=mask)),
+            rtol=2e-5, atol=2e-5)
+
+        # already inside a manual region: no nested shard_map
+        spec = P(BATCH_AXES, None, "model", None)
+        inner = shard_map(meshed, mesh=mesh, in_specs=(spec, spec, spec),
+                          out_specs=spec)
+        assert str(jax.make_jaxpr(inner)(q, k, v)).count("shard_map") == 1
+        np.testing.assert_allclose(np.asarray(inner(q, k, v)),
+                                   np.asarray(plain(q, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+
+
 class TestFlashPaddingMask:
     """Key-padding masks ride the Pallas kernels (VERDICT r3 #2): BERT on
     real padded batches must keep the flash path, gradients included."""

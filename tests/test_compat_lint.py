@@ -1,10 +1,9 @@
-"""Compat lint (ROADMAP "jax version skew"): every shard_map in the repo
-must go through the one version-compat shim, `parallel/collectives.py
-shard_map` — the entry point moved (jax.experimental.shard_map ->
-jax.shard_map) and the replication-check flag was renamed (check_rep ->
-check_vma) across the jax versions this code runs under. A direct import
-anywhere else works on ONE jax version and breaks on the next; this tier-1
-test fails the moment a new violation lands.
+"""Shim lint: every shard_map in the repo must go through the one wrapper,
+`parallel/collectives.py shard_map` — the entry point moved
+(jax.experimental.shard_map -> jax.shard_map) and the replication-check
+flag was renamed (check_rep -> check_vma) across jax versions, and every
+body here needs the check off; this tier-1 test fails the moment a direct
+use lands anywhere else.
 
 MIGRATED onto the AST engine (analysis/ast_rules.py `shard-map-shim-only`,
 ISSUE 3): the old regex fired on entry-point MENTIONS inside docstrings and
@@ -52,18 +51,8 @@ def test_docstring_mentions_no_longer_false_positive(tmp_path):
     assert len(found) == 1 and found[0].location.endswith(":2")
 
 
-def test_this_repo_prose_would_have_tripped_the_old_regex():
-    """Regression direction-proof: the repo really contains entry-point
-    mentions in prose (the shim's own docstring at minimum), so the AST
-    migration is load-bearing, not a rename."""
-    assert "jax.experimental.shard_map" in SHIM.read_text()
-
-
-def test_shim_itself_still_wraps_the_raw_entry_points():
-    """The lint is only meaningful while the shim really is the compat
-    layer: it must reference both historical entry points, and the rule
-    must keep pointing at this file."""
-    src = SHIM.read_text()
-    assert "jax.shard_map" in src
-    assert "jax.experimental.shard_map" in src
+def test_shim_is_the_wrapper_the_rule_points_at():
+    """The lint is only meaningful while the shim really is the one caller
+    of the raw entry point, and the rule keeps pointing at this file."""
+    assert "jax.shard_map(" in SHIM.read_text()
     assert SHIM.as_posix().endswith(SHARD_MAP_SHIM)

@@ -88,6 +88,26 @@ def slot_engine(mesh8, tiny):
     return eng
 
 
+def test_auto_page_codec_is_the_xla_one_on_a_multi_device_mesh(mesh8, tiny,
+                                                               devices):
+    """The engine's programs are GSPMD programs and GSPMD cannot partition
+    a Mosaic kernel, so on a mesh of more than one device the int8 page
+    codec's "auto" resolves to the XLA-composed one (same grid, same page
+    bytes); a one-device mesh keeps the backend gate's choice, and an
+    explicit setting is never overridden."""
+    from distributed_pytorch_training_tpu.parallel import MeshSpec, build_mesh
+
+    model, params = tiny
+    mesh1 = build_mesh(MeshSpec(data=1), devices=devices[:1])
+    int8 = dict(kv_dtype="int8")
+    assert SlotEngine(model, mesh8, paged_cfg(**int8),
+                      params)._fused_quantize is False
+    assert SlotEngine(model, mesh1, paged_cfg(**int8),
+                      params)._fused_quantize is None
+    assert SlotEngine(model, mesh8, paged_cfg(fused_quantize=True, **int8),
+                      params)._fused_quantize is True
+
+
 def prompts(ns, seed=0):
     rng = np.random.RandomState(seed)
     return [rng.randint(0, VOCAB, n).astype(np.int32) for n in ns]

@@ -160,17 +160,6 @@ class TestOrchestrator:
         assert [l["world"] for l in report.launches] == [4]
         assert report.launches[0]["available"] == 7
 
-    def test_relay_death_rc70_is_named_and_relaunched(self, tmp_path):
-        plans = [
-            {"rc": 70, "label": 4, "step": 4, "world": 8},
-            {"rc": 0, "label": 12, "step": 12, "world": 8},
-        ]
-        orch, _ckpt = _orchestrator(tmp_path, plans, [8])
-        report = orch.run()
-        assert report.completed
-        assert [l["outcome"] for l in report.launches] == \
-            ["relay_death", "completed"]
-
     def test_mismatch_escape_is_counted(self, tmp_path):
         """A CheckpointWorldSizeMismatch surfacing in a child's output is
         the exact failure the orchestrator exists to absorb — counted as

@@ -60,6 +60,31 @@ def test_all_devices_used_once(devices):
     assert ids == sorted(d.id for d in devices)
 
 
+def test_layout_refusal_is_an_error_on_tpu_and_a_reshape_on_cpu(devices,
+                                                                monkeypatch):
+    """When the topology helper refuses a layout, a plain reshape is right
+    for CPU test meshes (no topology to respect) and WRONG on a TPU, where
+    it would put collectives on the wrong links without saying so."""
+    from jax.experimental import mesh_utils
+
+    from distributed_pytorch_training_tpu.parallel.mesh import (
+        _VirtualSliceDevice,
+    )
+
+    def refuse(*a, **kw):
+        raise ValueError("no such layout")
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", refuse)
+    assert build_mesh(MeshSpec(data=8), devices=devices).size == 8
+
+    class TpuDressed(_VirtualSliceDevice):
+        platform = "tpu"
+
+    tpus = [TpuDressed(d, 0) for d in devices]
+    with pytest.raises(ValueError, match="no such layout"):
+        build_mesh(MeshSpec(data=8), devices=tpus)
+
+
 def test_mesh_spec_parse_errors():
     with pytest.raises(ValueError, match="unknown axis"):
         MeshSpec.parse("bogus=2")
@@ -181,6 +206,8 @@ class TestHybridDcnMesh:
         )
 
         class FakeDev:
+            platform = "cpu"
+
             def __init__(self, i, slice_index):
                 self.id = i
                 self.slice_index = slice_index

@@ -18,8 +18,6 @@ import subprocess
 import sys
 from pathlib import Path
 
-import pytest
-
 WORKER = Path(__file__).parent / "_multihost_worker.py"
 REPO = Path(__file__).parent.parent
 
@@ -30,25 +28,10 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="container jax 0.4.37's XLA:CPU backend cannot run MULTIPROCESS "
-           "computations: the workers rendezvous fine, but the first host "
-           "collective (barrier -> multihost_utils.sync_global_devices -> "
-           "jit psum over both processes) fails with INVALID_ARGUMENT: "
-           "'Multiprocess computations aren't implemented on the CPU "
-           "backend'. Newer jaxlib CPU builds (cross-host collectives via "
-           "gloo/mpi) pass this test unchanged, so it stays xfail — not "
-           "skip — to light up green the moment the runtime supports it.")
 def test_two_process_rendezvous_and_training():
-    """Root cause of the long-standing tier-1 failure (triaged, ISSUE 8):
-    NOT a rendezvous bug in runtime/dist.py — `jax.distributed.initialize`
-    succeeds and both workers see the 2-process topology — but a jaxlib
-    capability gap: this container's XLA:CPU client has no cross-process
-    collective implementation, so every multi-process computation on it is
-    rejected at dispatch. The single-process multi-device suite (conftest's
-    8-device virtual mesh) is unaffected: its collectives never leave the
-    process."""
+    """Two real processes rendezvous through ``jax.distributed`` on the
+    CPU backend, run host collectives and a training step across both, and
+    converge to the same loss."""
     # bounded by the workers' communicate(timeout=240) below
     port = _free_port()
     procs = []

@@ -99,3 +99,15 @@ def test_loader_native_path_matches_python_path(mesh8):
     for nb, pb in zip(native_batches, python_batches):
         for k in ("image", "label", "weight"):
             assert np.array_equal(nb[k], pb[k]), k
+
+
+def test_library_file_name_carries_the_source_hash():
+    """mtimes mean nothing after a tree copy: the built file is named by a
+    hash of dpt_native.cpp, so a stale or foreign .so left in lib/ is never
+    the one that loads."""
+    import hashlib
+
+    digest = hashlib.sha256(native._SRC.read_bytes()).hexdigest()[:16]
+    assert native.lib_path().name == f"libdpt_native-{digest}.so"
+    assert native.lib_path().exists()  # is_available() built exactly it
+    assert native.describe() == native.lib_path().name

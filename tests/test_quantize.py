@@ -65,6 +65,26 @@ class TestKernelBitIdentity:
             np.asarray(_dequant_sum_rows(q, s, fused=False)),
             np.asarray(dequant_sum_rows_fused(q, s)))
 
+    def test_row_split_tiles_bit_identical(self):
+        """The int8 KV-page scatter quantizes tens of thousands of short
+        rows; past `_ROW_SPLIT_BYTES` the kernel tiles the ROW axis too
+        (an un-split tile outgrows VMEM on the chip). Rows are independent,
+        so the split — including its zero-padded last row block — changes
+        no code and no scale."""
+        from distributed_pytorch_training_tpu.ops.quantize import (
+            _fit_block, _fit_rows,
+        )
+
+        shape = (8200, 64)
+        block_r, padded_n = _fit_rows(shape[0], _fit_block(shape[1],
+                                                           shape[0])[0])
+        assert block_r < shape[0] < padded_n  # split, with row padding
+        rows = _rand_rows(shape, seed=3)
+        q_ref, s_ref = _quantize_int8_rows(rows, fused=False)
+        q_fused, s_fused = quantize_int8_rows_fused(rows)
+        np.testing.assert_array_equal(np.asarray(q_ref), np.asarray(q_fused))
+        np.testing.assert_array_equal(np.asarray(s_ref), np.asarray(s_fused))
+
     def test_zero_rows_hit_the_scale_floor(self):
         """All-zero rows exercise the 1e-30 floor: codes 0, scale
         1e-30/127 — identical on both paths (the floor is what keeps the

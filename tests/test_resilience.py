@@ -15,7 +15,6 @@ The binding contracts:
 import json
 import os
 import socket
-import sys
 import threading
 import time
 from pathlib import Path
@@ -28,7 +27,7 @@ from distributed_pytorch_training_tpu.resilience.faults import (
     FaultError, FaultInjector, FaultPlan,
 )
 from distributed_pytorch_training_tpu.resilience.heartbeat import (
-    Deathwatch, LivenessPolicy, port_listening, relay_ports,
+    port_listening,
 )
 from distributed_pytorch_training_tpu.resilience.supervisor import (
     RetryPolicy, Supervisor, SupervisorError,
@@ -585,40 +584,6 @@ class TestSupervisor:
         _assert_bitwise_equal(state.params, control.params)
         _assert_bitwise_equal(state.batch_stats, control.batch_stats)
 
-    def test_relay_death_checkpoints_then_aborts_then_resumes(
-            self, rig, tmp_path, capsys):
-        """ISSUE-6 satellite: an advisory deathwatch reporting the relay
-        dead mid-epoch drains the segment at the next step boundary,
-        writes AND FLUSHES the checkpoint, and aborts with
-        report.relay_death — checkpoint-then-abort, not a bare rc=70. The
-        simulated relaunch resumes that exact step and lands bitwise."""
-        import types
-
-        trainer, state_factory, make_loader = rig
-        watch = types.SimpleNamespace(died=threading.Event(),
-                                      dead_ports=[8082])
-        watch.died.set()  # tunnel already dead at the first step boundary
-        ckpt = CheckpointManager(str(tmp_path / "ckpt"))
-        sup = Supervisor(trainer, ckpt, state_factory, make_loader(),
-                         retry=_FAST_RETRY, checkpoint_every_steps=2,
-                         deathwatch=watch)
-        state, report = sup.run(epochs=2)
-        ckpt.close()
-        assert report.relay_death and not report.completed
-        assert int(state.step) == 1  # drained after ONE step, mid-epoch
-        assert ckpt.verify(1) is None  # the abort save is flushed + intact
-        assert "relay tunnel died" in capsys.readouterr().out
-
-        ckpt2 = CheckpointManager(str(tmp_path / "ckpt"))
-        sup2 = Supervisor(trainer, ckpt2, state_factory, make_loader(),
-                          retry=_FAST_RETRY, checkpoint_every_steps=2)
-        state, report2 = sup2.run(epochs=2)
-        ckpt2.close()
-        assert report2.completed and not report2.relay_death
-        assert int(state.step) == 8
-        control = _control_params(trainer, state_factory, make_loader(), 2)
-        _assert_bitwise_equal(state.params, control.params)
-
     def test_step_fence_detects_mismatched_coordinate(self, rig, tmp_path):
         """A checkpoint whose optimizer step disagrees with its (epoch,
         step) coordinate is the double-apply hazard: the supervisor must
@@ -1167,42 +1132,15 @@ class TestTokenLoaderFaultHook:
 
 
 # ---------------------------------------------------------------------------
-# heartbeat: the extracted deathwatch
+# heartbeat: TCP port liveness (what the control plane's capacity probe reads)
 # ---------------------------------------------------------------------------
 
 
-def _listener():
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    s.listen(8)
-    return s
-
-
-def _accept_forever(s):
-    # a real relay accepts; timeout-polling (not blocking) accept so
-    # close() actually stops the port listening (the bench test's trick)
-    s.settimeout(0.1)
-    while True:
-        try:
-            conn, _ = s.accept()
-            conn.close()
-        except socket.timeout:
-            continue
-        except OSError:
-            return
-
-
-class TestHeartbeat:
-    def test_default_ports_include_8087(self, monkeypatch):
-        """ADVICE r5 #1 pinned: omitting 8087 left the watch blind to an
-        8087-only partial death."""
-        monkeypatch.delenv("DPT_RELAY_PORTS", raising=False)
-        assert relay_ports() == [8082, 8083, 8087]
-        monkeypatch.setenv("DPT_RELAY_PORTS", "9001, bogus,9002")
-        assert relay_ports() == [9001, 9002]
-
+class TestPortLiveness:
     def test_port_listening_probe(self):
-        srv = _listener()
+        srv = socket.socket()
+        srv.bind(("127.0.0.1", 0))
+        srv.listen(8)
         try:
             assert port_listening(srv.getsockname()[1], timeout=0.5)
         finally:
@@ -1213,95 +1151,3 @@ class TestHeartbeat:
             assert not port_listening(bound.getsockname()[1], timeout=0.2)
         finally:
             bound.close()
-
-    def test_arm_requires_env_or_confirmation(self, monkeypatch):
-        monkeypatch.delenv("DPT_RELAY_PORTS", raising=False)
-        assert Deathwatch.arm() is None  # no opt-in: heuristics forbidden
-        # opted in but nothing listening: not a tunneled environment
-        bound = socket.socket()
-        bound.bind(("127.0.0.1", 0))
-        try:
-            monkeypatch.setenv("DPT_RELAY_PORTS",
-                               str(bound.getsockname()[1]))
-            assert Deathwatch.arm() is None
-        finally:
-            bound.close()
-
-    def test_advisory_watch_detects_partial_death(self, monkeypatch):
-        """The 1.5s/3-miss lethal semantics, observable: ONE of two armed
-        ports dies (partial death hangs compiles like total death) — the
-        watch must fire, name the dead port, and report the survivor to
-        on_death. lethal=False so the test survives to assert."""
-        srv_dies, srv_stays = _listener(), _listener()
-        for s in (srv_dies, srv_stays):
-            threading.Thread(target=_accept_forever, args=(s,),
-                             daemon=True).start()
-        seen = {}
-        port_dies = srv_dies.getsockname()[1]
-        port_stays = srv_stays.getsockname()[1]
-        monkeypatch.setenv("DPT_RELAY_PORTS", f"{port_dies},{port_stays}")
-        try:
-            watch = Deathwatch.arm(
-                policy=LivenessPolicy(interval_s=0.05,
-                                      connect_timeout_s=0.3, max_misses=3,
-                                      lethal=False),
-                on_death=lambda dead, alive: seen.update(dead=dead,
-                                                         alive=alive),
-                log=lambda _m: None)
-            assert watch is not None and len(watch.armed_ports) == 2
-            time.sleep(0.2)          # a few healthy samples first
-            assert not watch.died.is_set()
-            srv_dies.close()         # the "compile port" dies
-            assert watch.died.wait(timeout=10.0)
-            assert seen["dead"] == [port_dies] == watch.dead_ports
-            assert seen["alive"] == [port_stays]
-        finally:
-            srv_dies.close()
-            srv_stays.close()
-
-    def test_advisory_watch_escalates_when_owner_wedges(self, monkeypatch):
-        """escalate_after_s: an advisory watch whose owner never exits
-        (the checkpoint-then-abort wedged in dead-relay RPC retries) must
-        fall through to the lethal hard exit — advisory mode cannot hang
-        strictly longer than the lethal watch it replaced."""
-        from distributed_pytorch_training_tpu.resilience import heartbeat
-
-        srv = _listener()
-        threading.Thread(target=_accept_forever, args=(srv,),
-                         daemon=True).start()
-        port = srv.getsockname()[1]
-        monkeypatch.setenv("DPT_RELAY_PORTS", str(port))
-        exits = []
-        monkeypatch.setattr(heartbeat, "hard_exit",
-                            lambda code: exits.append(code))
-        try:
-            watch = Deathwatch.arm(
-                policy=LivenessPolicy(interval_s=0.05,
-                                      connect_timeout_s=0.3, max_misses=3,
-                                      lethal=False, escalate_after_s=0.2),
-                log=lambda _m: None)
-            assert watch is not None
-            srv.close()  # total death: no survivor, no PJRT-close detour
-            assert watch.died.wait(timeout=10.0)
-            deadline = time.monotonic() + 10.0
-            while not exits and time.monotonic() < deadline:
-                time.sleep(0.05)
-            assert exits == [heartbeat.DEATHWATCH_EXIT_CODE]
-        finally:
-            srv.close()
-
-    def test_bench_consumes_the_shared_heartbeat(self):
-        """The satellite's anti-drift pin: bench.py's port registry and
-        probe ARE the heartbeat module's (no second copy to rot), and the
-        inlined deathwatch is gone."""
-        sys.path.insert(0, str(REPO))
-        import bench
-
-        assert bench._relay_ports is relay_ports
-        assert bench._port_listening is port_listening
-        src = (REPO / "bench.py").read_text()
-        assert "Deathwatch.arm(" in src
-        # the one-source-of-truth claim, literally: no local def remains
-        assert "def _port_listening" not in src
-        assert "def _relay_ports" not in src
-        assert "def _try_clean_pjrt_close" not in src

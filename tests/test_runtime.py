@@ -49,42 +49,88 @@ def test_setup_distributed_single_process_context():
     assert ctx.is_main
 
 
-def test_persistent_compile_cache_refuses_cpu_backend(tmp_path):
+def test_platform_rule_cpu_only_by_name(monkeypatch):
+    """runtime.require_backend: JAX_PLATFORMS=cpu is a CPU run; anything
+    else (unset included) with no TPU behind it raises instead of carrying
+    on quietly on the host."""
+    import pytest
+
+    from distributed_pytorch_training_tpu.runtime import (
+        cpu_requested, require_backend,
+    )
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert cpu_requested() and require_backend() == "cpu"
+    for value in (None, "", "tpu,cpu"):
+        if value is None:
+            monkeypatch.delenv("JAX_PLATFORMS")
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", value)
+        assert not cpu_requested()
+        with pytest.raises(RuntimeError, match="did not ask for the CPU"):
+            require_backend()
+
+
+def test_serving_cli_never_sets_the_platform(monkeypatch):
+    """The serving CLI used to force JAX_PLATFORMS=cpu whenever the variable
+    was unset — a TPU would have sat idle beside an 8-device CPU run. Now
+    the virtual mesh is asked for only on a CPU run named as such, the
+    platform is never touched, and an unnamed CPU run is refused."""
+    import os
+
+    import jax
+    import pytest
+
+    from distributed_pytorch_training_tpu.analysis.__main__ import (
+        _ensure_test_mesh,
+    )
+    from distributed_pytorch_training_tpu.serving.__main__ import main
+
+    platforms_before = jax.config.jax_platforms
+    monkeypatch.delenv("JAX_PLATFORMS")
+    _ensure_test_mesh()
+    assert "JAX_PLATFORMS" not in os.environ
+    assert jax.config.jax_platforms == platforms_before
+    with pytest.raises(RuntimeError, match="did not ask for the CPU"):
+        main(["smoke", "--no-telemetry"])
+
+
+def test_compile_cache_refuses_cpu_backend_in_auto(monkeypatch):
     """XLA:CPU persistent-cache reloads are unsafe (AOT pseudo-feature
     mismatch desynchronized a collective rendezvous into a fatal abort —
     runtime.dist.enable_persistent_compile_cache docstring). On the CPU
-    test backend the helper must refuse (in the default "auto" mode) and
-    leave the config untouched."""
+    test backend the default "auto" mode must refuse and leave the config
+    untouched."""
     import jax
 
     from distributed_pytorch_training_tpu.runtime import (
-        enable_persistent_compile_cache,
+        CACHE_DIR_ENV, enable_persistent_compile_cache,
     )
 
+    monkeypatch.delenv(CACHE_DIR_ENV, raising=False)
     before = jax.config.jax_compilation_cache_dir
-    assert enable_persistent_compile_cache(tmp_path / "cache") is False
+    assert enable_persistent_compile_cache() is False
     assert jax.config.jax_compilation_cache_dir == before
-    assert not (tmp_path / "cache").exists()
 
 
-def test_compile_cache_tristate(tmp_path, monkeypatch):
+def test_compile_cache_tristate(monkeypatch):
     """ISSUE-11: the DPT_COMPILE_CACHE tri-state — "off" never enables,
     "on" forces (the operator vouches), invalid values are loud, unset
-    resolves to "auto" (the backend-gated historical behavior)."""
+    resolves to "auto" (the backend-gated behavior)."""
     import jax
     import pytest
 
     from distributed_pytorch_training_tpu.runtime import (
-        COMPILE_CACHE_ENV, compile_cache_mode,
+        CACHE_DIR_ENV, COMPILE_CACHE_ENV, compile_cache_mode,
         enable_persistent_compile_cache,
     )
 
+    monkeypatch.delenv(CACHE_DIR_ENV, raising=False)
     dir_before = jax.config.jax_compilation_cache_dir
-    min_before = jax.config.jax_persistent_cache_min_compile_time_secs
 
     monkeypatch.setenv(COMPILE_CACHE_ENV, "off")
     assert compile_cache_mode() == "off"
-    assert enable_persistent_compile_cache(tmp_path / "c") is False
+    assert enable_persistent_compile_cache() is False
     assert jax.config.jax_compilation_cache_dir == dir_before
 
     monkeypatch.setenv(COMPILE_CACHE_ENV, "maybe")
@@ -95,25 +141,56 @@ def test_compile_cache_tristate(tmp_path, monkeypatch):
     assert compile_cache_mode() == "auto"
     assert compile_cache_mode("on") == "on"  # explicit arg beats the env
 
+
+def test_compile_cache_lives_in_one_place(tmp_path, monkeypatch):
+    """ONE rule (runtime/dist.py): with JAX_COMPILATION_CACHE_DIR set, jax
+    reads it itself and our code never touches jax_compilation_cache_dir
+    (and creates nothing under the checkout); unset, the directory is the
+    fixed <checkout>/.jax_cache. No caller can pass a directory."""
+    import inspect
+    from pathlib import Path
+
+    import jax
+
+    from distributed_pytorch_training_tpu.runtime import (
+        CACHE_DIR_ENV, compile_cache_dir, enable_persistent_compile_cache,
+    )
+
+    checkout = Path(__file__).resolve().parent.parent
+    assert list(inspect.signature(
+        enable_persistent_compile_cache).parameters) == ["mode"]
+    assert not inspect.signature(compile_cache_dir).parameters
+
+    dir_before = jax.config.jax_compilation_cache_dir
+    enabled_before = jax.config.jax_enable_compilation_cache
+    had_checkout_cache = (checkout / ".jax_cache").exists()
     try:
-        assert enable_persistent_compile_cache(tmp_path / "c",
-                                               mode="on") is True
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "c")
+        # variable set: the config is jax's to read, not ours to write
+        monkeypatch.setenv(CACHE_DIR_ENV, str(tmp_path / "cache"))
+        assert compile_cache_dir() == tmp_path / "cache"
+        assert enable_persistent_compile_cache(mode="on") is True
+        assert jax.config.jax_compilation_cache_dir == dir_before
+        assert (checkout / ".jax_cache").exists() == had_checkout_cache
+        # ...and a refusal (auto on XLA:CPU) switches jax's own cache off
+        assert enable_persistent_compile_cache() is False
+        assert jax.config.jax_enable_compilation_cache is False
+
+        # variable unset: the fixed checkout-local directory
+        monkeypatch.delenv(CACHE_DIR_ENV)
+        assert compile_cache_dir() == checkout / ".jax_cache"
+        assert enable_persistent_compile_cache(mode="on") is True
+        assert jax.config.jax_compilation_cache_dir == \
+            str(checkout / ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", dir_before)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_before)
+        jax.config.update("jax_enable_compilation_cache", enabled_before)
 
+    # and no other module decides: the config key appears in dist.py only
+    from distributed_pytorch_training_tpu.analysis.ast_rules import (
+        iter_source_files,
+    )
 
-def test_compile_cache_dir_is_keyed_and_sanitized(tmp_path):
-    """(topology, config) key one directory each; key components become
-    filesystem-safe tokens."""
-    from distributed_pytorch_training_tpu.runtime import compile_cache_dir
-
-    a = compile_cache_dir(tmp_path, "cpu-8dev", "gpt2 124m/zero1")
-    b = compile_cache_dir(tmp_path, "cpu-4dev", "gpt2 124m/zero1")
-    c = compile_cache_dir(tmp_path, "cpu-8dev", "gpt2 124m/fsdp")
-    assert len({a, b, c}) == 3
-    assert a.parent == b.parent == tmp_path
-    for p in (a, b, c):
-        assert "/" not in p.name and " " not in p.name
+    setters = [p.relative_to(checkout).as_posix()
+               for p in iter_source_files()
+               if "jax_compilation_cache_dir" in p.read_text()]
+    assert setters == ["distributed_pytorch_training_tpu/runtime/dist.py"]
