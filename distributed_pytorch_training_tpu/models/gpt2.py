@@ -218,7 +218,10 @@ class GPT2LMHead(VocabPaddingMixin, nn.Module):
                               jnp.finfo(jnp.float32).min)
             return TpShardedLogits(local, self.tp_axis, vocab_rows,
                                    self.vocab_size)
-        logits = wte.attend(x)  # tied LM head (HF ties wte <-> lm_head)
+        # tied LM head (HF ties wte <-> lm_head). flax files `attend` under
+        # `wte`; `head` around it tells the vocab-wide matmul from the lookup
+        with jax.named_scope("head"):
+            logits = wte.attend(x)
         logits = mask_vocab_padding(logits.astype(jnp.float32),
                                     self.vocab_size)
         return logits if cache is None else (logits, tuple(new_cache))

@@ -216,6 +216,7 @@ def _quant_rows(x: jnp.ndarray, fused: Optional[bool] = None
     return q.reshape(x.shape), scales.reshape(lead)
 
 
+@jax.named_scope("kv_gather")
 def gather_paged_kv(pkv: PagedKV, page_table: jnp.ndarray,
                     dtype: Dtype = jnp.float32
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -240,6 +241,7 @@ def gather_paged_kv(pkv: PagedKV, page_table: jnp.ndarray,
     return dense(pkv.k, pkv.k_scale), dense(pkv.v, pkv.v_scale)
 
 
+@jax.named_scope("kv_scatter")
 def scatter_paged_rows(pkv: PagedKV, page_table: jnp.ndarray,
                        positions: jnp.ndarray, k_rows: jnp.ndarray,
                        v_rows: jnp.ndarray, active: jnp.ndarray,
@@ -271,6 +273,7 @@ def scatter_paged_rows(pkv: PagedKV, page_table: jnp.ndarray,
     return PagedKV(k=k, v=v, k_scale=ks, v_scale=vs)
 
 
+@jax.named_scope("kv_scatter")
 def scatter_paged_window(pkv: PagedKV, page_table: jnp.ndarray,
                          positions: jnp.ndarray, k_rows: jnp.ndarray,
                          v_rows: jnp.ndarray, active: jnp.ndarray,
@@ -303,6 +306,7 @@ def scatter_paged_window(pkv: PagedKV, page_table: jnp.ndarray,
     return PagedKV(k=k, v=v, k_scale=ks, v_scale=vs)
 
 
+@jax.named_scope("kv_scatter")
 def scatter_paged_prefill(pkv: PagedKV, page_row: jnp.ndarray,
                           k_seqs: jnp.ndarray, v_seqs: jnp.ndarray,
                           length: jnp.ndarray,

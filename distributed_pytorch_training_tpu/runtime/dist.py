@@ -130,6 +130,21 @@ def enable_persistent_compile_cache(mode: Optional[str] = None) -> bool:
     elif enabled:
         jax.config.update("jax_compilation_cache_dir",
                           str(_CHECKOUT_CACHE_DIR))
+    if enabled:
+        # The key is the program AND its scope names, nothing else. jax's
+        # key drops a program's metadata by default, and the
+        # `jax.named_scope` paths a profiler trace is read by ARE metadata:
+        # a directory shared with another checkout would hand back that
+        # checkout's executable under its scope names (PERF.md section 7).
+        # So the metadata goes into the key, and file names and line
+        # numbers come out of the metadata (locations keep the scope path
+        # and the primitive): an edit that moves a line, or a second
+        # checkout at another path, still finds every program it did not
+        # change. The price: compiled programs carry no source_file /
+        # source_line.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+        jax.config.update("jax_traceback_in_locations_limit", 0)
     from .. import telemetry
 
     telemetry.counter("compile_cache_enabled", int(enabled),

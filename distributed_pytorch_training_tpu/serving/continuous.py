@@ -74,6 +74,7 @@ from .engine import InferenceEngine
 from .paged import PagedServeConfig, PageLease, PagePool
 
 
+@jax.named_scope("sample")
 def sample_tokens(logits: jnp.ndarray, keys: jnp.ndarray,
                   temperatures: jnp.ndarray,
                   top_ps: jnp.ndarray) -> jnp.ndarray:
@@ -279,6 +280,10 @@ class SlotEngine(InferenceEngine):
         return prefill
 
     def _make_paged_decode(self) -> Callable:
+        """The decode step, its parts under `jax.named_scope`s (`kv_gather`,
+        `model`, `kv_scatter`, `sample`, `bookkeeping`): trace-time metadata
+        by which the benchmark's `batch_decode_*_ms` read a profiler trace
+        (`PAGED_DECODE` in benchmark/layer_metrics/_regions.py)."""
         rows = self.config.rows
         fused = self._fused_quantize
 
@@ -291,48 +296,52 @@ class SlotEngine(InferenceEngine):
             # bitwise-pinned decode attention consumes unchanged. The pool
             # is layer-stacked, so this is ONE gather; the per-layer
             # slices below are fused into their attention consumers.
-            k_all, v_all = gather_paged_kv(pool, page_table,
-                                           dtype=self.model.dtype)
-            cache = tuple((k_all[l], v_all[l])
-                          for l in range(self.model.depth))
-            logits, new_cache = self.model.apply(
-                self._apply_vars(params), tok[:, None], train=False,
-                cache=cache, cache_positions=positions)
+            with jax.named_scope("kv_gather"):
+                k_all, v_all = gather_paged_kv(pool, page_table,
+                                               dtype=self.model.dtype)
+                cache = tuple((k_all[l], v_all[l])
+                              for l in range(self.model.depth))
+            with jax.named_scope("model"):
+                logits, new_cache = self.model.apply(
+                    self._apply_vars(params), tok[:, None], train=False,
+                    cache=cache, cache_positions=positions)
             # write half: ONE fresh (H, D) row per live slot per layer,
             # restacked to (L, rows, H, D) -> ONE scatter back to the pool
-            idx = positions[:, None, None, None]
-            k_rows = jnp.stack([
-                jnp.take_along_axis(k_new, idx, axis=1)[:, 0]
-                for k_new, _ in new_cache])
-            v_rows = jnp.stack([
-                jnp.take_along_axis(v_new, idx, axis=1)[:, 0]
-                for _, v_new in new_cache])
-            new_pool = scatter_paged_rows(pool, page_table, positions,
-                                          k_rows, v_rows, active,
-                                          fused=fused)
+            with jax.named_scope("kv_scatter"):
+                idx = positions[:, None, None, None]
+                k_rows = jnp.stack([
+                    jnp.take_along_axis(k_new, idx, axis=1)[:, 0]
+                    for k_new, _ in new_cache])
+                v_rows = jnp.stack([
+                    jnp.take_along_axis(v_new, idx, axis=1)[:, 0]
+                    for _, v_new in new_cache])
+                new_pool = scatter_paged_rows(pool, page_table, positions,
+                                              k_rows, v_rows, active,
+                                              fused=fused)
             # the token at position p+1, from THIS request's key stream
             step_keys = jax.vmap(jax.random.fold_in)(
                 control["keys"], positions + 1)
             nxt = sample_tokens(logits[:, 0], step_keys, control["temps"],
                                 control["top_ps"])
-            act = active.astype(jnp.int32)
-            safe_row = jnp.where(active, jnp.arange(rows), rows)
-            out_buf = control["out_buf"].at[
-                safe_row, control["emitted"]].set(nxt, mode="drop")
-            # a skip-admitted slot's first step captures the last-prompt
-            # logits the prefill would have stored (bitwise, by the
-            # decode-vs-full parity pin); -1 for everyone else
-            cap = positions == control["last_pos"]
-            new_control = dict(control)
-            new_control["tok"] = jnp.where(active, nxt, tok)
-            new_control["positions"] = positions + act
-            new_control["budget"] = control["budget"] - act
-            new_control["emitted"] = control["emitted"] + act
-            new_control["out_buf"] = out_buf
-            new_control["last_buf"] = jnp.where(
-                cap[:, None], logits[:, 0], control["last_buf"])
-            new_control["last_pos"] = jnp.where(
-                cap, -1, control["last_pos"])
+            with jax.named_scope("bookkeeping"):
+                act = active.astype(jnp.int32)
+                safe_row = jnp.where(active, jnp.arange(rows), rows)
+                out_buf = control["out_buf"].at[
+                    safe_row, control["emitted"]].set(nxt, mode="drop")
+                # a skip-admitted slot's first step captures the last-prompt
+                # logits the prefill would have stored (bitwise, by the
+                # decode-vs-full parity pin); -1 for everyone else
+                cap = positions == control["last_pos"]
+                new_control = dict(control)
+                new_control["tok"] = jnp.where(active, nxt, tok)
+                new_control["positions"] = positions + act
+                new_control["budget"] = control["budget"] - act
+                new_control["emitted"] = control["emitted"] + act
+                new_control["out_buf"] = out_buf
+                new_control["last_buf"] = jnp.where(
+                    cap[:, None], logits[:, 0], control["last_buf"])
+                new_control["last_pos"] = jnp.where(
+                    cap, -1, control["last_pos"])
             return new_pool, new_control
 
         return decode

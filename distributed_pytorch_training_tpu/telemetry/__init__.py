@@ -40,7 +40,15 @@ Design constraints (enforced, not aspirational):
   (analysis/ast_rules.py) forbids Recorder calls in jit/shard_map bodies,
   and a tier-1 test pins that the lowered HLO of a telemetry-on and
   telemetry-off run is IDENTICAL (PARITY.md: telemetry adds surfaces,
-  never changes training numerics).
+  never changes training numerics). Device-side regions are
+  ``jax.named_scope``s (and the flash kernels' ``name=``) in the traced
+  code, read afterwards from the profiler's trace by scope path — never
+  Recorder calls inside traced code; the ``telemetry-emit-outside-traced``
+  rule stands. Their names are literals where the code opens them and
+  listed once per program where they are read:
+  ``benchmark/layer_metrics/_regions.py`` (``TRAIN_STEP``,
+  ``FLASH_KERNELS``, ``PAGED_DECODE``), held against each program's
+  lowered text by ``tests/benchmark/test_benchmark_region_names.py``.
 * **Zero cost when unconfigured.** The module-level emit helpers check one
   global and return; no file, no ring, no timestamps.
 * **No jax at module scope.** The flight recorder must be callable from

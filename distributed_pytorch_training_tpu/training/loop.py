@@ -503,6 +503,13 @@ class Trainer:
 
     # -- compiled bodies ---------------------------------------------------
 
+    # A train step's named regions are scope paths in the compiled program:
+    # flax opens one per module (`attn`, `mlp`, `wte`, `wpe`, `ln_f`), `head`
+    # is opened in models/gpt2.py, `loss` in training/tasks.py, `optimizer`
+    # wherever a step body runs `tx.update`. Trace-time metadata only; the
+    # benchmark's `train_*_ms` read device time by them (`TRAIN_STEP` in
+    # benchmark/layer_metrics/_regions.py lists them).
+
     def _train_step_impl(self, state: TrainState, batch, epoch_key):
         rng = jax.random.fold_in(epoch_key, state.step)
         accum = self.config.grad_accum
@@ -640,8 +647,10 @@ class Trainer:
 
         flat_g = jax.tree_util.tree_map(flat_dp, grads)
         p_flat = jax.tree_util.tree_map(flat_dp, state.params)
-        updates, new_opt = state.tx.update(flat_g, state.opt_state, p_flat)
-        new_flat = optax.apply_updates(p_flat, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = state.tx.update(flat_g, state.opt_state,
+                                               p_flat)
+            new_flat = optax.apply_updates(p_flat, updates)
         # back to model shapes, re-constrained to the rules' layout so the
         # updated params keep their TP sharding instead of whatever the
         # flat->full reshape propagates
@@ -824,8 +833,9 @@ class Trainer:
             # replicated update from the synced global-mean gradient — the
             # optimizer must NOT carry shard_axes here (grads are already
             # global; a psum'd clip norm would count every replica n times)
-            updates, new_opt = outer.tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = outer.tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
 
             if has_stats:
                 new_stats = jax.tree_util.tree_map(
@@ -1044,8 +1054,10 @@ class Trainer:
                 lambda g, p: (g / total_w).astype(p.dtype), g_sum, p_shards)
 
             # 1/N of the optimizer update — the whole point of zero1
-            updates, new_opt = outer.tx.update(grads, opt_state, p_shards)
-            new_p_shards = optax.apply_updates(p_shards, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = outer.tx.update(grads, opt_state,
+                                                   p_shards)
+                new_p_shards = optax.apply_updates(p_shards, updates)
             if wire == "int8_multihop":
                 # compressed param gather: s8 UPDATE codes + one fp32 scale
                 # per chunk; every replica adds the identical dequantized
@@ -1370,8 +1382,10 @@ class Trainer:
             # 1/N of the optimizer update, on the at-rest shards — the
             # zero1 core, minus its epilogue gather: the new shards ARE
             # the output layout
-            updates, new_opt = outer.tx.update(grads, opt_state, p_shards)
-            new_p_shards = optax.apply_updates(p_shards, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = outer.tx.update(grads, opt_state,
+                                                   p_shards)
+                new_p_shards = optax.apply_updates(p_shards, updates)
 
             if has_stats:
                 new_stats = jax.tree_util.tree_map(

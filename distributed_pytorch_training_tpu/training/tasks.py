@@ -117,33 +117,36 @@ class LanguageModelingTask(Task):
         logits, mutated = state.apply_fn(
             {"params": params}, ids, train=train, mutable=["losses"],
             rngs=rngs)
-        # shift: predict ids[:, 1:] from logits[:, :-1]
-        tgt = ids[:, 1:]
-        if isinstance(logits, TpShardedLogits):
-            # vocab-parallel head (explicit TP): Megatron parallel-vocab
-            # CE over the local logit columns — two (B, S, 2)-sized
-            # model-axis stats instead of a vocab-scale logits gather
-            # (parallel/collectives.tp_parallel_cross_entropy). Same
-            # train and eval path.
-            per_tok, predicted = tp_parallel_cross_entropy(
-                logits.map_local(lambda x: x[:, :-1]), tgt)
-        else:
-            lg = logits[:, :-1].astype(jnp.float32)
-            per_tok = optax.softmax_cross_entropy_with_integer_labels(
-                lg, tgt)
-            predicted = jnp.argmax(lg, axis=-1) == tgt
-        w = batch["weight"][:, None] * jnp.ones_like(per_tok)
-        wsum = w.sum()
-        loss = (per_tok * w).sum() / jnp.maximum(wsum, 1.0)
-        if self.aux_loss_weight:
-            aux_leaves = jax.tree_util.tree_leaves(mutated.get("losses", {}))
-            if aux_leaves:
-                aux = (sum(jnp.asarray(a).mean() for a in aux_leaves)
-                       / len(aux_leaves))
-                loss = loss + self.aux_loss_weight * aux
-        correct = (predicted * w).sum()
-        metrics = {"loss_sum": (per_tok * w).sum(), "correct": correct,
-                   "weight": wsum}
+        # `loss`: the vocab-wide work after the model (PERF.md section 3)
+        with jax.named_scope("loss"):
+            # shift: predict ids[:, 1:] from logits[:, :-1]
+            tgt = ids[:, 1:]
+            if isinstance(logits, TpShardedLogits):
+                # vocab-parallel head (explicit TP): Megatron parallel-vocab
+                # CE over the local logit columns — two (B, S, 2)-sized
+                # model-axis stats instead of a vocab-scale logits gather
+                # (parallel/collectives.tp_parallel_cross_entropy). Same
+                # train and eval path.
+                per_tok, predicted = tp_parallel_cross_entropy(
+                    logits.map_local(lambda x: x[:, :-1]), tgt)
+            else:
+                lg = logits[:, :-1].astype(jnp.float32)
+                per_tok = optax.softmax_cross_entropy_with_integer_labels(
+                    lg, tgt)
+                predicted = jnp.argmax(lg, axis=-1) == tgt
+            w = batch["weight"][:, None] * jnp.ones_like(per_tok)
+            wsum = w.sum()
+            loss = (per_tok * w).sum() / jnp.maximum(wsum, 1.0)
+            if self.aux_loss_weight:
+                aux_leaves = jax.tree_util.tree_leaves(
+                    mutated.get("losses", {}))
+                if aux_leaves:
+                    aux = (sum(jnp.asarray(a).mean() for a in aux_leaves)
+                           / len(aux_leaves))
+                    loss = loss + self.aux_loss_weight * aux
+            correct = (predicted * w).sum()
+            metrics = {"loss_sum": (per_tok * w).sum(), "correct": correct,
+                       "weight": wsum}
         return loss, (metrics, state.batch_stats)
 
 

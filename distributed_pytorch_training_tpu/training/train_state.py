@@ -53,8 +53,10 @@ class TrainState:
 
     def apply_gradients(self, grads: Any, batch_stats: Any = None) -> "TrainState":
         """optimizer.step() equivalent (ref :214 / :208)."""
-        updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
-        new_params = optax.apply_updates(self.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = self.tx.update(
+                grads, self.opt_state, self.params)
+            new_params = optax.apply_updates(self.params, updates)
         return self.replace(
             step=self.step + 1,
             params=new_params,

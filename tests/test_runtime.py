@@ -163,6 +163,9 @@ def test_compile_cache_lives_in_one_place(tmp_path, monkeypatch):
 
     dir_before = jax.config.jax_compilation_cache_dir
     enabled_before = jax.config.jax_enable_compilation_cache
+    metadata_before = \
+        jax.config.jax_compilation_cache_include_metadata_in_key
+    frames_before = jax.config.jax_traceback_in_locations_limit
     had_checkout_cache = (checkout / ".jax_cache").exists()
     try:
         # variable set: the config is jax's to read, not ours to write
@@ -170,6 +173,12 @@ def test_compile_cache_lives_in_one_place(tmp_path, monkeypatch):
         assert compile_cache_dir() == tmp_path / "cache"
         assert enable_persistent_compile_cache(mode="on") is True
         assert jax.config.jax_compilation_cache_dir == dir_before
+        # scope names are what a trace is read by: they are in the key,
+        # and neither this file's name nor a line number is
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        text = jax.jit(jax.named_scope("a_region")(lambda x: x + 1)).lower(
+            1.0).as_text(debug_info=True)
+        assert "a_region/add" in text and "test_runtime" not in text
         assert (checkout / ".jax_cache").exists() == had_checkout_cache
         # ...and a refusal (auto on XLA:CPU) switches jax's own cache off
         assert enable_persistent_compile_cache() is False
@@ -184,6 +193,9 @@ def test_compile_cache_lives_in_one_place(tmp_path, monkeypatch):
     finally:
         jax.config.update("jax_compilation_cache_dir", dir_before)
         jax.config.update("jax_enable_compilation_cache", enabled_before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          metadata_before)
+        jax.config.update("jax_traceback_in_locations_limit", frames_before)
 
     # and no other module decides: the config key appears in dist.py only
     from distributed_pytorch_training_tpu.analysis.ast_rules import (
