@@ -26,6 +26,7 @@ from ..parallel.collectives import (
 )
 from ..parallel.sharding import PartitionRules
 from .layers import (
+    PagedRead,
     TransformerBlock,
     VocabPaddingMixin,
     causal_mask,
@@ -100,7 +101,11 @@ class GPT2LMHead(VocabPaddingMixin, nn.Module):
           values).
 
         With a cache the return value is ``(logits, new_cache)`` where
-        ``new_cache`` matches `init_cache`'s structure.
+        ``new_cache`` matches `init_cache`'s structure. One more decode
+        form: ``cache`` a `layers.PagedRead` (S == 1 only) reads the paged
+        pool in place through `ops.paged_attention` instead of dense views;
+        ``new_cache`` is then each block's fresh (k, v) rows, (B, H*D)
+        each, for the caller to scatter into the pool.
         """
         b, s = input_ids.shape
         decoding = cache is not None and cache_positions is not None
@@ -156,7 +161,18 @@ class GPT2LMHead(VocabPaddingMixin, nn.Module):
         # row's position (later slots are unwritten or prefill pad — both
         # must stay invisible).
         uses_kernel = self.attention_fn is not dot_product_attention
-        if decoding and s == 1:
+        paged = isinstance(cache, PagedRead)
+        if paged:
+            # the pool read in place (S == 1): `ops.paged_attention` owns
+            # the visibility rule, positions < the row's own from its
+            # pages and the fresh row at its own
+            if not decoding or s != 1:
+                raise ValueError(
+                    "a PagedRead cache serves the S=1 decode step only — "
+                    "prefill and windows take dense views "
+                    "(layers.gather_paged_kv)")
+            mask = None
+        elif decoding and s == 1:
             t = cache[0][0].shape[1]
             mask = (jnp.arange(t)[None, :]
                     <= cache_positions[:, None])[:, None, None, :]
@@ -196,7 +212,9 @@ class GPT2LMHead(VocabPaddingMixin, nn.Module):
                 x = block(x, mask=mask, deterministic=not train)
             else:
                 x, c = block(x, mask=mask, deterministic=not train,
-                             cache=cache[i], cache_positions=cache_positions)
+                             cache=(cache.replace(layer=i) if paged
+                                    else cache[i]),
+                             cache_positions=cache_positions)
                 new_cache.append(c)
 
         x = nn.LayerNorm(epsilon=self.layernorm_epsilon, dtype=self.dtype,
@@ -237,13 +255,15 @@ class GPT2LMHead(VocabPaddingMixin, nn.Module):
     def init_paged_pool(self, n_pages: int, page_size: int,
                         quantized: bool = False):
         """Zero-filled paged KV pool: ONE `layers.PagedKV` stacked over all
-        ``depth`` blocks — (depth, n_pages, page_size, heads, head_dim)
-        pages (int8 codes + per-row fp32 scales when ``quantized`` — the
-        wire-codec grid). The paged serving engine
-        (serving/continuous.py) gathers per-slot pages into the SAME dense
-        cache shape `init_cache` produces, so the decode forward above
-        runs unchanged — paging is a storage layout, not a numerics change
-        (PARITY.md)."""
+        ``depth`` blocks — (depth, n_pages, page_size, heads * head_dim)
+        lane-dense pages (int8 codes + per-row fp32 scales when
+        ``quantized`` — the wire-codec grid). The paged serving engine
+        (serving/continuous.py) either gathers per-slot pages into the
+        SAME dense cache shape `init_cache` produces, so the decode forward
+        above runs unchanged (the reference read: paging is then a storage
+        layout, not a numerics change), or hands the pool itself to the
+        S=1 decode step as a `layers.PagedRead` (the kernel read, a TPU's;
+        PARITY.md has both exactness models)."""
         from .layers import init_paged_kv
 
         return init_paged_kv(self.depth, n_pages, page_size,

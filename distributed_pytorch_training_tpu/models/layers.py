@@ -134,18 +134,28 @@ def decode_dot_product_attention(
 # The dense per-request cache above allocates (rows, bucket + max_new, H, D)
 # per block whether a slot is live or not — the HBM ceiling at long
 # max_new_tokens. The paged form stores k/v in a POOL of fixed-size pages
-# (L, n_pages, page_size, H, D), stacked over every block so one gather /
-# one scatter serves the whole model; each serving slot owns a row of a
-# page TABLE
-# mapping its logical positions onto pool pages. The compiled decode step
-# gathers a slot's pages into the SAME dense (rows, T, H, D) view the
-# bitwise-pinned decode attention consumes, so fp32 paged decode inherits
-# the dense path's exactness proof verbatim: trailing/garbage positions are
-# masked to the fp32 min, their softmax weight underflows to exactly 0.0,
-# and adding 0.0 in the fp32 contraction is exact. int8 pages quantize each
-# (position, head) row over D through the gradient-wire codec grid
-# (``grad_sync._quantize_int8_rows`` — codes + one fp32 scale per row), a
-# bounded, deterministic, replica-identical perturbation (PARITY.md).
+# (L, n_pages, page_size, H*D), stacked over every block; each serving slot
+# owns a row of a page TABLE mapping its logical positions onto pool pages.
+# A page is lane-dense — a position's heads lie side by side in the last
+# axis — and the pool is only ever touched in that at-rest layout: writes
+# are row scatters over its flattened (L*n_pages*page_size, H*D) view (a
+# bitcast; `_put_rows`), and the S=1 decode step on a TPU reads it page by
+# page from HBM (`ops.paged_attention`). Two reads exist:
+#
+# * the REFERENCE read, `gather_paged_kv`: a slot's pages gathered into the
+#   SAME dense (rows, T, H, D) view the bitwise-pinned decode attention
+#   consumes, so fp32 paged decode inherits the dense path's exactness
+#   proof verbatim: trailing/garbage positions are masked to the fp32 min,
+#   their softmax weight underflows to exactly 0.0, and adding 0.0 in the
+#   fp32 contraction is exact. Windows (resume, speculative verify), int8
+#   pools, meshes of more than one device and every CPU run take it.
+# * the KERNEL read, `PagedRead` -> `ops.paged_attention`: no dense view;
+#   equal to the reference within a tolerance, not bitwise (PARITY.md).
+#
+# int8 pages quantize each (position, head) row over D through the
+# gradient-wire codec grid (``grad_sync._quantize_int8_rows`` — codes + one
+# fp32 scale per row), a bounded, deterministic, replica-identical
+# perturbation (PARITY.md).
 # ---------------------------------------------------------------------------
 
 
@@ -153,15 +163,21 @@ def decode_dot_product_attention(
 class PagedKV:
     """The model's paged KV pool, stacked across ALL blocks.
 
-    ``k``/``v`` are (L, n_pages, page_size, H, D) in the model dtype — one
-    leading layer axis over every transformer block — or int8 codes when
+    ``k``/``v`` are (L, n_pages, page_size, H*D) in the model dtype — one
+    leading layer axis over every transformer block, a position's
+    ``num_heads`` heads side by side in the lanes — or int8 codes when
     quantized, in which case ``k_scale``/``v_scale`` hold one fp32 scale
     per (layer, page, position, head) row (the wire codec's per-row grid
-    over D). The stack is a performance contract, not a convenience: every
-    block's pages share one page table, so the decode step's read half is
-    ONE gather and its write half ONE scatter, instead of 2 x depth tiny
-    ops each paying their own dispatch (measured ~6 ms/step of pure
-    overhead on the 8-device CPU mesh at depth 4).
+    over D). The shape is private to this module (`init_paged_kv`, the
+    gather, the scatters) and to the kernel that reads it.
+
+    Why this shape, on the chip (v5e, GPT-2 124M, 64 rows, a 2.42 GB
+    pool): at rest as (L, n_pages, page_size, H, D) XLA relaid the whole
+    pool out and back around the scatter and built the dense view twice,
+    145 ms of a 197.7 ms decode step (ledger, PR 24); in this shape the
+    scatter is in place and no pool-sized copy is left (PERF.md, PR 25).
+    The stack over layers stays: every block's pages share one page table,
+    so the write half of a step is ONE scatter.
 
     Page 0 is the SCRATCH page by convention (serving/paged.py): freed or
     unallocated table entries point at it, so a gather is always in-bounds
@@ -172,25 +188,43 @@ class PagedKV:
     v: jnp.ndarray
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    num_heads: int = flax.struct.field(pytree_node=False, default=1)
 
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
 
 
+@flax.struct.dataclass
+class PagedRead:
+    """What the S=1 decode step hands the model in place of dense cache
+    views when attention reads the pool in place (`ops.paged_attention`):
+    the pool, the page table (rows, P) and, per slot row, how many
+    positions to read from its pages — the row's position, 0 for a dead
+    row (the fresh token's own k/v never come from the pool). ``layer`` is
+    the block the read is for; the model sets it per block."""
+
+    pool: PagedKV
+    page_table: jnp.ndarray
+    live: jnp.ndarray
+    layer: int = flax.struct.field(pytree_node=False, default=0)
+
+
 def init_paged_kv(depth: int, n_pages: int, page_size: int, num_heads: int,
                   head_dim: int, dtype: Dtype = jnp.float32,
                   quantized: bool = False) -> PagedKV:
     """Zero-filled paged pool for ALL ``depth`` blocks (stacked axis 0)."""
-    shape = (depth, n_pages, page_size, num_heads, head_dim)
+    shape = (depth, n_pages, page_size, num_heads * head_dim)
     if quantized:
+        scales = (depth, n_pages, page_size, num_heads)
         return PagedKV(
             k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
-            k_scale=jnp.zeros(shape[:-1], jnp.float32),
-            v_scale=jnp.zeros(shape[:-1], jnp.float32))
+            k_scale=jnp.zeros(scales, jnp.float32),
+            v_scale=jnp.zeros(scales, jnp.float32), num_heads=num_heads)
     # k and v must be DISTINCT buffers: the serving step donates the whole
     # pool, and XLA rejects donating one buffer twice
-    return PagedKV(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    return PagedKV(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+                   num_heads=num_heads)
 
 
 def _dequant_pages(codes: jnp.ndarray, scales: jnp.ndarray,
@@ -220,25 +254,59 @@ def _quant_rows(x: jnp.ndarray, fused: Optional[bool] = None
 def gather_paged_kv(pkv: PagedKV, page_table: jnp.ndarray,
                     dtype: Dtype = jnp.float32
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-slot dense view of the whole pool: ``page_table`` (rows, P)
-    int32 -> (L, rows, P * page_size, H, D) k and v in ``dtype``
-    (dequantized when the pool is int8) — ONE gather covering every layer.
-    Per-layer slices of the result feed the bitwise-pinned
-    `decode_dot_product_attention` unchanged; positions beyond a slot's
-    write frontier carry scratch/stale (finite) values the caller's mask
-    zeroes exactly."""
+    """Per-slot dense view of the whole pool, the REFERENCE read:
+    ``page_table`` (rows, P) int32 -> (L, rows, P * page_size, H, D) k and
+    v in ``dtype`` (dequantized when the pool is int8) — one gather
+    covering every layer. Per-layer slices of the result feed the
+    bitwise-pinned `decode_dot_product_attention` unchanged; positions
+    beyond a slot's write frontier carry scratch/stale (finite) values the
+    caller's mask zeroes exactly."""
     rows, pages = page_table.shape
-    depth, _, ps = pkv.k.shape[:3]
+    depth, _, ps, width = pkv.k.shape
+    heads = pkv.num_heads
+    view = (depth, rows, pages * ps, heads, width // heads)
 
     def dense(codes, scales):
-        g = codes[:, page_table]              # (L, rows, P, ps, H, D)
-        g = g.reshape(depth, rows, pages * ps, *g.shape[4:])
+        g = codes[:, page_table].reshape(view)   # from (L, rows, P, ps, H*D)
         if scales is not None:
-            s = scales[:, page_table].reshape(depth, rows, pages * ps, -1)
+            s = scales[:, page_table].reshape(view[:-1])
             return _dequant_pages(g, s, dtype)
         return g.astype(dtype)
 
     return dense(pkv.k, pkv.k_scale), dense(pkv.v, pkv.v_scale)
+
+
+def _put_rows(pkv: PagedKV, page: jnp.ndarray, off: jnp.ndarray,
+              k_new: jnp.ndarray, v_new: jnp.ndarray,
+              fused: Optional[bool]) -> PagedKV:
+    """The one write all three scatters share: ``k_new`` / ``v_new``
+    (L, *idx, H, D) land at (page, off), both of shape ``idx``, in every
+    layer, as ONE row scatter over the pool's flattened
+    (L * n_pages * page_size, H*D) view — row ``(l * n_pages + page) *
+    page_size + off``. A write to drop arrives as ``page == n_pages`` and
+    leaves through the far end of the view (``mode="drop"``), so it can
+    never land in the next layer's page 0. The reshape is a bitcast and the
+    scatter in place: indexed as ``store.at[:, page, off]`` XLA copied the
+    whole pool into another layout and back (PERF.md, PR 25)."""
+    depth, n_pages, ps, _ = pkv.k.shape
+    flat = depth * n_pages * ps
+    row = (jnp.arange(depth).reshape((depth,) + (1,) * page.ndim)
+           * n_pages + page[None]) * ps + off[None]
+    row = jnp.where(page[None] < n_pages, row, flat).reshape(-1)
+
+    def put(store, fresh):
+        width = store.shape[-1]
+        return store.reshape(flat, width).at[row].set(
+            fresh.reshape(-1, width).astype(store.dtype),
+            mode="drop").reshape(store.shape)
+
+    if not pkv.quantized:
+        return pkv.replace(k=put(pkv.k, k_new), v=put(pkv.v, v_new))
+    kq, ks = _quant_rows(k_new, fused=fused)
+    vq, vs = _quant_rows(v_new, fused=fused)
+    return pkv.replace(k=put(pkv.k, kq), v=put(pkv.v, vq),
+                       k_scale=put(pkv.k_scale, ks),
+                       v_scale=put(pkv.v_scale, vs))
 
 
 @jax.named_scope("kv_scatter")
@@ -247,30 +315,19 @@ def scatter_paged_rows(pkv: PagedKV, page_table: jnp.ndarray,
                        v_rows: jnp.ndarray, active: jnp.ndarray,
                        fused: Optional[bool] = None) -> PagedKV:
     """Write ONE fresh (H, D) k/v row per slot per layer — ``k_rows`` /
-    ``v_rows`` are (L, rows, H, D) — at that slot's own position: the paged
-    decode step's write half, ONE scatter covering every layer.
+    ``v_rows`` are (L, rows, H, D), or (L, rows, H*D) as the kernel read
+    returns them — at that slot's own position: the paged decode step's
+    write half, ONE scatter covering every layer.
     ``positions`` (rows,) int32, ``active`` (rows,) bool: inactive rows are
-    dropped by pointing their write at an out-of-range page
-    (``mode="drop"``), so finished/free slots never touch the pool (the
-    token-granular join/leave substrate). ``fused`` is the int8 codec's
-    PR 6 tri-state (`_quant_rows`)."""
+    dropped by pointing their write at an out-of-range page, so
+    finished/free slots never touch the pool (the token-granular
+    join/leave substrate). ``fused`` is the int8 codec's PR 6 tri-state
+    (`_quant_rows`)."""
     n_pages, ps = pkv.k.shape[1], pkv.k.shape[2]
     rows = positions.shape[0]
     page = page_table[jnp.arange(rows), positions // ps]
     page = jnp.where(active, page, n_pages)         # drop inactive writes
-    off = positions % ps
-
-    def put(store, scale_store, fresh):
-        if scale_store is not None:
-            q, s = _quant_rows(fresh, fused=fused)
-            return (store.at[:, page, off].set(q, mode="drop"),
-                    scale_store.at[:, page, off].set(s, mode="drop"))
-        return (store.at[:, page, off].set(fresh.astype(store.dtype),
-                                           mode="drop"), None)
-
-    k, ks = put(pkv.k, pkv.k_scale, k_rows)
-    v, vs = put(pkv.v, pkv.v_scale, v_rows)
-    return PagedKV(k=k, v=v, k_scale=ks, v_scale=vs)
+    return _put_rows(pkv, page, positions % ps, k_rows, v_rows, fused)
 
 
 @jax.named_scope("kv_scatter")
@@ -291,19 +348,7 @@ def scatter_paged_window(pkv: PagedKV, page_table: jnp.ndarray,
     rows = positions.shape[0]
     page = page_table[jnp.arange(rows)[:, None], positions // ps]  # (rows, S)
     page = jnp.where(active, page, n_pages)         # drop inactive writes
-    off = positions % ps
-
-    def put(store, scale_store, fresh):
-        if scale_store is not None:
-            q, s = _quant_rows(fresh, fused=fused)
-            return (store.at[:, page, off].set(q, mode="drop"),
-                    scale_store.at[:, page, off].set(s, mode="drop"))
-        return (store.at[:, page, off].set(fresh.astype(store.dtype),
-                                           mode="drop"), None)
-
-    k, ks = put(pkv.k, pkv.k_scale, k_rows)
-    v, vs = put(pkv.v, pkv.v_scale, v_rows)
-    return PagedKV(k=k, v=v, k_scale=ks, v_scale=vs)
+    return _put_rows(pkv, page, positions % ps, k_rows, v_rows, fused)
 
 
 @jax.named_scope("kv_scatter")
@@ -319,22 +364,9 @@ def scatter_paged_prefill(pkv: PagedKV, page_row: jnp.ndarray,
     (identical params + identical tokens -> identical k/v, bitwise — the
     prefix-sharing safety argument)."""
     n_pages, ps = pkv.k.shape[1], pkv.k.shape[2]
-    s = k_seqs.shape[1]
-    idx = jnp.arange(s)
+    idx = jnp.arange(k_seqs.shape[1])
     page = jnp.where(idx < length, page_row[idx // ps], n_pages)
-    off = idx % ps
-
-    def put(store, scale_store, fresh):
-        if scale_store is not None:
-            q, sc = _quant_rows(fresh, fused=fused)
-            return (store.at[:, page, off].set(q, mode="drop"),
-                    scale_store.at[:, page, off].set(sc, mode="drop"))
-        return (store.at[:, page, off].set(fresh.astype(store.dtype),
-                                           mode="drop"), None)
-
-    k, ks = put(pkv.k, pkv.k_scale, k_seqs)
-    v, vs = put(pkv.v, pkv.v_scale, v_seqs)
-    return PagedKV(k=k, v=v, k_scale=ks, v_scale=vs)
+    return _put_rows(pkv, page, idx % ps, k_seqs, v_seqs, fused)
 
 
 def paged_kv_bytes(pool) -> int:
@@ -372,7 +404,7 @@ class MultiHeadAttention(nn.Module):
 
     KV cache (serving/): ``cache=(k, v)`` of shape (B, T, H, D) engages the
     incremental-decoding path and the call returns ``(out, new_cache)``.
-    Two cache writes exist:
+    Three cache forms exist:
 
     * prefill (``cache_positions=None``, S > 1 legal): the fresh k/v land
       in slots [0, S) and attention runs over the FRESH k/v with the
@@ -384,6 +416,10 @@ class MultiHeadAttention(nn.Module):
       rows at different prompt lengths advance independently with no
       recompile) and attention runs over the UPDATED cache under the
       caller's per-row validity mask.
+
+    * decode over the pool in place (``cache`` a `PagedRead`, S == 1): no
+      cache write and no view; `_attend_pool` returns the fresh k/v rows
+      as the new cache and the caller scatters them (the kernel read).
 
     With ``cache=None`` the path is byte-identical to the pre-cache module
     (pinned by tests/test_serving.py's lowering test).
@@ -418,8 +454,10 @@ class MultiHeadAttention(nn.Module):
                     "KV-cache decoding needs the XLA attention path — the "
                     "kernel attention_fns own their causal structure and "
                     "take no cache (serve with --attention xla)")
-            ck, cv = cache
-            if cache_positions is None:
+            if isinstance(cache, PagedRead):
+                y, new_cache = self._attend_pool(q, k, v, cache)
+            elif cache_positions is None:
+                ck, cv = cache
                 # prefill: the S fresh rows fill slots [0, S); attention
                 # runs over the FRESH k/v below (the eval computation)
                 new_cache = (
@@ -428,6 +466,7 @@ class MultiHeadAttention(nn.Module):
                     jax.lax.dynamic_update_slice(
                         cv, v.astype(cv.dtype), (0, 0, 0, 0)))
             else:
+                ck, cv = cache
                 # decode: per-row scatter at each row's own position, then
                 # attend over the updated cache. S == 1 is the classic
                 # one-token step; S > 1 is the speculative VERIFY window
@@ -461,6 +500,27 @@ class MultiHeadAttention(nn.Module):
                               dtype=self.dtype, param_dtype=self.param_dtype,
                               use_bias=self.use_bias, name="out")(y)
         return out if cache is None else (out, new_cache)
+
+    def _attend_pool(self, q, k, v, cache: PagedRead):
+        """Decode at S == 1 with the pool read in place (the kernel read):
+        no dense view and no row write. `ops.paged_attention` folds the
+        fresh k/v row in at the row's own position; the rows returned as
+        the new cache, (rows, H*D) in the pool's dtype, are what the caller
+        scatters into the pool, once, after the last block. The kernel
+        sits under ``kv_gather``: that region is "reading the cache,
+        attention over it included"."""
+        from ..ops.paged_attention import paged_attention
+
+        rows, features = q.shape[0], self.num_heads * self.head_dim
+        pool = cache.pool
+        k_row = k.reshape(rows, features).astype(pool.k.dtype)
+        v_row = v.reshape(rows, features).astype(pool.v.dtype)
+        with jax.named_scope("kv_gather"):
+            y = paged_attention(
+                q.reshape(rows, features), k_row, v_row, pool.k, pool.v,
+                cache.page_table, cache.live, layer=cache.layer,
+                num_heads=self.num_heads)
+        return y.reshape(q.shape).astype(self.dtype), (k_row, v_row)
 
     def _tp_call(self, x, mask, deterministic, cache, dense):
         """The explicit-TP attention body (tp_size > 1): local head slice,

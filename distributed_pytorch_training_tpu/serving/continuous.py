@@ -15,10 +15,13 @@ whole cycle. This module rebuilds the decode loop around SLOTS:
   substrate (`budget > 0` is liveness, inactive rows' cache writes are
   dropped).
 * The KV cache is the PAGED pool (models/layers.py): the decode step
-  gathers each slot's pages into the same dense view the bitwise-pinned
-  decode attention consumes, and scatters the one fresh row back. Page
-  residency is a host decision (serving/paged.py `PagePool`): prefix
-  sharing, eviction, int8 pages — none of it touches the compiled step.
+  reads each slot's pages and scatters the one fresh row back. On one TPU
+  with an unquantized pool the read is `ops.paged_attention`, page by
+  page, in place (`SlotEngine.kv_path` == "kernel"); everywhere else the
+  pages are gathered into the same dense view the bitwise-pinned decode
+  attention consumes ("gather", the reference read). Page residency is a
+  host decision (serving/paged.py `PagePool`): prefix sharing, eviction,
+  int8 pages — none of it touches the compiled step.
 * Sampling is threaded PER REQUEST like training threads per-step RNG
   keys: each slot carries its request's (key, temperature, top_p), and
   the token at absolute position ``q`` is sampled with
@@ -59,12 +62,17 @@ import numpy as np
 from .. import telemetry
 from ..data.pack import bucket_for
 from ..models.layers import (
+    PagedRead,
     dense_kv_bytes,
     gather_paged_kv,
     paged_kv_bytes,
     scatter_paged_prefill,
     scatter_paged_rows,
     scatter_paged_window,
+)
+from ..ops.paged_attention import (
+    paged_attention_backend_supported,
+    paged_attention_supports,
 )
 from ..parallel.mesh import batch_shard_count
 from ..parallel.sharding import batch_sharding, replicated
@@ -191,8 +199,10 @@ class SlotEngine(InferenceEngine):
             # be captured into last_buf (-1 = already captured — the
             # prefill path writes last_buf itself; a skip-admitted slot
             # never ran a prefill, so its first decode step captures the
-            # last-prompt logits here, bitwise the prefill's by the
-            # decode-vs-full parity pin)
+            # last-prompt logits here: bitwise the prefill's on the
+            # reference read in fp32 on the CPU mesh (the decode-vs-full
+            # parity pin), within rounding in bf16 on a TPU, either read
+            # (PARITY.md has the chip's measurement))
             "last_pos": jnp.full((rows,), -1, jnp.int32),
         }
 
@@ -279,45 +289,82 @@ class SlotEngine(InferenceEngine):
 
         return prefill
 
+    @property
+    def kv_path(self) -> str:
+        """How the decode step reads the pool: ``"kernel"`` —
+        `ops.paged_attention`, page by page, in place — where everything
+        it needs is visible when the step is traced: an unquantized pool
+        (the kernel reads no codes and scales), a one-device mesh (GSPMD
+        cannot partition a Mosaic kernel, the rule `_fused_quantize`
+        follows), a TPU backend and pages that are whole tiles; else
+        ``"gather"``, the reference read (`gather_paged_kv` +
+        `decode_dot_product_attention`), which every window program
+        (resume, speculative verify and draft) takes whatever this says.
+        No option selects it. The ``compile`` span of ``paged_decode``
+        carries it."""
+        cfg: PagedServeConfig = self.config
+        kernel = (cfg.kv_dtype != "int8" and self.mesh.size == 1
+                  and paged_attention_backend_supported()
+                  and paged_attention_supports(
+                      cfg.page_size, self.model.hidden_dim, self.model.dtype))
+        return "kernel" if kernel else "gather"
+
     def _make_paged_decode(self) -> Callable:
         """The decode step, its parts under `jax.named_scope`s (`kv_gather`,
         `model`, `kv_scatter`, `sample`, `bookkeeping`): trace-time metadata
         by which the benchmark's `batch_decode_*_ms` read a profiler trace
-        (`PAGED_DECODE` in benchmark/layer_metrics/_regions.py)."""
+        (`PAGED_DECODE` in benchmark/layer_metrics/_regions.py). On the
+        kernel read `kv_gather` lies inside `model`, around each block's
+        `paged_attention` call. One step on a v5e, GPT-2 124M in bf16, 64
+        rows of up to 1024 positions: 197.7 ms of device time on the gather
+        read with the pool at rest as (L, pages, page, H, D) (ledger, PR
+        24), 37.9 ms on the kernel read, of which the sampler is 34.9 and
+        the twelve kernel calls 1.8 (my chip runs, PR 25; PERF.md section
+        5)."""
         rows = self.config.rows
         fused = self._fused_quantize
+        kernel = self.kv_path == "kernel"
 
         def decode(served, pool, control, page_table):
             params = self._dequant(served)
             active = control["budget"] > 0
             positions = control["positions"]
             tok = control["tok"]
-            # read half: every slot's pages -> the dense view the
-            # bitwise-pinned decode attention consumes unchanged. The pool
-            # is layer-stacked, so this is ONE gather; the per-layer
-            # slices below are fused into their attention consumers.
-            with jax.named_scope("kv_gather"):
-                k_all, v_all = gather_paged_kv(pool, page_table,
-                                               dtype=self.model.dtype)
-                cache = tuple((k_all[l], v_all[l])
-                              for l in range(self.model.depth))
+            if kernel:
+                # read half, in place: each block's attention reads the
+                # slot's pages straight from the pool (positions below the
+                # row's own; a dead row reads none) and takes the fresh
+                # row as an input. The pool is read-only inside the model.
+                cache = PagedRead(pool=pool, page_table=page_table,
+                                  live=jnp.where(active, positions, 0))
+            else:
+                # read half, the reference: every slot's pages -> the
+                # dense view the bitwise-pinned decode attention consumes
+                # unchanged, per-layer slices of one gather
+                with jax.named_scope("kv_gather"):
+                    k_all, v_all = gather_paged_kv(pool, page_table,
+                                                   dtype=self.model.dtype)
+                    cache = tuple((k_all[l], v_all[l])
+                                  for l in range(self.model.depth))
             with jax.named_scope("model"):
                 logits, new_cache = self.model.apply(
                     self._apply_vars(params), tok[:, None], train=False,
                     cache=cache, cache_positions=positions)
-            # write half: ONE fresh (H, D) row per live slot per layer,
-            # restacked to (L, rows, H, D) -> ONE scatter back to the pool
+            # write half: ONE fresh row per live slot per layer, stacked
+            # over the layers -> ONE in-place scatter back to the pool
             with jax.named_scope("kv_scatter"):
-                idx = positions[:, None, None, None]
-                k_rows = jnp.stack([
-                    jnp.take_along_axis(k_new, idx, axis=1)[:, 0]
-                    for k_new, _ in new_cache])
-                v_rows = jnp.stack([
-                    jnp.take_along_axis(v_new, idx, axis=1)[:, 0]
-                    for _, v_new in new_cache])
-                new_pool = scatter_paged_rows(pool, page_table, positions,
-                                              k_rows, v_rows, active,
-                                              fused=fused)
+                if not kernel:
+                    # the views come back whole: each row's own position
+                    idx = positions[:, None, None, None]
+                    new_cache = [
+                        (jnp.take_along_axis(k_new, idx, axis=1)[:, 0],
+                         jnp.take_along_axis(v_new, idx, axis=1)[:, 0])
+                        for k_new, v_new in new_cache]
+                new_pool = scatter_paged_rows(
+                    pool, page_table, positions,
+                    jnp.stack([k_new for k_new, _ in new_cache]),
+                    jnp.stack([v_new for _, v_new in new_cache]),
+                    active, fused=fused)
             # the token at position p+1, from THIS request's key stream
             step_keys = jax.vmap(jax.random.fold_in)(
                 control["keys"], positions + 1)
@@ -329,8 +376,8 @@ class SlotEngine(InferenceEngine):
                 out_buf = control["out_buf"].at[
                     safe_row, control["emitted"]].set(nxt, mode="drop")
                 # a skip-admitted slot's first step captures the last-prompt
-                # logits the prefill would have stored (bitwise, by the
-                # decode-vs-full parity pin); -1 for everyone else
+                # logits the prefill would have stored (see `last_pos` in
+                # `_init_control`); -1 for everyone else
                 cap = positions == control["last_pos"]
                 new_control = dict(control)
                 new_control["tok"] = jnp.where(active, nxt, tok)
@@ -395,7 +442,11 @@ class SlotEngine(InferenceEngine):
         prefixes. fp32 pools only: an int8 skip would read dequantized
         pages where the cold prefill reads fresh fp32 — residency would
         change the emitted stream and break the router's same-seed-retry
-        determinism (PARITY.md documents the exclusion)."""
+        determinism (PARITY.md documents the exclusion). The kernel read
+        of the decode step keeps it on: on the v5e in bf16 a skip-admitted
+        request's token #0 was the cold one's for 8 prompts of 8 on either
+        read, and on neither are the kept logits the prefill's bits (my
+        chip run, PR 25; PARITY.md)."""
         cfg: PagedServeConfig = self.config
         return (cfg.prefix_sharing and cfg.prefix_skip
                 and cfg.kv_dtype == "fp32")
@@ -525,7 +576,9 @@ class SlotEngine(InferenceEngine):
                 "paged_skip": self.lower_paged_skip,
                 "paged_resume": lambda: self.lower_paged_resume(bucket),
             }[kind]()
-            with telemetry.span("compile", program=kind, bucket=bucket):
+            path = {"kv_path": self.kv_path} if kind == "paged_decode" else {}
+            with telemetry.span("compile", program=kind, bucket=bucket,
+                                **path):
                 self._compiled[key] = lowered.compile()
             self.compiles += 1
         return self._compiled[key]
@@ -573,8 +626,8 @@ class SlotEngine(InferenceEngine):
         """Admit a FULLY prefix-resident request with no forward at all:
         one control-only program arms the slot to enter the shared decode
         step at the resumed position (see `_make_paged_skip` — token #0
-        and the last-prompt logits come out of that step, bitwise the
-        prefill path's)."""
+        and the last-prompt logits come out of that step: bitwise the
+        prefill path's in fp32 on the CPU mesh, PARITY.md for a TPU)."""
         key = np.asarray(jax.random.PRNGKey(int(seed)), np.uint32)
         dev = lambda x: jax.device_put(x, self._rep)  # noqa: E731
         exe = self._executable("paged_skip", 0)
@@ -694,6 +747,12 @@ class ContinuousScheduler:
         telemetry.gauge("serving_slot_occupancy",
                         len(self.running) / max(cfg.rows, 1))
         telemetry.gauge("serving_page_pool_free", self.pool.free_pages())
+        # the share of the page table that is leased: what the kernel read
+        # of the decode step touches (the gather read touches all of it)
+        telemetry.gauge(
+            "serving_kv_live_page_share",
+            sum(st.lease.n_pages for st in self.running.values())
+            / max(cfg.rows * cfg.pages_per_slot, 1))
         # the router's load signal: everything accepted but unfinished
         # (HttpReplica.queue_depth scrapes this off /metrics)
         telemetry.gauge("serving_queue_depth",
