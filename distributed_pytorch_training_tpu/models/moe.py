@@ -189,6 +189,140 @@ class MoeMlp(nn.Module):
         return xin, combine_fn, frac_tokens
 
 
+class HeldExpertsMoe(nn.Module):
+    """One chip's share of a dropless top-k expert layer (the model-configs
+    guide's section 4): the router is ``num_experts`` wide and keeps its
+    ``top_k``, this module holds experts ``first_expert ..
+    first_expert + num_experts_held - 1`` and returns the part of the layer's
+    result that THOSE experts give. What the absent experts would add is left
+    out: there is no exchange here and nothing stands in for one.
+
+    Dropless on static shapes. The ``tokens * top_k`` assignments are sorted
+    by expert with the held experts first, so the held ones are a prefix of
+    the sorted order, grouped by expert, and the absent ones fall past the
+    last group; gated-SiLU experts run as grouped products
+    (`lax.ragged_dot`) over the first quarter of the sorted order (this
+    share expects 1/16 of all assignments under balanced routing), and over
+    the other quarters, one at a time, only where held assignments reach
+    them (a `lax.cond` on the count): no assignment to a held expert is ever
+    dropped, however many land on one, and the worst routing costs time,
+    not memory. ``moe_dropped_assignments`` (held assignments minus those a
+    group covered) is zero by construction; it is counted anyway and checked
+    by the tests and the benchmark.
+
+    Counters, sown into ``"counters"`` (training/tasks.py folds them into the
+    step's metrics): ``moe_held_assignments``, ``moe_dropped_assignments``,
+    ``moe_expert_load_max_over_mean`` (largest group over the mean group).
+    """
+
+    num_experts: int
+    num_experts_held: int
+    top_k: int
+    expert_dim: int
+    first_expert: int = 0
+    dtype: Dtype = jnp.float32
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        t, k, held = b * s, self.top_k, self.num_experts_held
+        if not 0 < held <= self.num_experts - self.first_expert:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + held - 1}"
+                f" are not among {self.num_experts}")
+        init = nn.initializers.normal(stddev=0.02)
+        router = self.param("router", init, (d, self.num_experts),
+                            self.param_dtype)
+        w_gate = self.param("gate", init, (held, d, self.expert_dim),
+                            self.param_dtype)
+        w_up = self.param("up", init, (held, d, self.expert_dim),
+                          self.param_dtype)
+        w_down = self.param("down", init, (held, self.expert_dim, d),
+                            self.param_dtype)
+        xf = x.reshape(t, d)
+
+        with jax.named_scope("moe_route"):
+            # the router in float32 over ALL experts: a top-k is a
+            # comparison, and a bf16 product would reorder near-ties
+            probs = jax.nn.softmax(jnp.dot(
+                xf.astype(jnp.float32), router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST), axis=-1)
+            top_p, top_e = jax.lax.top_k(probs, k)
+            kept = top_p / top_p.sum(-1, keepdims=True)  # norm_topk_prob
+            # held experts -> 0..held-1, absent ones -> held..E-1
+            local = ((top_e - self.first_expert) % self.num_experts
+                     ).reshape(t * k)
+            order = jnp.argsort(local, stable=True)
+            ends = jnp.searchsorted(local[order], jnp.arange(held + 1),
+                                    side="left").astype(jnp.int32)
+            group_sizes = ends[1:] - ends[:-1]
+            n_held = ends[-1]
+            token_of = (order // k).astype(jnp.int32)
+            weight_of = kept.reshape(t * k)[order]
+
+        all_rows = t * k
+        rows = min(all_rows, max(8, -(-all_rows // 4)))
+
+        def experts_on(start):
+            """The part of the result that sorted assignments ``start ..
+            start + rows - 1`` give, and how many of them a group covered."""
+            at = start + jnp.arange(rows)
+            in_group = at < n_held
+            token = jnp.take(token_of, at, mode="clip")
+            with jax.named_scope("moe_dispatch"):
+                # rows past the last group are masked on the way in as on
+                # the way out: a grouped product leaves them unwritten, in
+                # its transpose too, and what lies there must not reach a
+                # token's gradient through the gather's scatter-add
+                xin = jnp.where(in_group[:, None], xf[token], 0).astype(
+                    self.dtype)
+            with jax.named_scope("moe_experts"):
+                sizes = jnp.clip(ends[1:], start, start + rows) \
+                    - jnp.clip(ends[:-1], start, start + rows)
+                grouped = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+                    a, w.astype(self.dtype), sizes)
+                mid = nn.silu(grouped(xin, w_gate)) * grouped(xin, w_up)
+                out = grouped(mid, w_down)
+            with jax.named_scope("moe_dispatch"):
+                # a row past the last group holds no expert's result: it is
+                # masked BEFORE the product with its weight, so that the
+                # weight's gradient (row . cotangent) never multiplies what
+                # lies there, and the weight itself is masked too
+                weight = jnp.where(
+                    in_group, jnp.take(weight_of, at, mode="clip"), 0.0)
+                out = jnp.where(in_group[:, None], out, 0) \
+                    * weight[:, None].astype(out.dtype)
+                y = jnp.zeros((t, d), out.dtype).at[token].add(out)
+            return y, sizes.sum()
+
+        # the first quarter of the sorted order always; the other quarters
+        # only where held assignments reach them, one at a time and
+        # rematerialised, so the worst routing costs time and not memory
+        y, covered = experts_on(0)
+        if all_rows > rows:
+            def rest():
+                def more(carry, start):
+                    y_more, n_more = jax.checkpoint(experts_on)(start)
+                    return (carry[0] + y_more, carry[1] + n_more), None
+                return jax.lax.scan(
+                    more, (jnp.zeros_like(y), jnp.zeros_like(covered)),
+                    jnp.arange(rows, all_rows, rows))[0]
+            y_rest, n_rest = jax.lax.cond(
+                n_held > rows, rest,
+                lambda: (jnp.zeros_like(y), jnp.zeros_like(covered)))
+            y, covered = y + y_rest, covered + n_rest
+
+        mean = jnp.maximum(n_held, 1).astype(jnp.float32) / held
+        self.sow("counters", "moe_held_assignments",
+                 n_held.astype(jnp.float32))
+        self.sow("counters", "moe_dropped_assignments",
+                 (n_held - covered).astype(jnp.float32))
+        self.sow("counters", "moe_expert_load_max_over_mean",
+                 group_sizes.max().astype(jnp.float32) / mean)
+        return y.reshape(b, s, d).astype(self.dtype)
+
+
 def moe_rules() -> PartitionRules:
     """Expert-parallel rules: stacked expert weights split over ``expert``;
     the router stays replicated (it is tiny and every token needs it)."""

@@ -1,0 +1,142 @@
+"""The gated delta rule in chunks: the recurrence of a Gated DeltaNet layer
+(Yang et al., "Gated Delta Networks", arXiv:2412.06464) computed 64 positions
+at a time, in XLA operations, forward and (by XLA's own transpose) backward.
+
+Per head, with a state ``S`` of shape (key, value) starting at zero::
+
+    S   = exp(g_t) * S                  # decay, g_t <= 0
+    u_t = (v_t - S^T k_t) * beta_t      # what the state does not hold yet
+    S   = S + k_t u_t^T
+    o_t = S^T q_t
+
+`gated_delta_rule_stepwise` is that loop, position by position (the tests'
+oracle for the chunked form, one `lax.scan` step a position). The chunked
+form is the WY representation the reference implementations use: within a
+chunk the ``u_t`` solve a unit lower-triangular system ``(I + A) U = beta *
+(V - exp(g) K S_in)`` with ``A[i, j] = beta_i (k_i . k_j) exp(g_i - g_j)`` for
+``j < i``, so one triangular solve gives every ``u_t`` of the chunk from the
+state the chunk started with, and the state moves once a chunk. Everything
+that does not need the incoming state (the solve, the in-chunk scores) is
+computed for all chunks at once; the state is carried by a `lax.scan` over
+chunks, not unrolled.
+
+Precision: the state, the decays and the triangular solve are float32
+whatever the inputs' dtype, and every product is taken at PRECISION (HIGHEST:
+on a TPU a float32 product otherwise runs as one bf16 pass, which would make
+the state bf16 in all but name). PARITY.md has the boundary.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+CHUNK = 64
+PRECISION = lax.Precision.HIGHEST
+
+
+def gated_delta_rule_stepwise(q, k, v, g, beta):
+    """The recurrence itself. q, k: (B, S, H, Dk); v: (B, S, H, Dv); g,
+    beta: (B, S, H). Returns (B, S, H, Dv) float32."""
+    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    b, _, h, dk = q.shape
+    hi = PRECISION
+
+    def step(state, xs):
+        q_t, k_t, v_t, g_t, b_t = xs
+        state = state * jnp.exp(g_t)[..., None, None]
+        held = jnp.einsum("bhkv,bhk->bhv", state, k_t, precision=hi)
+        u = (v_t - held) * b_t[..., None]
+        state = state + jnp.einsum("bhk,bhv->bhkv", k_t, u, precision=hi)
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t, precision=hi)
+
+    xs = tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta))
+    _, out = lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32),
+                      xs)
+    return jnp.moveaxis(out, 0, 1)
+
+
+def gated_delta_rule(q, k, v, g, beta, *, head_block: int):
+    """The same result as `gated_delta_rule_stepwise`, CHUNK positions at a
+    time. A length that is no multiple of CHUNK is padded with
+    positions that leave the state alone (k = v = 0, beta = 0, g = 0).
+
+    Heads are independent, and the float32 intermediates of all of them at
+    once are what a layer's backward holds most of (3.5 GB for 32 heads of
+    128 at S=8192). So the heads go ``min(head_block, H)`` at a time through
+    a `lax.map` whose body is rematerialised: the backward holds one block's
+    intermediates and pays the block's forward once more. One path: a head
+    count the block does not divide is an error, not another program."""
+    h = q.shape[2]
+    block = min(head_block, h)
+    if block < 1 or h % block:
+        raise ValueError(f"gated_delta_rule: head_block {head_block} does "
+                         f"not divide the {h} heads")
+
+    def blocks(x):   # (B, S, H, ...) -> (H / block, B, S, block, ...)
+        x = x.reshape(*x.shape[:2], h // block, block, *x.shape[3:])
+        return jnp.moveaxis(x, 2, 0)
+
+    out = lax.map(jax.checkpoint(lambda xs: _chunked_rule(*xs)),
+                  tuple(blocks(x) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(out, 0, 2).reshape(*q.shape[:3], v.shape[-1])
+
+
+def _chunked_rule(q, k, v, g, beta):
+    chunk = CHUNK
+    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = -s % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    n = (s + pad) // chunk
+
+    def chunked(x):   # (B, S, H, ...) -> (N, B, H, C, ...)
+        x = x.reshape(b, n, chunk, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    q, k, v, g, beta = (chunked(x) for x in (q, k, v, g, beta))
+    dot = lambda spec, x, y: jnp.einsum(spec, x, y,  # noqa: E731
+                                        precision=PRECISION)
+
+    # decays inside a chunk: gc_i = sum of g up to and including i, so
+    # exp(gc_i - gc_j) carries position j's write to position i >= j. The
+    # differences are masked BEFORE the exponential: above the diagonal they
+    # are positive and would overflow under strong decay.
+    gc = jnp.cumsum(g, axis=-1)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    diff = jnp.where(lower, gc[..., :, None] - gc[..., None, :], 0.0)
+    decay = jnp.where(lower, jnp.exp(diff), 0.0)
+
+    k_beta = k * beta[..., None]
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    a = jnp.where(strict, dot("nbhik,nbhjk->nbhij", k_beta, k) * decay, 0.0)
+    rhs = jnp.concatenate(
+        [v * beta[..., None], k_beta * jnp.exp(gc)[..., None]], axis=-1)
+    # (I + A) X = rhs: unit lower triangular, one solve for both halves
+    solved = lax.linalg.triangular_solve(
+        a + jnp.eye(chunk, dtype=a.dtype), rhs, left_side=True, lower=True,
+        unit_diagonal=True)
+    v_own, k_cum = solved[..., :dv], solved[..., dv:]
+    scores = dot("nbhik,nbhjk->nbhij", q, k) * decay
+    q_in = q * jnp.exp(gc)[..., None]                # reads the old state
+    g_end = gc[..., -1]
+    k_out = k * jnp.exp(g_end[..., None] - gc)[..., None]   # writes to the end
+
+    def step(state, xs):
+        v_own_c, k_cum_c, scores_c, q_in_c, k_out_c, g_end_c = xs
+        u = v_own_c - dot("bhik,bhkv->bhiv", k_cum_c, state)
+        out = dot("bhik,bhkv->bhiv", q_in_c, state) \
+            + dot("bhij,bhjv->bhiv", scores_c, u)
+        state = state * jnp.exp(g_end_c)[..., None, None] \
+            + dot("bhik,bhiv->bhkv", k_out_c, u)
+        return state, out
+
+    _, out = lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32),
+                      (v_own, k_cum, scores, q_in, k_out, g_end))
+    out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 3, 2)   # (B, N, C, H, Dv)
+    return out.reshape(b, n * chunk, h, dv)[:, :s]

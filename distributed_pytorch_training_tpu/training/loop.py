@@ -1616,7 +1616,8 @@ class Trainer:
         cfg = self.config
         epoch_key = jax.random.fold_in(jax.random.PRNGKey(cfg.seed), epoch)
 
-        epoch_metrics = zero_metrics()
+        epoch_metrics = None   # the first step's metrics name the sums
+        counters_seen = (0, {})   # (steps, sums) at the last print boundary
         # perf_counter, not time.time(): an NTP step mid-epoch would
         # corrupt the CSV's epoch_time_seconds (the ThroughputMeter got
         # the same fix)
@@ -1656,7 +1657,8 @@ class Trainer:
                 watchdog.observe_step(start_step + i,
                                       data_wait_s + dispatch_s,
                                       data_wait_s=data_wait_s)
-            epoch_metrics = add_metrics(epoch_metrics, metrics)
+            epoch_metrics = metrics if epoch_metrics is None \
+                else add_metrics(epoch_metrics, metrics)
             steps_done = i + 1
             # sample count is host-known (sampler math), no device fetch:
             if samples_per_step is not None:
@@ -1670,6 +1672,9 @@ class Trainer:
                 # Like the reference, the printed loss/acc are the epoch
                 # running averages (ref :230-231).
                 avg_loss, avg_acc = summarize(epoch_metrics)
+                counters_seen = self._emit_step_counters(
+                    epoch_metrics, steps_done, counters_seen,
+                    step=start_step + i, epoch=epoch)
                 if watchdog is not None:
                     # the loop's only host fetch — the non-finite-loss
                     # detector rides it instead of adding a sync
@@ -1696,6 +1701,8 @@ class Trainer:
         # Epoch totals: weighted sums are already global (the batch was the
         # global batch) — the reference needs 3 all-reduces here (ref :251-253);
         # we need none.
+        if epoch_metrics is None:
+            epoch_metrics = zero_metrics()
         with telemetry.span("device_sync", epoch=epoch):
             jax.block_until_ready(epoch_metrics["weight"])
         epoch_time = time.perf_counter() - t_epoch
@@ -1706,10 +1713,34 @@ class Trainer:
         loss, acc = summarize(epoch_metrics)
         return state, loss, acc, epoch_time, steps_done
 
+    @staticmethod
+    def _emit_step_counters(epoch_metrics, steps_done, seen, **attrs):
+        """At a print boundary, what the model's counters (`tasks.
+        step_counters`) added since the last one: a name ending in
+        ``_max_over_mean`` as a gauge of its mean over those steps, any other
+        as a counter of its total with the number of steps beside it. Read
+        from the sums the boundary fetches anyway; a model without counters
+        costs nothing here."""
+        sums = epoch_metrics.get("counters")
+        if not sums or not telemetry.is_configured():
+            return seen
+        steps_before, before = seen
+        steps = steps_done - steps_before
+        now = {name: float(total) for name, total in sums.items()}
+        for name, total in now.items():
+            added = total - before.get(name, 0.0)
+            if name.endswith("_max_over_mean"):
+                telemetry.gauge(name, added / max(steps, 1), **attrs)
+            else:
+                telemetry.counter(name, added, steps=steps, **attrs)
+        return steps_done, now
+
     def evaluate(self, state: TrainState, batches: Iterable) -> Tuple[float, float]:
         """Sharded validation (maps validate, ref :266-300)."""
         with telemetry.span("eval"):
-            totals = zero_metrics()
+            totals = None   # the first step's metrics name the sums
             for batch in batches:
-                totals = add_metrics(totals, self._eval_step(state, batch))
-            return summarize(totals)
+                metrics = self._eval_step(state, batch)
+                totals = metrics if totals is None \
+                    else add_metrics(totals, metrics)
+            return summarize(zero_metrics() if totals is None else totals)

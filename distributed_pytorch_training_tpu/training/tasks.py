@@ -115,8 +115,8 @@ class LanguageModelingTask(Task):
         # "dropout" stream available at train time.
         rngs = {"dropout": rng} if train else None
         logits, mutated = state.apply_fn(
-            {"params": params}, ids, train=train, mutable=["losses"],
-            rngs=rngs)
+            {"params": params}, ids, train=train,
+            mutable=["losses", "counters"], rngs=rngs)
         # `loss`: the vocab-wide work after the model (PERF.md section 3)
         with jax.named_scope("loss"):
             # shift: predict ids[:, 1:] from logits[:, :-1]
@@ -147,6 +147,9 @@ class LanguageModelingTask(Task):
             correct = (predicted * w).sum()
             metrics = {"loss_sum": (per_tok * w).sum(), "correct": correct,
                        "weight": wsum}
+        counters = step_counters(mutated.get("counters", {}))
+        if counters:
+            metrics["counters"] = counters
         return loss, (metrics, state.batch_stats)
 
 
@@ -199,12 +202,37 @@ class MaskedLMTask(Task):
         return loss, (metrics, state.batch_stats)
 
 
+def step_counters(sown) -> Metrics:
+    """What a model sowed into its ``"counters"`` collection (one scalar per
+    module and name, e.g. `models.moe.HeldExpertsMoe`), folded to one value
+    per name for the step: the sum over modules, or, for a name ending in
+    ``_max_over_mean``, the worst module. They ride the step's metrics in a
+    dict of their own, ``metrics["counters"]``, so the loop fetches them with
+    the loss at a print boundary and not apart. A model that sows nothing
+    adds nothing."""
+    by_name: Dict[str, list] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(sown):
+        name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+        by_name.setdefault(name, []).append(jnp.asarray(leaf, jnp.float32))
+    return {name: (jnp.max if name.endswith("_max_over_mean") else jnp.sum)(
+        jnp.stack(leaves)) for name, leaves in by_name.items()}
+
+
 def zero_metrics() -> Metrics:
     return {"loss_sum": jnp.zeros(()), "correct": jnp.zeros(()),
             "weight": jnp.zeros(())}
 
 
 def add_metrics(a: Metrics, b: Metrics) -> Metrics:
+    """``a + b``, name by name. Both sides hold the same names: a sum that
+    starts from `zero_metrics` cannot take a step's ``"counters"``, and says
+    so rather than let them go."""
+    if jax.tree_util.tree_structure(a) != jax.tree_util.tree_structure(b):
+        raise ValueError(
+            f"add_metrics: {sorted(a)} and {sorted(b)} differ; a model's "
+            "step counters are summed from the first step's metrics "
+            "(Trainer.train_epoch, Trainer.evaluate), not from zero_metrics "
+            "(the microbatch loops of grad_accum > 1 do not carry them)")
     return jax.tree_util.tree_map(jnp.add, a, b)
 
 

@@ -238,7 +238,8 @@ def _run(args, guard):
 
     compute_dtype = jnp.bfloat16 if args.amp else jnp.float32
     overrides = parse_model_overrides(args.model_overrides)
-    is_lm = args.model.startswith(("gpt2", "bert"))
+    is_lm = args.model.startswith(("gpt2", "bert", "qwen3_next"))
+    # `family` picks the token data and the task: every causal LM is "gpt2"
     family = "bert" if args.model.startswith("bert") else "gpt2"
     resolved_seq = args.seq_len or (512 if family == "bert" else 1024)
     attention = resolve_attention(args.attention, is_lm,
