@@ -1,6 +1,11 @@
 """The gated delta rule in chunks: the recurrence of a Gated DeltaNet layer
 (Yang et al., "Gated Delta Networks", arXiv:2412.06464) computed 64 positions
-at a time, in XLA operations, forward and (by XLA's own transpose) backward.
+at a time. Two forms of one result: in a one-device program on a TPU the
+kernel pair of `gdn_rule_kernels` (`gdn_rule_fwd`, `gdn_rule_bwd`: a chunk's
+intermediates in VMEM, the rule's own backward); everywhere else XLA
+operations, forward and (by XLA's own transpose) backward, which is also the
+tests' second oracle beside the recurrence. `gated_delta_rule` chooses by
+what it can observe of the backend, the shapes and the trace: no option.
 
 Per head, with a state ``S`` of shape (key, value) starting at zero::
 
@@ -10,17 +15,19 @@ Per head, with a state ``S`` of shape (key, value) starting at zero::
     o_t = S^T q_t
 
 `gated_delta_rule_stepwise` is that loop, position by position (the tests'
-oracle for the chunked form, one `lax.scan` step a position). The chunked
+oracle for both chunked forms, one `lax.scan` step a position). The chunked
 form is the WY representation the reference implementations use: within a
 chunk the ``u_t`` solve a unit lower-triangular system ``(I + A) U = beta *
 (V - exp(g) K S_in)`` with ``A[i, j] = beta_i (k_i . k_j) exp(g_i - g_j)`` for
 ``j < i``, so one triangular solve gives every ``u_t`` of the chunk from the
-state the chunk started with, and the state moves once a chunk. Everything
-that does not need the incoming state (the solve, the in-chunk scores) is
-computed for all chunks at once; the state is carried by a `lax.scan` over
-chunks, not unrolled.
+state the chunk started with, and the state moves once a chunk. In the XLA
+form everything that does not need the incoming state (the solve, the
+in-chunk scores) is computed for all chunks at once and the state is carried
+by a `lax.scan` over chunks, not unrolled; the kernels make the same
+quantities a chunk at a time and invert ``I + A`` by block substitution
+(their module has why that is exact).
 
-Precision: the state, the decays and the triangular solve are float32
+Precision, both forms: the state, the decays and the solve are float32
 whatever the inputs' dtype, and every product is taken at PRECISION (HIGHEST:
 on a TPU a float32 product otherwise runs as one bf16 pass, which would make
 the state bf16 in all but name). PARITY.md has the boundary.
@@ -31,6 +38,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from .gdn_rule_kernels import (
+    gated_delta_rule_kernels, gdn_rule_backend_supported,
+    gdn_rule_one_device_trace, gdn_rule_supports,
+)
 
 CHUNK = 64
 PRECISION = lax.Precision.HIGHEST
@@ -62,22 +74,38 @@ def gated_delta_rule(q, k, v, g, beta, *, head_block: int):
     time. A length that is no multiple of CHUNK is padded with
     positions that leave the state alone (k = v = 0, beta = 0, g = 0).
 
-    Heads are independent, and the float32 intermediates of all of them at
-    once are what a layer's backward holds most of (3.5 GB for 32 heads of
-    128 at S=8192). So the heads go ``min(head_block, H)`` at a time through
-    a `lax.map` whose body is rematerialised: the backward holds one block's
-    intermediates and pays the block's forward once more. One path: a head
-    count the block does not divide is an error, not another program."""
+    Two paths, and no option chooses between them. The kernel pair of
+    `gdn_rule_kernels` holds a chunk's intermediates in VMEM and needs no
+    ``head_block`` (the argument is checked and otherwise unused there). It
+    is taken where all three of its module's gates hold: the backend is a
+    TPU, the shapes are those the kernels were compiled for (an even head
+    count, head sizes in multiples of 128), and the program being traced is
+    one device's (one device in the process, or inside a `shard_map`): GSPMD
+    cannot partition a Mosaic kernel, so a multi-device GSPMD program keeps
+    the XLA form, which it partitions as any other operations. Everywhere
+    else, that XLA form, below: heads are
+    independent, and the float32 intermediates of all of them at once are
+    what a layer's backward holds most of (3.5 GB for 32 heads of 128 at
+    S=8192), so the heads go ``min(head_block, H)`` at a time through a
+    `lax.map` whose body is rematerialised: the backward holds one block's
+    intermediates and pays the block's forward once more. A head count the
+    block does not divide is an error on both paths, not another program."""
     h = q.shape[2]
     block = min(head_block, h)
     if block < 1 or h % block:
         raise ValueError(f"gated_delta_rule: head_block {head_block} does "
                          f"not divide the {h} heads")
+    if gdn_rule_backend_supported() \
+            and gdn_rule_supports(h, q.shape[-1], v.shape[-1]) \
+            and gdn_rule_one_device_trace():
+        return gated_delta_rule_kernels(q, k, v, g, beta)
 
     def blocks(x):   # (B, S, H, ...) -> (H / block, B, S, block, ...)
         x = x.reshape(*x.shape[:2], h // block, block, *x.shape[3:])
         return jnp.moveaxis(x, 2, 0)
 
+    # `_chunked_rule` is looked up at call time: the benchmark's test of its
+    # layer check replaces it
     out = lax.map(jax.checkpoint(lambda xs: _chunked_rule(*xs)),
                   tuple(blocks(x) for x in (q, k, v, g, beta)))
     return jnp.moveaxis(out, 0, 2).reshape(*q.shape[:3], v.shape[-1])

@@ -1,0 +1,577 @@
+"""The chunked gated delta rule as a pair of Pallas TPU kernels with the
+rule's own backward (`gdn_rule_fwd`, `gdn_rule_bwd`): what
+`ops/gated_delta_rule.py::gated_delta_rule` runs in a one-device program on
+a TPU.
+
+One grid step is one chunk of CHUNK positions of eight heads. The chunk axis
+is the innermost, sequential one; each head's (key, value) state lives in
+VMEM scratch across it, zeroed at chunk 0. Everything a chunk needs besides
+that state (the in-chunk decays, ``A``, the inverse of ``I + A``, the scores)
+is made in VMEM from the chunk's rows of q, k, v, g, beta: nothing per chunk
+goes to HBM but the output rows and the backward's two residuals. q, k, v
+are read as the mixer holds them, ``(B, S, H * D)`` (a reshape, no copy);
+only g and beta, a megabyte each, are regrouped to ``(B, H / heads, S,
+heads)`` so that a step's block of them is whole in its last dimension.
+
+The mathematics is `gated_delta_rule._chunked_rule`'s (its docstring has the
+derivation), in another order of summation::
+
+    gc = cumsum(g); Gamma[i, j] = exp(gc_i - gc_j) for j <= i
+    A = strictly_lower(beta_i (k_i . k_j) Gamma[i, j]);  T = (I + A)^-1
+    u = T (beta * (v - exp(gc) * (k S)))
+    o = (exp(gc) * q) S + ((q k^T) * Gamma) u
+    S' = exp(gc_end) S + (k * exp(gc_end - gc))^T u
+
+What a product costs here is less its arithmetic than its being one: at
+HIGHEST a float32 product is six bf16 passes over three loads of its right
+side, whatever part of the 128 x 128 unit a 64-wide tile fills. So products
+that share a right side are stacked into one (``[k_beta; q] k^T``,
+``[k; q_in] S``), and the heads go in PACKs of two whose (CHUNK, CHUNK) tables
+lie side by side in one (CHUNK, 128) array: a product of two tables is then
+one product for both heads, its right side laid out block-diagonally (the
+ten of the inverse are most of a chunk's products). And since those ten wait
+on each other, the four packs of a grid step are advanced in turn, one
+product each (`_in_turn`), so that independent products stand side by side.
+
+The solve: Mosaic lowers no `triangular_solve`, so ``T`` is formed, by block
+forward substitution written as products. ``I + A`` restricted to its 2 x 2
+diagonal blocks has the exact inverse ``I - A_2``; and if ``T_b`` inverts the
+b x b diagonal blocks, with ``off`` the part of ``A`` that joins the two
+halves of each 2b x 2b block, then ``I + A_2b = (I + A_b)(I + T_b off)`` and
+``(T_b off)^2 = 0`` (it maps first halves to second halves only), so
+``T_2b = T_b - T_b off T_b`` exactly. Five doublings reach 64: ten products
+a chunk. It is exact in exact arithmetic, and in floating point it is
+substitution, not a power series: the Neumann product ``(I - A)(I + A^2) ...
+(I + A^32)`` costs the same ten products but passes through ``A^n``, whose
+entries reach binomial(63, n) when the keys of a chunk repeat (a run of one
+token) and cancel catastrophically.
+
+The backward runs the chunks in reverse with the state's cotangent in VMEM
+scratch, recomputes a chunk's other intermediates, and gives gradients for
+all five inputs. Residuals besides the inputs: the state each chunk STARTED
+from, ``(B, S / CHUNK, H, Dk, Dv)`` float32 (268 MB a layer at 8,192 x 32
+heads of 128 x 128), and each chunk's ``T``, packed, ``(B, S / CHUNK, H /
+PACK, CHUNK, PACK * CHUNK)`` (67 MB): a quarter of the states for ten of the
+backward's thirty products. They are outputs of the differentiated forward
+only (under the layer's remat its first pass is that one too); a forward
+that nothing differentiates (evaluation, the benchmark's rule check) is the
+same kernel without the two outputs and puts neither in HBM.
+
+Where it runs (`gated_delta_rule` asks the three gates below): on a TPU, for
+heads in whole PACKs of sizes in whole 128-lane tiles, in a program that is
+one device's. A multi-device GSPMD program takes the XLA form, as it did
+before there were kernels: GSPMD cannot partition a Mosaic kernel, and no
+mesh reaches the rule to `shard_map` it over.
+
+Precision: every product is float32 at HIGHEST, the state, the decays and
+``T`` are float32, inputs are cast up on the chip and never rounded down.
+"""
+
+from __future__ import annotations
+
+import functools
+import itertools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+CHUNK = 64              # the kernels' own: ten 64-wide products invert I + A
+HEADS_PER_STEP = 8      # at most: four packs in turn a step; 16 measured the same
+PACK = 128 // CHUNK     # heads whose (CHUNK, CHUNK) tables share 128 lanes
+
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+def gdn_rule_backend_supported() -> bool:
+    """The backend's gate of the kernel path (`flash_backend_supported` and
+    `paged_attention_backend_supported` are its siblings): a TPU. On the CPU
+    the kernels run in interpreter mode, for the tests only."""
+    return jax.default_backend() == "tpu"
+
+
+def gdn_rule_supports(heads: int, dk: int, dv: int) -> bool:
+    """The shapes the kernels are written for and were compiled for on the
+    chip: heads in whole PACKs, and head sizes of whole 128-lane tiles (a
+    step's block of q, k or v is then lane-aligned whatever the group). The
+    interpreter has no tiles and takes any head size."""
+    return heads % PACK == 0 and (
+        _interpret() or (dk % 128 == 0 and dv % 128 == 0))
+
+
+def gdn_rule_one_device_trace() -> bool:
+    """Whether what is being traced is one device's program. GSPMD cannot
+    partition a Mosaic kernel (a lowering error on any multi-device program:
+    `make_flash_attention_fn` has the story), and the rule, unlike
+    attention, is handed no mesh to `shard_map` itself over. Inside a
+    `shard_map` whose axes of any size are all manual the operands are one
+    shard's, and that is seen here. Outside one, how many devices a jitted
+    program spans is decided after the trace, by its operands' shardings:
+    all a trace can know is whether the process has more than one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.manual_axes:
+        return all(size == 1 for axis, size in mesh.shape.items()
+                   if axis not in mesh.manual_axes)
+    return jax.device_count() == 1
+
+
+def _dot(x, y, dims):
+    return lax.dot_general(x, y, (dims, ((), ())),
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _total(x):   # (1, 1): the sum of a 2-D array
+    return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
+
+
+def _rowsum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _stack(parts):
+    return jnp.concatenate(parts, axis=0)
+
+
+# A PACK of heads shares every (CHUNK, CHUNK) table: head h of the pack owns
+# lanes [h * CHUNK, (h + 1) * CHUNK) of one (CHUNK, PACK * CHUNK) array, so
+# that a table fills the 128 lanes of a vector register and of the matrix
+# unit, and a product of two tables is ONE product for the pack: the left
+# side as it is, the right side laid out block-diagonally.
+
+def _lanes(parts):
+    return jnp.concatenate(parts, axis=1)
+
+
+def _diagonal(parts):
+    """(p * rows, p * d): ``parts[h]`` (rows, d) as the h-th diagonal block
+    of zeros elsewhere."""
+    zero = jnp.zeros_like(parts[0])
+    return _stack([_lanes([part if i == h else zero
+                           for i in range(len(parts))])
+                   for h, part in enumerate(parts)])
+
+
+def _diagonal_blocks(x, p):
+    """The p diagonal blocks of ``x`` (p * rows, p * d)."""
+    rows, d = x.shape[0] // p, x.shape[1] // p
+    return [x[h * rows:(h + 1) * rows, h * d:(h + 1) * d] for h in range(p)]
+
+
+def _split(x, p):
+    d = x.shape[1] // p
+    return [x[:, h * d:(h + 1) * d] for h in range(p)]
+
+
+class _Pack:
+    """One chunk of a pack of heads: first what needs no state (the decay
+    table, ``A`` and its inverse, the scores, as packed tables), then, given
+    each head's ``k S``, the chunk's ``u``. The forward reads its output
+    from these; the backward recomputes them, all but the inverse ``t``,
+    which it is handed. Per-head quantities are lists over the pack.
+
+    A pack's products wait on each other (the inverse alone is a chain of
+    ten), those of another pack of the same grid step do not. So the work
+    is written as generators that ``yield`` wherever a product's result is
+    awaited, and `_in_turn` advances the packs of a step one product each:
+    in program order independent products then stand side by side, and the
+    matrix units overlap them (by that order alone the forward went from
+    8.6 to 6.5 ms at two packs a step, and to 5.8 at four)."""
+
+    def __init__(self, qs, ks, vs, gs, gcs, afters, betas):
+        c, p = CHUNK, len(qs)
+        self.p, self.qs, self.ks, self.vs, self.betas = p, qs, ks, vs, betas
+        self.gs = gs
+        row = lax.broadcasted_iota(jnp.int32, (c, p * c), 0)
+        lane = lax.broadcasted_iota(jnp.int32, (c, p * c), 1)
+        col, self.owner = lane & (c - 1), lane >> (c.bit_length() - 1)
+        self.row, self.col = row, col
+        self.lower, self.strict = row >= col, row > col
+        self.k_betas = [k * beta for k, beta in zip(ks, betas)]
+        self.intos = [jnp.exp(gc) for gc in gcs]     # read the old state
+        self.q_ins = [q * into for q, into in zip(qs, self.intos)]
+        self.to_ends = [jnp.exp(after) for after in afters]   # write to the
+        self.k_outs = [k * e for k, e in zip(ks, self.to_ends)]   # chunk end
+        last = lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+        self.keeps = [                               # (1, 1): a state's decay
+            jnp.exp(jnp.sum(jnp.where(last, gc, 0.0), axis=0, keepdims=True))
+            for gc in gcs]
+
+    def tables(self, t=None):
+        """The decay table, ``A``, the scores and ``t``: the inverse of
+        ``I + A`` by the doubling of the module's docstring, unless given."""
+        c = CHUNK
+        # the decay table's exponent gc_i - gc_j = sum of g over (j, i],
+        # summed as such: [m <= i] times g_m [m > j]. As a difference of two
+        # running sums it would lose, under strong decay (gc near -1e3 by
+        # the chunk's end), the digits that tell neighbours apart. Zero on
+        # and above the diagonal by construction, so nothing overflows.
+        table = _dot(self.lower[:, :c].astype(jnp.float32),
+                     jnp.where(self.strict, self.spread(self.gs), 0.0), _NN)
+        yield
+        self.decay = jnp.where(self.lower, jnp.exp(table), 0.0)
+        # [k_beta; q] k^T: A's and the scores' products share their right side
+        both = _dot(_stack([_lanes(self.k_betas), _lanes(self.qs)]),
+                    _diagonal(self.ks), _NT)
+        yield
+        self.a = jnp.where(self.strict, both[:c] * self.decay, 0.0)
+        self.scores = both[c:] * self.decay
+        if t is not None:
+            self.t = t
+            return
+
+        def same(log2_block):
+            return (self.row >> log2_block) == (self.col >> log2_block)
+
+        t = (self.row == self.col).astype(jnp.float32) \
+            - jnp.where(same(1), self.a, 0.0)
+        for log2_block in range(1, c.bit_length() - 1):
+            off = jnp.where(same(log2_block + 1) & ~same(log2_block),
+                            self.a, 0.0)
+            inner = self.times(off, t)
+            yield
+            t = t - self.times(t, inner)
+            yield
+        self.t = t
+
+    def spread(self, columns):
+        """(CHUNK, p * CHUNK): head h's (CHUNK, 1) column along its lanes."""
+        out = jnp.broadcast_to(columns[0], self.owner.shape)
+        for h in range(1, self.p):
+            out = jnp.where(self.owner == h, columns[h], out)
+        return out
+
+    def own(self, table, h):
+        """``table`` with the lanes of the pack's other heads zeroed."""
+        return jnp.where(self.owner == h, table, 0.0)
+
+    def times(self, x, y):
+        """Head by head ``x_h y_h`` of two packed tables, as one product."""
+        return _dot(x, _stack([self.own(y, h) for h in range(self.p)]), _NN)
+
+    def solve(self, helds):
+        """``helds[h]`` = k_h S_h, what the incoming state already holds."""
+        self.helds = helds
+        self.missings = [v - into * held for v, into, held
+                         in zip(self.vs, self.intos, helds)]
+        self.us = _split(_dot(self.t, _diagonal(
+            [beta * m for beta, m in zip(self.betas, self.missings)]), _NN),
+            self.p)
+
+
+def _in_turn(steps):
+    """Advances the generators one ``yield`` each, round after round."""
+    for _ in itertools.zip_longest(*steps):
+        pass
+
+
+def _running_sums(g):
+    """For a (CHUNK, heads) block of g: ``gc`` = the sum up to and including
+    each row, and ``after`` = the sum of the rows after it, each as one
+    product with a 0/1 triangle (exact at HIGHEST)."""
+    c = CHUNK
+    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    g = g.astype(jnp.float32)
+    return (_dot((row >= col).astype(jnp.float32), g, _NN),
+            _dot((row < col).astype(jnp.float32), g, _NN))
+
+
+def _with_column(block, h, column):
+    """``block`` (CHUNK, heads) with its column ``h`` set to ``column``."""
+    lane = lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    return jnp.where(lane == h, column, block)
+
+
+def _packs(heads: int):
+    """The heads of a grid step, PACK at a time."""
+    return [range(first, first + PACK) for first in range(0, heads, PACK)]
+
+
+def _pack(q_ref, k_ref, v_ref, g_ref, sums, beta_ref, members, dk, dv):
+    rows = lambda ref, d: [  # noqa: E731
+        ref[0, :, h * d:(h + 1) * d].astype(jnp.float32) for h in members]
+    columns = lambda x: [x[:, h:h + 1].astype(jnp.float32)  # noqa: E731
+                         for h in members]
+    return _Pack(rows(q_ref, dk), rows(k_ref, dk), rows(v_ref, dv),
+                 columns(g_ref[0, 0]), columns(sums[0]), columns(sums[1]),
+                 columns(beta_ref[0, 0]))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
+                heads: int, dk: int, dv: int):
+    """``rest``: the backward's two residuals where they are asked for (each
+    chunk's starting states, its packs' inverses), then the state scratch."""
+    *residuals, state_scr = rest
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_chunk():
+        state_scr[...] = jnp.zeros_like(state_scr)
+
+    c = CHUNK
+    sums = _running_sums(g_ref[0, 0])
+
+    def forward(n, members):
+        pk = _pack(q_ref, k_ref, v_ref, g_ref, sums, beta_ref, members, dk,
+                   dv)
+        yield from pk.tables()
+        states = [state_scr[h] for h in members]
+        # [k; q_in] S: what the state holds of the keys, and what it answers
+        reads = [_dot(_stack([k, q_in]), state, _NN)
+                 for k, q_in, state in zip(pk.ks, pk.q_ins, states)]
+        yield
+        pk.solve([read[:c] for read in reads])
+        yield
+        answers = _split(_dot(pk.scores, _diagonal(pk.us), _NN), pk.p)
+        if residuals:
+            start_ref, t_ref = residuals
+            t_ref[0, 0, n] = pk.t
+            for i, h in enumerate(members):
+                start_ref[0, 0, h] = states[i]
+        for i, h in enumerate(members):
+            o_ref[0, :, h * dv:(h + 1) * dv] = reads[i][c:] + answers[i]
+            state_scr[h] = states[i] * pk.keeps[i] + _dot(
+                pk.k_outs[i], pk.us[i], _TN)
+
+    _in_turn([forward(n, members)
+              for n, members in enumerate(_packs(heads))])
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, t_ref,
+                do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                dstate_scr, *, heads: int, dk: int, dv: int):
+    """Chunks arrive last first; ``dstate_scr`` is the cotangent of the state
+    the chunk LEAVES, zero behind the last chunk."""
+    @pl.when(pl.program_id(2) == 0)
+    def _last_chunk():
+        dstate_scr[...] = jnp.zeros_like(dstate_scr)
+
+    c = CHUNK
+    sums = _running_sums(g_ref[0, 0])
+    last = lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    dgcs, dbetas = {}, {}       # head of the step -> its (CHUNK, 1) column
+
+    def backward(n, members):
+        pk = _pack(q_ref, k_ref, v_ref, g_ref, sums, beta_ref, members, dk,
+                   dv)
+        p = pk.p
+        yield from pk.tables(t_ref[0, 0, n])
+        states = [start_ref[0, 0, h] for h in members]
+        dstates = [dstate_scr[h] for h in members]
+        dos = [do_ref[0, :, h * dv:(h + 1) * dv].astype(jnp.float32)
+               for h in members]
+        helds = [_dot(k, state, _NN) for k, state in zip(pk.ks, states)]
+        yield
+        pk.solve(helds)
+        # o = q_in S + scores u;  S' = keep S + k_out^T u. A packed table's
+        # transpose times the pack's rows gives every pairing of heads; each
+        # head's own is a diagonal block.
+        from_o = _diagonal_blocks(_dot(pk.scores, _lanes(dos), _TN), p)
+        from_state = [_dot(k_out, dstate, _NN)
+                      for k_out, dstate in zip(pk.k_outs, dstates)]
+        yield
+        dus = [x + y for x, y in zip(from_o, from_state)]
+        dk_outs = [_dot(u, dstate, _NT) for u, dstate in zip(pk.us, dstates)]
+        # u = T r, T = (I + A)^-1:  dr = T^T du,  dA = -dr u^T;
+        # dscores = do u^T shares the right side
+        drs = _diagonal_blocks(_dot(pk.t, _lanes(dus), _TN), p)
+        yield
+        tables = _dot(_stack([_lanes(dos), _lanes(drs)]), _diagonal(pk.us),
+                      _NT)
+        # r = beta * (v - into * held)
+        dmissings = [beta * dr for beta, dr in zip(pk.betas, drs)]
+        dhelds = [-into * dm for into, dm in zip(pk.intos, dmissings)]
+        # [do; dheld] S^T: q_in's and k's cotangents through the old state
+        through = [_dot(_stack([do, dheld]), state, _NT)
+                   for do, dheld, state in zip(dos, dhelds, states)]
+        yield
+        dscores = jnp.where(pk.lower, tables[:c], 0.0)
+        da = jnp.where(pk.strict, -tables[c:], 0.0)
+        # A = (k_beta k^T) * decay;  scores = (q k^T) * decay
+        dproducts = _stack([da * pk.decay, dscores * pk.decay])
+        right = _split(_dot(dproducts, _diagonal(pk.ks), _NN), p)
+        left = _diagonal_blocks(_dot(dproducts, _stack(
+            [_lanes(pk.k_betas), _lanes(pk.qs)]), _TN), p)
+        yield
+        dtable = da * pk.a + dscores * pk.scores     # d(gc_i - gc_j)
+        dtable_columns = _rowsum(dtable.T)           # (p * CHUNK, 1)
+        for i, h in enumerate(members):
+            q, k, beta, state = pk.qs[i], pk.ks[i], pk.betas[i], states[i]
+            dk_beta, dk_out, dq_in = right[i][:c], dk_outs[i], through[i][:c]
+            dq_ref[0, :, h * dk:(h + 1) * dk] = (
+                right[i][c:] + pk.intos[i] * dq_in).astype(dq_ref.dtype)
+            dk_ref[0, :, h * dk:(h + 1) * dk] = (
+                through[i][c:] + dk_beta * beta + left[i]
+                + pk.to_ends[i] * dk_out).astype(dk_ref.dtype)
+            dv_ref[0, :, h * dv:(h + 1) * dv] = dmissings[i].astype(
+                dv_ref.dtype)
+            dbetas[h] = _rowsum(drs[i] * pk.missings[i]) \
+                + _rowsum(dk_beta * k)
+            # the decays: gc through into, to_end, keep and the table
+            dinto = _rowsum(dq_in * q) - _rowsum(
+                dmissings[i] * pk.helds[i])
+            dto_end = _rowsum(dk_out * k) * pk.to_ends[i]
+            dg_end = (pk.keeps[i] * _total(state * dstates[i])
+                      + _total(dto_end))
+            dgcs[h] = (dinto * pk.intos[i] - dto_end
+                       + _rowsum(pk.own(dtable, i))
+                       - dtable_columns[i * c:(i + 1) * c]
+                       + jnp.where(last, dg_end, 0.0))
+            dstate_scr[h] = dstates[i] * pk.keeps[i] + _dot(
+                _stack([pk.q_ins[i], k]), _stack([dos[i], dhelds[i]]), _TN)
+
+    _in_turn([backward(n, members)
+              for n, members in enumerate(_packs(heads))])
+    dgc_all = jnp.zeros((c, heads), jnp.float32)
+    dbeta_all = jnp.zeros((c, heads), jnp.float32)
+    for h in range(heads):
+        dgc_all = _with_column(dgc_all, h, dgcs[h])
+        dbeta_all = _with_column(dbeta_all, h, dbetas[h])
+    # g -> gc is a running sum: its transpose sums from the end
+    upper = (lax.broadcasted_iota(jnp.int32, (c, c), 0)
+             <= lax.broadcasted_iota(jnp.int32, (c, c), 1))
+    dg_ref[0, 0] = _dot(upper.astype(jnp.float32), dgc_all,
+                        _NN).astype(dg_ref.dtype)
+    dbeta_ref[0, 0] = dbeta_all.astype(dbeta_ref.dtype)
+
+
+def _heads_per_step(h: int) -> int:
+    """The most whole PACKs, up to HEADS_PER_STEP heads, that divide ``h``."""
+    return max(d for d in range(PACK, HEADS_PER_STEP + 1, PACK) if h % d == 0)
+
+
+def _grouped(x, hb):   # (B, S, H) -> (B, H / hb, S, hb)
+    b, s, h = x.shape
+    return jnp.moveaxis(x.reshape(b, s, h // hb, hb), 2, 1)
+
+
+def _ungrouped(x):     # back
+    b, groups, s, hb = x.shape
+    return jnp.moveaxis(x, 1, 2).reshape(b, s, groups * hb)
+
+
+def _specs(hb, dk, dv, n, *, reverse):
+    chunk_of = (lambda c: n - 1 - c) if reverse else (lambda c: c)
+    wide = lambda d: pl.BlockSpec(  # noqa: E731
+        (1, CHUNK, hb * d), lambda i, j, c: (i, chunk_of(c), j))
+    narrow = pl.BlockSpec((1, 1, CHUNK, hb),
+                          lambda i, j, c: (i, j, chunk_of(c), 0))
+    start = pl.BlockSpec((1, 1, hb, dk, dv),
+                         lambda i, j, c: (i, chunk_of(c), j, 0, 0))
+    inverse = pl.BlockSpec((1, 1, hb // PACK, CHUNK, PACK * CHUNK),
+                           lambda i, j, c: (i, chunk_of(c), j, 0, 0))
+    return wide, narrow, start, inverse
+
+
+# four packs' tables live at once, beside double-buffered blocks of eight
+# heads: with float32 v the backward is past the 16 MiB a kernel gets by
+# default (compiled for a described v5e it fits in 20), and 32 is within
+# the VMEM of every TPU that Mosaic compiles for
+_SEQUENTIAL_CHUNKS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=32 * 1024 * 1024)
+
+
+# `jit(inline=True)` on the two calls: a kernel body, unrolled over four packs,
+# is ~2,000 operations, and a step calls the rule nine times (three layers,
+# their remat, their backward). jit keeps the traced call by its shapes, so
+# the body is traced once a process; `inline` puts its equations into the
+# caller at each site, under the caller's scope path (a jitted call proper
+# is lowered once for all sites and its operations lose the path the
+# region metrics read). Tracing is paid by every process, compile cache or
+# not: without this `setup_s` rose by 9 s.
+@functools.partial(jax.jit, inline=True, static_argnames="residuals")
+def _forward(q, k, v, g, beta, *, residuals: bool):
+    """(the output,) and, with ``residuals``, what the backward needs besides
+    the inputs: each chunk's starting states and its packs' inverses."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    hb, n = _heads_per_step(h), s // CHUNK
+    wide, narrow, start, inverse = _specs(hb, dk, dv, n, reverse=False)
+    kept = residuals * [
+        (start, jax.ShapeDtypeStruct((b, n, h, dk, dv), jnp.float32)),
+        (inverse, jax.ShapeDtypeStruct(
+            (b, n, h // PACK, CHUNK, PACK * CHUNK), jnp.float32))]
+    call = pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=hb, dk=dk, dv=dv),
+        name="gdn_rule_fwd", grid=(b, h // hb, n),
+        in_specs=[wide(dk), wide(dk), wide(dv), narrow, narrow],
+        out_specs=[wide(dv)] + [spec for spec, _ in kept],
+        out_shape=[jax.ShapeDtypeStruct((b, s, h * dv), jnp.float32)]
+        + [shape for _, shape in kept],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
+        compiler_params=_SEQUENTIAL_CHUNKS, interpret=_interpret())
+    with jax.named_scope("gdn_rule_fwd"):
+        out, *written = call(
+            q.reshape(b, s, h * dk), k.reshape(b, s, h * dk),
+            v.reshape(b, s, h * dv), _grouped(g, hb), _grouped(beta, hb))
+    return (out.reshape(b, s, h, dv), *written)
+
+
+@functools.partial(jax.jit, inline=True)
+def _backward(q, k, v, g, beta, starts, inverses, do):
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    hb, n = _heads_per_step(h), s // CHUNK
+    wide, narrow, start, inverse = _specs(hb, dk, dv, n, reverse=True)
+    like = lambda x, shape: jax.ShapeDtypeStruct(shape, x.dtype)  # noqa: E731
+    narrow_shape = (b, h // hb, s, hb)
+    call = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=hb, dk=dk, dv=dv),
+        name="gdn_rule_bwd", grid=(b, h // hb, n),
+        in_specs=[wide(dk), wide(dk), wide(dv), narrow, narrow, start,
+                  inverse, wide(dv)],
+        out_specs=[wide(dk), wide(dk), wide(dv), narrow, narrow],
+        out_shape=[like(q, (b, s, h * dk)), like(k, (b, s, h * dk)),
+                   like(v, (b, s, h * dv)), like(g, narrow_shape),
+                   like(beta, narrow_shape)],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
+        compiler_params=_SEQUENTIAL_CHUNKS, interpret=_interpret())
+    with jax.named_scope("gdn_rule_bwd"):
+        dq, dk_, dv_, dg, dbeta = call(
+            q.reshape(b, s, h * dk), k.reshape(b, s, h * dk),
+            v.reshape(b, s, h * dv), _grouped(g, hb), _grouped(beta, hb),
+            starts, inverses, do.reshape(b, s, h * dv))
+    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
+            _ungrouped(dg), _ungrouped(dbeta))
+
+
+@jax.custom_vjp
+def _rule(q, k, v, g, beta):
+    return _forward(q, k, v, g, beta, residuals=False)[0]
+
+
+def _rule_fwd(q, k, v, g, beta):
+    out, starts, inverses = _forward(q, k, v, g, beta, residuals=True)
+    return out, (q, k, v, g, beta, starts, inverses)
+
+
+def _rule_bwd(residuals, do):
+    return _backward(*residuals, do)
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def gated_delta_rule_kernels(q, k, v, g, beta):
+    """`gated_delta_rule_stepwise`'s result by the two kernels. q, k: (B, S,
+    H, Dk); v: (B, S, H, Dv); g, beta: (B, S, H); returns (B, S, H, Dv)
+    float32. A length that is no multiple of CHUNK is padded with positions
+    that leave the state alone (k = v = 0, beta = 0, g = 0). The heads come
+    in PACKs: `gdn_rule_supports` says which shapes may be sent here."""
+    s, h = q.shape[1:3]
+    if h % PACK:
+        raise ValueError(f"gated_delta_rule_kernels: {h} heads are not whole "
+                         f"packs of {PACK}")
+    pad = -s % CHUNK
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    return _rule(q, k, v, g, beta)[:, :s]

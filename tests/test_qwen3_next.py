@@ -271,8 +271,8 @@ def test_an_absent_share_computes_nothing(whole_layer):
     assert float(sown["counters"]["moe_held_assignments"][0]) == 0
 
 
-def rule_inputs(length, decay):
-    b, h, dk, dv = 2, 3, 16, 8
+def rule_inputs(length, decay, h=3):
+    b, dk, dv = 2, 16, 8
     ks = jax.random.split(jax.random.PRNGKey(length), 5)
     q = jax.random.normal(ks[0], (b, length, h, dk))
     k = jax.random.normal(ks[1], (b, length, h, dk))
