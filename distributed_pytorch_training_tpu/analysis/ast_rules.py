@@ -557,6 +557,65 @@ def check_pallas_call_in_ops(ctx: FileContext) -> List[Finding]:
 
 
 # ---------------------------------------------------------------------------
+
+# The package and its one leaf: experiments/ holds the studies
+# (scaling.py, plots.py, their harness), and only entry points may stand
+# on it. Matched on exact path components, like PALLAS_HOME.
+PACKAGE = PALLAS_HOME[0]
+EXPERIMENTS = "experiments"
+
+
+def _import_targets(here: Tuple[str, ...],
+                    node: ast.AST) -> List[Tuple[str, ...]]:
+    """The absolute dotted module paths an import statement binds or
+    reaches into, as tuples of components: a relative `from ..a import b`
+    is resolved against ``here``, the importing file's directory, and each
+    imported name is appended (it may be a submodule)."""
+    if isinstance(node, ast.Import):
+        return [tuple(a.name.split(".")) for a in node.names]
+    if not isinstance(node, ast.ImportFrom):
+        return []
+    base = here[:len(here) - (node.level - 1)] if node.level else ()
+    base += tuple(node.module.split(".")) if node.module else ()
+    return [base + (a.name,) for a in node.names]
+
+
+@rule("experiments-is-a-leaf", "ast",
+      "no module of the package outside experiments/ imports the "
+      "experiments package",
+      "experiments/ is the reference's 'scaling experiments' and 'gradient "
+      "sync profiling': drivers and their measuring recipe, reshaped "
+      "whenever a study changes. When the token server built its engine "
+      "through experiments.harness and the telemetry plane parsed captures "
+      "through experiments.trace_analysis, none of it could change without "
+      "breaking `serve`. How an engine is built is serving/'s decision, how "
+      "a capture is split is telemetry/'s; entry points (train.py, "
+      "experiments/scaling.py) may stand on experiments/, the package may "
+      "not.")
+def check_experiments_is_a_leaf(ctx: FileContext) -> List[Finding]:
+    parts = tuple(ctx.relpath.replace("\\", "/").split("/"))
+    if PACKAGE not in parts[:-1]:
+        return []  # a root script or a test: an entry point, not the package
+    inside = parts[parts.index(PACKAGE) + 1:]
+    if inside[0] == EXPERIMENTS:
+        return []
+    out: List[Finding] = []
+    for node in ast.walk(ctx.tree):
+        for target in _import_targets(parts[:-1], node):
+            if (PACKAGE, EXPERIMENTS) in zip(target, target[1:]):
+                out.append(Finding(
+                    "experiments-is-a-leaf",
+                    f"{'/'.join(inside)} imports {'.'.join(target)} — "
+                    "the package does not stand on experiments/; move "
+                    "what is needed to the package that owns the decision "
+                    "(serving/build.py, telemetry/trace_analysis.py, "
+                    "models/registry.py are where the last ones went)",
+                    ctx.loc(node)))
+                break
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Rule 7: no telemetry emission inside traced bodies
 # ---------------------------------------------------------------------------
 

@@ -3,9 +3,9 @@ train steps.
 
 The parsers (`hlo_result_elements`, `collective_census`,
 `weight_update_census`, `grad_sync_census`) moved here from
-`experiments/trace_analysis.py` (which keeps re-export shims — the trace
-half of that module is runtime analysis; this is the compile-time half,
-now a checked contract instead of scattered helpers).
+the trace reader (now `telemetry/trace_analysis.py`: that half is
+runtime analysis; this is the compile-time half, a checked contract
+instead of scattered helpers).
 
 Rules consume a `StepArtifacts` snapshot of one lowered config — the
 optimized HLO text, the pre-optimization text (the wire-dtype read on CPU,
@@ -273,9 +273,8 @@ def expected_buckets(total_grad_bytes: int, bucket_cap_mb: float) -> int:
 class StepArtifacts:
     """One lowered/compiled train-step config, as the rules see it.
 
-    Built by `evaluate_contract` (the matrix) and
-    `experiments.harness.measure_config` (per bench arm); tests build them
-    directly to feed rules synthetic violations (the mutation tests).
+    Built by `evaluate_contract` (the matrix); tests build them directly
+    to feed rules synthetic violations (the mutation tests).
     """
 
     name: str
@@ -475,8 +474,8 @@ def check_compressed_wire(a: StepArtifacts) -> List[Finding]:
         # No reliable wire read: CPU's float-normalization promotes bf16
         # collectives to f32 in the OPTIMIZED text, so checking it would
         # turn a pre-opt extraction failure into a false violation. The
-        # wire rules abstain rather than guess (the evaluator and
-        # measure_config always attempt the pre-opt read).
+        # wire rules abstain rather than guess (the evaluator always
+        # attempts the pre-opt read).
         return []
     expect = WIRE_HLO_DTYPE[a.wire_mode]
     wire = grad_sync_census(a.wire_text, a.min_elements)["wire_dtypes"]
@@ -1677,8 +1676,7 @@ def run_contract_matrix(contracts=None, mesh=None, rules=None):
 
 
 # ---------------------------------------------------------------------------
-# Raise-on-violation wrappers (the historical acceptance-gate API;
-# experiments/trace_analysis.py re-exports these for existing callers)
+# Raise-on-violation wrappers (the historical acceptance-gate API)
 # ---------------------------------------------------------------------------
 
 

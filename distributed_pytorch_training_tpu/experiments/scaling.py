@@ -30,8 +30,9 @@ outline (/root/reference/README.md:27-35):
   (b) static: a census of collective ops (all-reduce/all-gather/...) in the
       optimized HLO of the compiled step, with operand bytes — read from the
       compiled executable the way the reference would read an nsys timeline;
-  (c) trace-derived: a jax.profiler capture parsed by trace_analysis.py,
-      collective time summed against XLA-op busy time.
+  (c) trace-derived: a jax.profiler capture parsed by
+      telemetry/trace_analysis.py, collective time summed against XLA-op
+      busy time.
 * ``pipeline`` — GPipe bubble measurement: pipelined-GPT-2 throughput vs
   microbatch count against the pure-DP layout of the same model
   (bubble fraction (P-1)/(M+P-1); parallel/pipeline.py).
@@ -55,12 +56,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..analysis.hlo_rules import collective_census, weight_update_census
+from ..models.registry import is_lm_model
 from ..runtime import require_backend
-# One measurement harness shared with bench.py (experiments/harness.py) so
-# the headline bench and these tables stay comparable — including the
-# image-vs-LM dispatch (harness.build_trainer / make_synth_batch), so the
-# same --model string measures the same config everywhere.
-from .harness import build_trainer, is_lm_model, make_synth_batch, timed_steps
+# One recipe for every table here (experiments/harness.py): the
+# image-vs-LM dispatch (build_trainer / make_synth_batch) and the
+# differenced timing windows.
+from .harness import build_trainer, make_synth_batch, timed_steps
 
 # CI smoke runs shrink LM architectures (full-size bert/gpt2 on the CPU test
 # mesh costs minutes per build); real measurements never set this.
@@ -167,14 +169,6 @@ def run_amp(args) -> List[dict]:
     return rows
 
 
-# The static HLO census lives with the other gradient-sync instruments in
-# trace_analysis.py; re-exported here because this module is its historical
-# home (tests and notebooks import it from scaling).
-from .trace_analysis import (  # noqa: E402,F401
-    collective_census, weight_update_census,
-)
-
-
 def run_gradsync(args) -> List[dict]:
     devices = jax.devices()
     n = len(devices)
@@ -209,7 +203,9 @@ def run_gradsync(args) -> List[dict]:
         # stateN's buffers.
         import tempfile
 
-        from .trace_analysis import capture_step_trace, collective_share
+        from ..telemetry.trace_analysis import (
+            capture_step_trace, collective_share,
+        )
 
         trainerT, stateT, _, batchT, _gbT = _setup(devices, args.bf16, args)
         keyT = jax.random.PRNGKey(0)
@@ -294,10 +290,10 @@ def run_grad_sync(args) -> List[dict]:
     default); `--grad-accum` > 1 exercises the in-scan overlap (plus a
     no-overlap arm isolating its win).
     """
+    from ..analysis.hlo_rules import grad_sync_census, preopt_hlo_text
     from ..parallel.grad_sync import wire_bytes_for_config
     from ..parallel.mesh import batch_shard_count
     from .harness import trace_exposed_comm
-    from .trace_analysis import grad_sync_census, preopt_hlo_text
 
     devices = jax.devices()
     if len(devices) < 2:
@@ -458,9 +454,9 @@ def run_fsdp(args) -> List[dict]:
     mode is accounted per wire dtype (the int8_multihop gathers are
     ~1 B/element, n-independent; fp32 gathers are exact at ~4 B/element).
     `--grad-accum` > 1 exercises the in-scan per-layer scatter overlap."""
+    from ..analysis.hlo_rules import grad_sync_census
     from ..parallel.grad_sync import fsdp_gather_bytes, wire_bytes_for_config
     from ..parallel.mesh import batch_shard_count
-    from .trace_analysis import grad_sync_census
 
     devices = jax.devices()
     if len(devices) < 2:
@@ -527,7 +523,7 @@ def run_tp(args) -> List[dict]:
     from ..parallel.grad_sync import wire_bytes_for_config
     from ..parallel.mesh import batch_shard_count
     from .harness import build_lm_trainer, synth_token_batch
-    from ..analysis.hlo_rules import collective_census, replica_group_axis
+    from ..analysis.hlo_rules import replica_group_axis
 
     devices = jax.devices()
     n = len(devices)

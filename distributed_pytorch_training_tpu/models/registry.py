@@ -27,3 +27,13 @@ def get_model(name: str, **kwargs):
 
 def list_models():
     return sorted(_REGISTRY)
+
+
+def is_lm_model(name: str) -> bool:
+    """The image-vs-token dispatch that the experiment drivers, the trainer
+    builders and the serving CLI all ask of a model's name."""
+    return name.startswith(("gpt2", "bert"))
+
+
+def lm_vocab(name: str) -> int:
+    return 30522 if name.startswith("bert") else 50257

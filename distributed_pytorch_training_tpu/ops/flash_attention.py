@@ -56,7 +56,7 @@ def _interpret() -> bool:
 
 
 def flash_backend_supported(backend: Optional[str] = None) -> bool:
-    """ONE place for the backend gate shared by the bench harness and
+    """ONE place for the backend gate shared by the experiments harness and
     ``--attention auto``: the kernels are worth running only on real TPU.
     CPU would run pallas in interpreter mode (pure overhead); the pltpu
     VMEM scratch shapes cannot lower on GPU."""

@@ -63,7 +63,7 @@ explicit, with the three levers DDP exposes (and two it doesn't):
   collective for step *i* has no data dependency on step *i+1*'s compute
   and XLA's latency-hiding scheduler can run them concurrently — exposed
   comm time becomes hidden time (measured by
-  `experiments.trace_analysis.comm_overlap_split`).
+  `telemetry.trace_analysis.comm_overlap_split`).
 
 Everything here is shard_map-body code: collectives take bound mesh axis
 names, never a Mesh. The int8 wire uses all-gather / all-to-all (each
@@ -214,7 +214,7 @@ def wire_bytes_per_replica(plan: BucketPlan, wire_dtype: str,
                            n_shards: int, n_slices: int = 1) -> int:
     """Per-replica wire bytes of ONE full gradient sync under `wire_dtype` —
     the accounting behind the mode table (README) as a measured/recorded
-    number in bench and scaling rows, not a docstring claim.
+    number in scaling rows, not a docstring claim.
 
     Conventions (payload only — the fp32 scale sideband, O(n) bytes per
     bucket, is excluded as noise):
@@ -300,7 +300,7 @@ def fsdp_gather_bytes(params: Any, wire_dtype: str, n_shards: int,
                       n_slices: int = 1) -> int:
     """Per-replica wire bytes of ONE full per-layer parameter gather pass
     under explicit FSDP (`fsdp_explicit`) — the gather-traffic term
-    `wire_bytes_for_config` adds for that mode, recorded in bench/scaling
+    `wire_bytes_for_config` adds for that mode, recorded in scaling
     rows (satellite of ISSUE 7).
 
     Conventions (payload only, scale sidebands excluded as noise): the
@@ -362,9 +362,9 @@ def wire_bytes_for_config(params: Any, grad_sync_cfg: Optional[dict],
                           n_shards: int) -> int:
     """`wire_bytes_per_replica` from a TrainConfig-style override dict
     (``bucket_cap_mb`` / ``wire_dtype`` / ``fsdp_explicit``, with the
-    TrainConfig defaults) — the ONE accounting call both bench
-    (`harness.measure_config`) and scaling (`run_grad_sync` / `run_fsdp` /
-    `run_tp`) record, so their rows cannot drift apart.
+    TrainConfig defaults) — the ONE accounting call that
+    `emit_wire_accounting` (train.py's stream) and scaling (`run_grad_sync`
+    / `run_fsdp` / `run_tp`) record, so their rows cannot drift apart.
 
     For ``fsdp_explicit`` configs the number is scatter + gather: the
     gradient reduce-scatter at the wire dtype (4/2/1/1 bytes per padded
@@ -434,15 +434,15 @@ def emit_wire_accounting(params: Any, grad_sync_cfg: Optional[dict],
                          n_shards: int, tier: str = "ici",
                          **attrs: Any) -> dict:
     """Record the configured sync mode's per-replica wire accounting as
-    telemetry counters (host-side, setup-time — called once by train.py /
-    the bench harness, NEVER from traced code) and return the numbers —
-    THE one emission site, so the stream and the bench rows cannot drift.
+    telemetry counters (host-side, setup-time — called once by train.py,
+    NEVER from traced code) and return the numbers — THE one emission
+    site.
 
     ``tier`` names the interconnect the bytes ride — "ici" is the only
     tier today; the ROADMAP's two-tier (ICI + DCN) hierarchical sync will
     emit one counter set per tier through this same call, which is why
     the attribute exists now (per-tier byte/time telemetry is the
-    substrate that item presumes). Extra ``attrs`` (e.g. the bench's
+    substrate that item presumes). Extra ``attrs`` (e.g. a
     ``model=...``) ride every emitted counter.
 
     Explicit TP x FSDP (``cfg["model_shards"]`` > 1 with
@@ -547,9 +547,8 @@ class LayerPlan:
     @property
     def padded_group_sizes(self) -> Tuple[int, ...]:
         """Full padded elements per group (n_shards x row_size) — the ONE
-        budget the analysis/ fsdp rules read (contract evaluator and bench
-        `_contract_check` both snapshot this, so their expectations cannot
-        drift)."""
+        budget the analysis/ fsdp rules read (the contract evaluator
+        snapshots this)."""
         return tuple(self.n_shards * g.row_size for g in self.groups)
 
 

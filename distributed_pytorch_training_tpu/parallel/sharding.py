@@ -355,9 +355,9 @@ def tp_unflatten_leaf(flat, full_shape: Tuple[int, ...], dtype,
 def tp_clip_weights_for_model(model, rules: Optional[PartitionRules],
                               model_n: int, sample_input) -> dict:
     """`tp_clip_weights` derived straight from a model + its rules — THE
-    one derivation both train.py and the bench harness use (a weighting
+    one derivation both train.py and the experiments harness use (a weighting
     rule living in two hand-rolled copies would silently diverge between
-    the CLI and the bench arms). One abstract trace of ``model.init`` on
+    the CLI and the experiment arms). One abstract trace of ``model.init`` on
     ``sample_input`` recovers the leaf paths/shapes the divisibility
     decisions need."""
     import functools

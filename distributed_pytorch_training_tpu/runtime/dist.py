@@ -47,7 +47,7 @@ def cpu_requested() -> bool:
 
 def require_backend() -> str:
     """THE platform rule, shared by every entry point that measures or
-    trains (train.py, the serving CLI, bench.py, experiments/scaling.py,
+    trains (train.py, the serving CLI, experiments/scaling.py,
     chip_smoke.py): CPU only when asked for by name. If ``JAX_PLATFORMS``
     is literally ``cpu`` the run is a CPU run; otherwise the resolved
     backend must be ``tpu`` or this raises — a program that found no

@@ -16,8 +16,7 @@ Commands:
       engine worker drains the queue (continuous batching); reports
       p50/p99 latency, achieved request/token throughput, the compile
       census (zero recompiles after warmup is the contract), and the
-      serving HLO-contract verdict — the serving row of the bench table
-      (experiments/harness.py::measure_serving).
+      serving HLO-contract verdict (serving/loadtest.py::measure_serving).
       --continuous switches to the TOKEN-granular arm (slot engine +
       paged/int8 KV, serving/continuous.py) — same load schedule, so the
       two rows are the iteration-vs-token A/B; --replicas N spreads it
@@ -111,7 +110,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                         "'hidden_dim=64,depth=2,num_heads=2'")
     # checkpoint TEMPLATE flags (must mirror the training run's — orbax
     # validates the TrainState structure, and the optimizer chain's
-    # structure depends on these: see harness.build_serving_engine)
+    # structure depends on these: see serving/build.py::build_serving_engine)
     p.add_argument("--zero1", action="store_true")
     p.add_argument("--fsdp-explicit", action="store_true")
     p.add_argument("--wire-dtype", default="fp32")
@@ -254,13 +253,13 @@ def _run(args, buckets) -> int:
     import jax
 
     from .. import telemetry
-    from ..experiments.harness import (
-        build_serving_engine, is_lm_model, lm_vocab, measure_serving,
-    )
+    from ..models.registry import is_lm_model, lm_vocab
     from ..training import TrainConfig
     from ..utils.config import parse_model_overrides
     from ..utils.logging import log_main
     from .batching import RequestQueue, drain, serve_forever
+    from .build import build_serving_engine
+    from .loadtest import measure_serving, measure_serving_continuous
 
     overrides = (parse_model_overrides(args.model_overrides)
                  if args.model_overrides else None)
@@ -282,8 +281,6 @@ def _run(args, buckets) -> int:
         return _fleet(args, buckets)
 
     if args.command == "bench" and args.continuous:
-        from ..experiments.harness import measure_serving_continuous
-
         row = measure_serving_continuous(
             model_name=args.model, n_requests=args.requests,
             offered_rps=args.offered_load, buckets=buckets, rows=args.rows,
@@ -438,9 +435,9 @@ def _serve(args, buckets, overrides, train_config) -> int:
     import jax
 
     from .. import telemetry
-    from ..experiments.harness import build_slot_engine
     from ..utils.logging import log_main
     from .batching import RequestQueue
+    from .build import build_slot_engine
     from .continuous import ContinuousScheduler
 
     engine, _ = build_slot_engine(

@@ -175,7 +175,7 @@ class InferenceEngine:
                 f"mesh has model={model_n} but the engine was given no "
                 "partition rules — serving shards weights over the model "
                 "axis via the model's GSPMD rules (tp_fsdp_rules); pass "
-                "rules= (harness.build_serving_engine does)")
+                "rules= (serving.build.build_serving_engine does)")
         if model_n > 1 and config.serve_dtype == "int8":
             raise ValueError(
                 "--serve-dtype int8 on a model-axis mesh is not supported "

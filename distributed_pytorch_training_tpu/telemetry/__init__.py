@@ -53,7 +53,7 @@ Design constraints (enforced, not aspirational):
   global and return; no file, no ring, no timestamps.
 * **No jax at module scope.** The flight recorder must be callable from
   resilience/heartbeat.py (which refuses to initialize a backend) and
-  from the bench driver before any backend exists.
+  from an entry point before any backend exists.
 """
 
 from __future__ import annotations

@@ -5,8 +5,7 @@ can say WHICH rank was slow and in WHICH phase, but not what the device was
 doing. This module closes that gap: whenever utils/profiling.StepProfiler
 finishes a capture (the static ``--profile-steps`` window, a ``POST
 /profile`` on-demand window, or an anomaly-triggered one), the trace is
-parsed through the experiments/trace_analysis machinery
-(:func:`~..experiments.trace_analysis.device_time_split` — the
+parsed by its neighbour (:func:`~.trace_analysis.device_time_split` — the
 ``comm_overlap_split`` interval algebra plus the collective census' op
 normalization) into ONE ``device_profile`` event on the stream:
 
@@ -17,7 +16,7 @@ normalization) into ONE ``device_profile`` event on the stream:
   reduce-scatter time);
 * ``exposed_comm_ratio`` — exposed / total collective time, the number
   that decides whether compressed gradient sync paid off (DynamiQ's
-  headline metric, now a runtime series instead of a bench.py-only one);
+  headline metric, as a runtime series);
 * measured MFU when the caller provides a FLOPs reference (train.py wires
   the Trainer's analytic per-step FLOPs + chip peak).
 
@@ -52,7 +51,7 @@ def analyze_capture(trace_dir: str) -> Optional[Dict[str, Any]]:
     """Parse one captured trace directory into the device split, or None
     (logged) when no trace exists / parsing fails."""
     try:
-        from ..experiments.trace_analysis import device_time_split
+        from .trace_analysis import device_time_split
 
         return device_time_split(trace_dir)
     except FileNotFoundError:

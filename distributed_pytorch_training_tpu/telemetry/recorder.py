@@ -376,7 +376,7 @@ _NULL_SPAN = NullSpan()
 
 # ---------------------------------------------------------------------------
 # The process-global recorder: one stream per process, installed by the
-# entry point (train.py / bench.py / the chaos CLI), consumed by every
+# entry point (train.py / the serving CLI / the chaos CLI), consumed by every
 # instrumented layer through the no-op-when-unconfigured helpers below.
 # ---------------------------------------------------------------------------
 

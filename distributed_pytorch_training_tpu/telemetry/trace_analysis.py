@@ -9,11 +9,12 @@ total XLA-op busy time. This module parses the Chrome-trace JSON the profiler
 writes (`plugins/profile/<ts>/<host>.trace.json.gz`) — no tensorboard plugin
 needed.
 
-Instruments in experiments/scaling.py `gradsync`, cross-checked three ways:
-(a) measured 1-vs-N step-time delta, (b) static HLO collective census
-(`collective_census` below, plus the zero1 weight-update classification
-`weight_update_census`/`verify_zero1_collectives`), (c) the trace-derived
-share (the profiler-timeline read-off the README placeholder calls for).
+Two readers: experiments/scaling.py `gradsync`, which cross-checks three
+ways — (a) measured 1-vs-N step-time delta, (b) the static HLO collective
+census (`analysis/hlo_rules.py`), (c) the trace-derived share here (the
+profiler-timeline read-off the README placeholder calls for) — and the
+telemetry plane's `device_profile` event (`telemetry/device.py`, over
+`device_time_split`).
 """
 
 from __future__ import annotations
@@ -133,20 +134,6 @@ def collective_share(log_dir: str) -> dict:
     }
 
 
-# ---------------------------------------------------------------------------
-# Static HLO collective census — MOVED to analysis/hlo_rules.py (ISSUE 3:
-# the compile-time half of the gradient-sync analysis is now a checked
-# contract subsystem, not scattered helpers). Re-exported here so existing
-# callers (scaling.py, harness.py, tests, notebooks) keep working.
-# ---------------------------------------------------------------------------
-
-from ..analysis.hlo_rules import (  # noqa: E402,F401
-    collective_census, grad_sync_census, hlo_result_elements,
-    preopt_hlo_text, verify_grad_sync_collectives, verify_zero1_collectives,
-    weight_update_census,
-)
-
-
 def comm_overlap_split(log_dir: str) -> dict:
     """Exposed-vs-hidden communication time from a jax.profiler trace —
     the overlap instrument of the bucketed reducer (DDP's hooks hide comm
@@ -221,10 +208,11 @@ def device_time_split(log_dir: str) -> dict:
     EXACTLY on any trace — including the CPU thunk pool, where 8 virtual
     replicas' all-reduce events overlap each other on one pid and a
     per-event sum (``comm_overlap_split``'s accounting, kept unchanged
-    for the bench) can exceed the wall. ``by_op`` stays per-event op
-    time (the collective rollup is op work, not wall share). On the CPU
-    backend the hidden/exposed numbers measure thunk concurrency, not
-    ICI overlap — the ``comm_overlap_split`` caveat applies unchanged.
+    for `experiments.scaling`) can exceed the wall. ``by_op`` stays
+    per-event op time (the collective rollup is op work, not wall share).
+    On the CPU backend the hidden/exposed numbers measure thunk
+    concurrency, not ICI overlap — the ``comm_overlap_split`` caveat
+    applies unchanged.
     """
     events, pids, tids = load_trace(log_dir)
     ops = xla_op_events(events, pids, tids)
@@ -317,7 +305,7 @@ def capture_step_trace(step_fn, state, batch, key, log_dir: str,
             raise RuntimeError(
                 "capture_step_trace: a jax profiler session is already "
                 "open in this process — stop it (StepProfiler window / "
-                "on-demand capture) before capturing a bench trace")
+                "on-demand capture) before capturing a step trace")
         metrics = None
         for _ in range(steps):
             state, metrics = step_fn(state, batch, key)

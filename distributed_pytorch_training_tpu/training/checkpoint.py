@@ -41,10 +41,10 @@ masquerades as a trusted legacy checkpoint. What async changes is *when*
 bytes hit disk, never *what*: the written files and manifest digests are
 those of a synchronous save of the same state (PARITY.md).
 
-Blocked-time accounting (the bench instrument): ``save_blocked_ms`` sums
-every millisecond the calling thread spent inside ``save``/``wait`` —
-under async saves it collapses to ~``snapshot_ms`` (the device→host copy),
-which is the whole point.
+Blocked-time accounting (train.py's and the chaos CLI's end-of-run
+line): ``save_blocked_ms`` sums every millisecond the calling thread spent
+inside ``save``/``wait`` — under async saves it collapses to
+~``snapshot_ms`` (the device→host copy), which is the whole point.
 """
 
 from __future__ import annotations
@@ -189,7 +189,7 @@ class CheckpointManager:
         self._writer: Optional[threading.Thread] = None
         self._writer_label: Optional[int] = None
         self._writer_error: Optional[BaseException] = None
-        # blocked-time accounting (bench: the save_blocked_ms instrument)
+        # blocked-time accounting
         self.save_blocked_ms = 0.0   # caller-thread ms inside save()/wait()
         self.snapshot_ms = 0.0       # of which: the device→host snapshot
         self.saves_started = 0

@@ -451,7 +451,7 @@ class Trainer:
     def tp_wire_bytes(self, local_batch: int, seq_len: int) -> int:
         """Per-replica model-axis wire bytes of one explicit-TP step
         (`grad_sync.tp_psum_bytes_per_step` fed from the TP model) — the
-        TP tier term train.py and the bench harness emit. 0 when explicit
+        TP tier term train.py and the experiments harness emit. 0 when explicit
         TP is not engaged."""
         from ..parallel.grad_sync import tp_psum_bytes_per_step
 
@@ -468,7 +468,7 @@ class Trainer:
     def wire_accounting_inputs(self, state: TrainState, base_cfg: dict,
                                global_batch: int, seq_len: int):
         """(params, cfg) for `grad_sync.emit_wire_accounting` — THE one
-        assembly both train.py and the bench harness use, so their rows
+        assembly both train.py and the experiments harness use, so their rows
         cannot drift. Under explicit TP the data-axis terms come from the
         TP-LOCAL template (each model shard gathers/scatters only its 1/M
         slice) and the model-axis activation bytes ride ``tp_psum_bytes``

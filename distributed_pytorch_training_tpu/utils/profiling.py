@@ -79,7 +79,7 @@ def _note_busy(owner: str, wanted: str) -> None:
 
 @contextlib.contextmanager
 def trace_session(log_dir: str, owner: str = "trace_session"):
-    """The sanctioned raw-session form (experiments/trace_analysis.py's
+    """The sanctioned raw-session form (telemetry/trace_analysis.py's
     ``capture_step_trace`` rides it): start a jax.profiler trace into
     ``log_dir`` under the process-wide guard, yield True; if another
     session is open, yield False WITHOUT touching jax (the caller decides

@@ -370,6 +370,53 @@ class TestPallasCallInOpsOnly:
 
 
 # ---------------------------------------------------------------------------
+# experiments-is-a-leaf
+# ---------------------------------------------------------------------------
+
+
+class TestExperimentsIsALeaf:
+    def test_mutation_every_import_form_flags(self, tmp_path):
+        """The forms the serving CLI and telemetry/device.py used until
+        PR 30, and the absolute ones: each is found in a module of the
+        package, none in experiments/ itself, a root script or a test."""
+        pkg = "distributed_pytorch_training_tpu"
+        rule = ["experiments-is-a-leaf"]
+        relative = (
+            "from ..experiments.harness import build_slot_engine\n",
+            "def f():\n    from ..experiments import flops\n",
+            "from .. import telemetry, experiments\n",
+        )
+        absolute = (
+            f"import {pkg}.experiments.scaling\n",
+            f"from {pkg}.experiments.harness import timed_steps\n",
+            f"from {pkg} import experiments as ex\n",
+        )
+        for src in relative + absolute:
+            found = _lint(tmp_path, src, rules=rule,
+                          name=f"{pkg}/serving/rogue.py")
+            assert _rules_of(found) == {"experiments-is-a-leaf"}, src
+            assert _lint(tmp_path, src, rules=rule,
+                         name=f"{pkg}/experiments/scaling.py") == []
+        for src in absolute:
+            for entry_point in ("train.py", "tests/test_rogue.py"):
+                assert _lint(tmp_path, src, rules=rule,
+                             name=entry_point) == []
+        # its neighbours and lookalikes are no findings
+        clean = ("from ..telemetry.trace_analysis import collective_share\n"
+                 "from .build import build_slot_engine\n"
+                 "from experiments import something_else\n"
+                 'OUT = "./experiments"  # the run-output directory\n')
+        assert _lint(tmp_path, clean, rules=rule,
+                     name=f"{pkg}/serving/fine.py") == []
+
+    def test_the_package_does_not_stand_on_experiments(self):
+        """Binds on the real tree (it did not before PR 30: serving/__main__
+        built its engine through experiments.harness and telemetry/device
+        parsed captures through experiments.trace_analysis)."""
+        assert run_ast_rules(rules=["experiments-is-a-leaf"]) == []
+
+
+# ---------------------------------------------------------------------------
 # profiler-session-via-stepprofiler-only
 # ---------------------------------------------------------------------------
 
@@ -667,7 +714,7 @@ class TestEngine:
 
     def test_source_file_set_covers_package_and_scripts_not_tests(self):
         files = {p.name for p in iter_source_files()}
-        assert "loop.py" in files and "bench.py" in files \
+        assert "loop.py" in files and "chip_smoke.py" in files \
             and "train.py" in files
         assert "test_analysis_ast.py" not in files
 

@@ -752,6 +752,64 @@ class TestRouterUnits:
 
 
 # ---------------------------------------------------------------------------
+# Landing a kill with work in flight, without a clock
+# ---------------------------------------------------------------------------
+
+
+class _AnnouncingLock:
+    """A scheduler's lock that says when a caller found it held
+    (``waited``) and when that caller has had it and let go (``passed``)."""
+
+    def __init__(self):
+        self._inner = threading.Lock()
+        self._waiter = None
+        self.waited = threading.Event()
+        self.passed = threading.Event()
+
+    def __enter__(self):
+        if not self._inner.acquire(blocking=False):
+            if not self.waited.is_set():
+                self._waiter = threading.get_ident()
+                self.waited.set()
+            self._inner.acquire()
+
+    def __exit__(self, *exc):
+        self._inner.release()
+        if self._waiter == threading.get_ident():
+            self.passed.set()
+
+
+def hold_a_step_for_kill(monkeypatch, sched, stop, ready=lambda: True):
+    """Make `sched.kill()` land mid-step and with work in flight, whatever
+    the machine's load. The first decode step for which ``ready()`` holds
+    keeps the scheduler's lock until a kill() is WAITING for it; the
+    worker then leaves that kill() the step boundary (``stop.is_set`` is
+    what `run` reads there, outside the lock: a plain Lock is not fair,
+    and on a loaded box the worker used to barge past the waiter until
+    nothing was left to kill). Returns the event to wait on before calling
+    kill(), and the lock. The timeouts only bound a failure."""
+    lock = _AnnouncingLock()
+    in_step = threading.Event()
+    decode_step, is_set = sched.engine.decode_step, stop.is_set
+
+    def held_decode_step():
+        if ready() and not lock.waited.is_set():
+            in_step.set()
+            assert lock.waited.wait(30.0), "kill() never took the lock"
+        return decode_step()
+
+    def yielding_is_set():
+        if lock.waited.is_set():
+            lock.passed.wait(30.0)
+        return is_set()
+
+    monkeypatch.setattr(sched, "_lock", lock)
+    monkeypatch.setattr(sched.engine, "decode_step", held_decode_step)
+    monkeypatch.setattr(stop, "is_set", yielding_is_set)
+    return in_step, lock
+
+
+# ---------------------------------------------------------------------------
 # Scheduler kill: nothing hangs
 # ---------------------------------------------------------------------------
 
@@ -782,9 +840,13 @@ class TestSchedulerKill:
         step(): it must wait for the step boundary — no 'dict changed
         size' crash iterating running/pending, and no request resolved
         twice (set_result by the completing step AND set_error by the
-        kill). A stub engine with a slow decode step widens the race
-        window; the scheduler lock is what keeps this green."""
+        kill). The scheduler lock is what keeps this green.
+
+        No clock paces it (`hold_a_step_for_kill`; a `time.sleep` did, and
+        on a loaded box the worker served all 30 before the kill got the
+        lock): the kill lands on the step after the first completions."""
         cfg = paged_cfg()
+        completed: list = []           # slots whose results are out
 
         class _StubEngine:
             config = cfg
@@ -797,9 +859,10 @@ class TestSchedulerKill:
                 return cfg.buckets[-1]
 
             def decode_step(self):
-                time.sleep(0.002)
+                pass
 
             def fetch_slot(self, slot):
+                completed.append(slot)
                 return (np.zeros(cfg.max_new_tokens, np.int32),
                         np.zeros(VOCAB, np.float32))
 
@@ -825,6 +888,8 @@ class TestSchedulerKill:
         q = RequestQueue(cfg.buckets)
         sched = ContinuousScheduler(_StubEngine(), q)
         stop = threading.Event()
+        in_step, lock = hold_a_step_for_kill(
+            monkeypatch, sched, stop, ready=lambda: bool(completed))
         worker_err: list = []
 
         def run():
@@ -833,14 +898,17 @@ class TestSchedulerKill:
             except BaseException as e:  # noqa: BLE001 - the race crash
                 worker_err.append(e)
 
+        # all 30 are queued before the worker starts: rows=8, so results
+        # are out AND work is in flight when the held step is reached
+        reqs = [q.submit(s) for s in prompts([4] * 30, seed=23)]
         t = threading.Thread(target=run, daemon=True)
         t.start()
-        reqs = [q.submit(s) for s in prompts([4] * 30, seed=23)]
-        time.sleep(0.02)               # land the kill with work in flight
+        assert in_step.wait(30.0), f"no step was held: {worker_err}"
         sched.kill()
         t.join(timeout=30.0)
         assert not t.is_alive()
         assert not worker_err, f"worker crashed: {worker_err}"
+        assert lock.passed.is_set()    # the kill did wait for a step
         served = failed = 0            # everything resolves, nothing hangs
         for r in reqs:
             try:
@@ -848,7 +916,7 @@ class TestSchedulerKill:
                 served += 1
             except RuntimeError:       # the kill's error (ReplicaDead kin)
                 failed += 1
-        assert served + failed == len(reqs) and failed > 0
+        assert served + failed == len(reqs) and served > 0 and failed > 0
         assert len(resolutions) == len(reqs)
         assert set(resolutions.values()) == {1}, (
             f"double-resolved requests: "
@@ -878,11 +946,15 @@ class TestFleetAcceptance:
             engines.append(eng)
         return engines
 
-    def test_fleet_kill_all_complete_bitwise(self, fleet_engines, tiny):
+    def test_fleet_kill_all_complete_bitwise(self, fleet_engines, tiny,
+                                             monkeypatch):
         model, params = tiny
         eng_a, eng_b = fleet_engines
         warm = (eng_a.compiles, eng_b.compiles)
-        ra = InProcessReplica("r0", eng_a)
+        ra = InProcessReplica("r0", eng_a, start=False)
+        in_step, _lock = hold_a_step_for_kill(monkeypatch, ra.scheduler,
+                                              ra._stop)
+        ra._thread.start()
         rb = InProcessReplica("r1", eng_b)
         router = Router([ra, rb])
         rng = np.random.RandomState(7)
@@ -890,12 +962,10 @@ class TestFleetAcceptance:
                 .astype(np.int32) for _ in range(22)]
         reqs = [router.submit(s, temperature=0.0, max_new_tokens=6)
                 for s in seqs]
-        # the death must land with work IN FLIGHT on r0: submission is
-        # instant and service is not, so depth > 0 immediately
-        deadline = time.perf_counter() + 30.0
-        while time.perf_counter() < deadline and ra.queue_depth() == 0:
-            time.sleep(0.001)
-        assert ra.queue_depth() > 0, "r0 never held work to kill"
+        # the death must land with work IN FLIGHT on r0: its first decode
+        # step is held until the kill waits for it (a poll of queue_depth
+        # did this once, and under load r0 drained before the kill got in)
+        assert in_step.wait(60.0), "r0 never held work to kill"
         failed = ra.kill()
         assert failed, "the kill found nothing in flight"
         results = [r.result(timeout=300.0) for r in reqs]

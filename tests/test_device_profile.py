@@ -93,7 +93,7 @@ class TestDeviceTimeSplit:
     def test_four_way_split_sums_to_window(self, tmp_path):
         """compute + hidden + exposed + gap == window, with a collective
         half-hidden under compute and a host gap between ops."""
-        from distributed_pytorch_training_tpu.experiments.trace_analysis \
+        from distributed_pytorch_training_tpu.telemetry.trace_analysis \
             import device_time_split
 
         log = _write_trace(
@@ -120,7 +120,7 @@ class TestDeviceTimeSplit:
     def test_cpu_thunk_lanes_and_wrapped_names(self, tmp_path):
         """The CPU test backend's shape: no device pids, wrapped_ thunk
         names, runtime bookkeeping excluded."""
-        from distributed_pytorch_training_tpu.experiments.trace_analysis \
+        from distributed_pytorch_training_tpu.telemetry.trace_analysis \
             import device_time_split
 
         log = _write_trace(

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from distributed_pytorch_training_tpu.experiments.scaling import (
+from distributed_pytorch_training_tpu.analysis.hlo_rules import (
     collective_census,
 )
 
@@ -38,7 +38,7 @@ def test_trace_derived_collective_share(mesh8, tmp_path):
     from distributed_pytorch_training_tpu.experiments.harness import (
         build_image_trainer, synth_image_batch,
     )
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.telemetry.trace_analysis import (
         capture_step_trace, collective_share,
     )
 
@@ -58,7 +58,7 @@ def test_trace_derived_collective_share(mesh8, tmp_path):
 def test_trace_parser_raises_without_trace(tmp_path):
     import pytest
 
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.telemetry.trace_analysis import (
         collective_share,
     )
     with pytest.raises(FileNotFoundError):
@@ -143,7 +143,7 @@ def test_comm_overlap_split_math(tmp_path):
     import gzip
     import json
 
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.telemetry.trace_analysis import (
         comm_overlap_split,
     )
 
@@ -193,7 +193,7 @@ def test_comm_overlap_split_cross_pid_and_async_start(tmp_path):
     import gzip
     import json
 
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.telemetry.trace_analysis import (
         comm_overlap_split,
     )
 
@@ -242,7 +242,7 @@ def test_trace_census_ragged_all_to_all_and_async_pairing(tmp_path):
     import gzip
     import json
 
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.telemetry.trace_analysis import (
         collective_share,
     )
 
@@ -436,140 +436,3 @@ def test_chip_peak_unknown_tpu_kind_raises():
     assert chip_peak_tflops(dev("cpu", "cpu")) is None
     with pytest.raises(KeyError, match="TPU v9 imaginary"):
         chip_peak_tflops(dev("tpu", "TPU v9 imaginary"))
-
-
-class TestBenchReport:
-    """report.py regenerates the README benchmark table from the committed
-    bench_history.jsonl (VERDICT r4 missing #2: provenance for every row)."""
-
-    ENTRY = {
-        "metric": "resnet18_cifar10_train_throughput_bf16_b4096",
-        "value": 1000.0, "n_chips": 1, "chip": "TPU v5 lite",
-        "vs_baseline": 4.0, "timestamp": "2026-07-30T00:00:00Z",
-        "configs": [
-            {"model": "resnet18", "bf16": True, "per_device_batch": 4096,
-             "samples_per_sec_chip": 1000.0, "mfu_pct": 50.0, "image_hw": 32},
-            {"model": "resnet18", "bf16": False, "per_device_batch": 4096,
-             "samples_per_sec_chip": 250.0, "mfu_pct": 12.0, "image_hw": 32},
-            {"model": "gpt2_124m", "bf16": True, "per_device_batch": 8,
-             "seq_len": 1024, "samples_per_sec_chip": 100.0,
-             "tokens_per_sec": 102400.0, "mfu_pct": 45.0},
-        ],
-        "configs_skipped": ["bert_base"],
-    }
-
-    def test_renders_latest_entry_as_markdown(self, tmp_path, capsys):
-        import json
-
-        from distributed_pytorch_training_tpu.experiments.report import main
-
-        hist = tmp_path / "bench_history.jsonl"
-        older = dict(self.ENTRY, value=900.0, timestamp="2026-07-29T00:00:00Z")
-        hist.write_text(json.dumps(older) + "\n" + json.dumps(self.ENTRY) + "\n")
-        assert main(["--history", str(hist)]) == 0
-        out = capsys.readouterr().out
-        assert "| ResNet-18 / CIFAR-10 (headline) | 4096 | 1,000 | 50.0% |" in out
-        assert "fp32 `HIGHEST` baseline" in out
-        assert "GPT-2 124M @ S=1024 | 8 | 100 (102k tok/s) | 45.0% |" in out
-        assert "2026-07-30" in out  # the LATEST entry won
-        assert "bert_base" in out   # skipped configs stay visible
-
-    def test_all_lists_every_run(self, tmp_path, capsys):
-        import json
-
-        from distributed_pytorch_training_tpu.experiments.report import main
-
-        hist = tmp_path / "bench_history.jsonl"
-        hist.write_text(json.dumps(self.ENTRY) + "\n")
-        assert main(["--history", str(hist), "--all"]) == 0
-        assert "resnet18_cifar10" in capsys.readouterr().out
-
-    def test_missing_history_fails_loudly(self, tmp_path, capsys):
-        from distributed_pytorch_training_tpu.experiments.report import main
-
-        assert main(["--history", str(tmp_path / "nope.jsonl")]) == 1
-        assert "no history" in capsys.readouterr().err
-
-    def test_merged_view_joins_chunked_runs(self, tmp_path, capsys):
-        """The full matrix accumulates through `bench.py --only` chunk runs;
-        the default report view must join them — newest per config, CPU
-        mechanism-validation rows excluded once a TPU row exists."""
-        import json
-
-        from distributed_pytorch_training_tpu.experiments.report import main
-
-        cpu = {"metric": "m", "value": 1.0, "chip": "cpu",
-               "timestamp": "2026-07-28T00:00:00Z",
-               "configs": [{"model": "resnet18", "bf16": True,
-                            "per_device_batch": 256,
-                            "samples_per_sec_chip": 1.0, "mfu_pct": None}]}
-        chunk = {"metric": "gpt2_124m_train_throughput_bf16", "value": 100.0,
-                 "chip": "TPU v5 lite", "timestamp": "2026-07-31T02:00:00Z",
-                 "only": ["gpt2_124m"],
-                 "configs": [{"model": "gpt2_124m", "label": "gpt2_124m",
-                              "bf16": True, "per_device_batch": 8,
-                              "seq_len": 1024, "samples_per_sec_chip": 100.0,
-                              "mfu_pct": 45.0}],
-                 "configs_skipped": []}
-        stale = dict(chunk, timestamp="2026-07-30T00:00:00Z")
-        stale["configs"] = [dict(chunk["configs"][0],
-                                 samples_per_sec_chip=90.0)]
-        hist = tmp_path / "h.jsonl"
-        hist.write_text("\n".join(json.dumps(e) for e in
-                                  (cpu, stale, self.ENTRY, chunk)) + "\n")
-        assert main(["--history", str(hist)]) == 0
-        out = capsys.readouterr().out
-        assert "ResNet-18 / CIFAR-10 (headline)" in out   # from ENTRY
-        assert "| 100 " in out and "| 90 " not in out     # newest chunk won
-        assert "2026-07-31T02:00:00Z" in out              # per-row source
-        assert "| 256 " not in out                        # cpu entry excluded
-        assert "bert_base" in out                         # still unmeasured
-
-    def test_latest_flag_keeps_single_entry_view(self, tmp_path, capsys):
-        import json
-
-        from distributed_pytorch_training_tpu.experiments.report import main
-
-        hist = tmp_path / "h.jsonl"
-        hist.write_text(json.dumps(self.ENTRY) + "\n")
-        assert main(["--history", str(hist), "--latest"]) == 0
-        assert "Measured on 1x TPU v5 lite" in capsys.readouterr().out
-
-
-def test_report_write_updates_readme_between_markers(tmp_path):
-    """--write keeps the README's committed-measurements table a pure
-    projection of bench_history.jsonl (hand-edited numbers are what VERDICT
-    r4 called 'indistinguishable from fiction'). Idempotent: a second write
-    reports no change."""
-    from distributed_pytorch_training_tpu.experiments import report
-
-    readme = tmp_path / "README.md"
-    readme.write_text(
-        "intro\n\n<!-- bench-table:begin (regen hint) -->\nstale\n"
-        "<!-- bench-table:end -->\n\nfooter\n")
-    entries = [{"chip": "TPU v5 lite", "timestamp": "2026-07-31T01:05:56Z",
-                "vs_baseline": 4.135,
-                "configs": [{"model": "resnet18", "bf16": True,
-                             "per_device_batch": 4096,
-                             "samples_per_sec_chip": 459280.51,
-                             "mfu_pct": 52.17}],
-                "configs_skipped": ["gpt2_124m"]}]
-    assert report.write_readme_table(entries, readme) is True
-    text = readme.read_text()
-    assert "stale" not in text
-    assert "459,281" in text and "52.17%" in text
-    assert "still unmeasured on this chip: gpt2_124m" in text
-    assert text.startswith("intro\n\n<!-- bench-table:begin")
-    assert text.rstrip().endswith("footer")
-    # idempotent second write
-    assert report.write_readme_table(entries, readme) is False
-
-    # missing markers must fail loudly, not corrupt the file
-    bare = tmp_path / "bare.md"
-    bare.write_text("no markers here\n")
-    try:
-        report.write_readme_table(entries, bare)
-    except SystemExit as e:
-        assert "markers" in str(e)
-    else:
-        raise AssertionError("expected SystemExit on missing markers")

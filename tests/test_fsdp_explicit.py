@@ -207,7 +207,7 @@ def test_fsdp_census_one_gather_and_one_scatter_per_layer_group(mesh8, wire):
     """The acceptance census: gathers == layer groups (above the floor),
     gradients land as per-layer reduce-scatter / s8 all-to-all, and NO
     gradient-sized all-reduce survives."""
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.analysis.hlo_rules import (
         grad_sync_census,
     )
 

@@ -570,7 +570,7 @@ def _lower(mesh, **cfg):
 
 @pytest.mark.slow  # ~7 s; strictly redundant with the gsync_fp32 contract in the matrix gate
 def test_census_bucket_bound_fp32(mesh8):
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.analysis.hlo_rules import (
         grad_sync_census, verify_grad_sync_collectives,
     )
 
@@ -591,7 +591,7 @@ def test_census_bucket_bound_fp32(mesh8):
 
 
 def test_census_bf16_on_the_wire(mesh8):
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.analysis.hlo_rules import (
         preopt_hlo_text, verify_grad_sync_collectives,
     )
 
@@ -606,7 +606,7 @@ def test_census_bf16_on_the_wire(mesh8):
 
 
 def test_census_int8_on_the_wire(mesh8):
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.analysis.hlo_rules import (
         verify_grad_sync_collectives,
     )
 
@@ -626,7 +626,7 @@ def test_census_int8_multihop_two_per_bucket(mesh8):
     2 x ceil(bytes/cap) gradient-sized collectives (+slack 2) with the
     two-hop signature (all-to-all + all-gather) and s8 — never f32 — on
     the gradient wire."""
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.analysis.hlo_rules import (
         grad_sync_census, verify_grad_sync_collectives,
     )
 
@@ -653,7 +653,7 @@ def test_census_int8_multihop_two_per_bucket(mesh8):
 def test_census_rejects_unengaged_bucketing(mesh8):
     """The verifier must FAIL when handed an implicit-path step whose
     collective count exceeds the bucket bound — that is its whole job."""
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.analysis.hlo_rules import (
         grad_sync_census, verify_grad_sync_collectives,
     )
 
@@ -675,7 +675,7 @@ def test_census_rejects_unengaged_bucketing(mesh8):
 
 @pytest.mark.slow  # ~10 s; bf16 wire and zero1 are each pinned fast separately (bf16 converges, zero1 multihop parity)
 def test_zero1_bf16_wire_matches_zero1_fp32(mesh8):
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.analysis.hlo_rules import (
         grad_sync_census, preopt_hlo_text,
     )
 

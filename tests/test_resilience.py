@@ -341,21 +341,6 @@ class TestAsyncSave:
         assert asyn.snapshot_ms <= async_blocked
         assert asyn.saves_started == sync.saves_started == 1
 
-    def test_checkpoint_save_ab_instrument(self, rig, tmp_path):
-        """The bench instrument (experiments/harness.py): one sync + one
-        async throwaway save, blocked-ms per mode, nothing left on disk."""
-        from distributed_pytorch_training_tpu.experiments.harness import (
-            checkpoint_save_ab,
-        )
-
-        _trainer, state_factory, _ml = rig
-        out = checkpoint_save_ab(state_factory(), base_dir=str(tmp_path))
-        assert set(out) == {"sync_blocked_ms", "async_blocked_ms",
-                            "snapshot_ms", "write_ms"}
-        assert all(v >= 0.0 for v in out.values())
-        assert out["snapshot_ms"] <= out["async_blocked_ms"]
-        assert list(tmp_path.iterdir()) == []  # the A/B dir is gone
-
     def test_crash_between_commit_and_finalize_skipped_loudly(
             self, rig, tmp_path, capsys):
         """CI satellite: a crash injected between the orbax commit and the

@@ -3,8 +3,9 @@
 Pins, in order:
 * the cache-aware GPT-2 forward leaves the no-cache training path
   BYTE-IDENTICAL HLO (lowering test against a pre-cache reference copy);
-* prefill+decode logits match the full-context forward BITWISE in fp32,
-  including mixed-length batches vs solo forwards;
+* prefill logits match the full-context forward BITWISE in fp32; decode
+  logits, and mixed-length batches vs solo forwards, within 8 eps of the
+  largest logit (`assert_logits_match`: other XLA:CPU programs);
 * fp32 served logits are bitwise the (compiled, sharded) eval forward —
   the acceptance criterion;
 * zero recompiles across >= 20 mixed-length requests within the bucket
@@ -13,7 +14,7 @@ Pins, in order:
 * the request queue / continuous batcher / drain semantics;
 * the serving decode HLO contract + the two new analysis rules
   (mutation-tested, per the checker's own standard);
-* `measure_serving` (the bench row) and the slow CLI e2e.
+* `measure_serving` (the CLI's `bench` row) and the slow CLI e2e.
 """
 
 import json
@@ -72,8 +73,26 @@ def prompts(ns, seed=0):
 
 
 # ---------------------------------------------------------------------------
-# The cache-aware forward: HLO identity + bitwise logit parity
+# The cache-aware forward: HLO identity + logit parity
 # ---------------------------------------------------------------------------
+
+
+def assert_logits_match(got, want, what=""):
+    """Cached logits against the full-context forward's, where the two are
+    DIFFERENT XLA programs (a query of length 1, or another batch, against
+    the whole sequence). They were written as `==` on jax 0.4.x, whose
+    XLA:CPU gave both the same bits; jax 0.9.0's picks its dot kernel by
+    shape and reassociates the contraction. Found (PR 30): max abs 1.64e-7
+    on logits of 0.37 at most, 4.5e-7 of the largest logit (up to 5,752
+    ulps of a logit near zero, so no ulp count of the element is the
+    bound). Held to
+    8 eps of the largest logit (9.5e-7 of it), the next power of two over
+    what was found: every logit is a sum of products of that scale. Where
+    the programs are the same one, the tests below still say `==`."""
+    want = np.asarray(want)
+    bound = 8 * np.finfo(np.float32).eps * np.abs(want).max()
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=bound,
+                               err_msg=what)
 
 
 class TestCacheForward:
@@ -178,10 +197,11 @@ class TestCacheForward:
                                   cache=cache0)
         assert bool(jnp.all(pre == ev))
 
-    def test_prefill_decode_matches_full_forward_bitwise(self, tiny):
-        """The satellite pin: prefill over the prompt + K forced decode
-        steps reproduce the full-context forward's logits BITWISE in
-        fp32."""
+    def test_prefill_decode_matches_full_forward(self, tiny):
+        """The satellite pin: prefill over the prompt reproduces the
+        full-context forward's logits BITWISE in fp32, and K forced decode
+        steps reproduce them as far as two XLA:CPU programs can
+        (`assert_logits_match`)."""
         model, params = tiny
         rng = np.random.RandomState(2)
         B, S, K = 3, 12, 4
@@ -198,11 +218,12 @@ class TestCacheForward:
                                     ids[:, S + k][:, None], train=False,
                                     cache=cache, cache_positions=pos)
             dec.append(lg[:, 0])
-        assert bool(jnp.all(jnp.stack(dec, axis=1) == full[:, S:]))
+        assert_logits_match(jnp.stack(dec, axis=1), full[:, S:])
 
-    def test_mixed_length_decode_matches_solo_forward_bitwise(self, tiny):
+    def test_mixed_length_decode_matches_solo_forward(self, tiny):
         """Rows at DIFFERENT prompt lengths decode in one batch; each
-        row's logits equal its own solo full-context forward bitwise —
+        row's logits are its own solo full-context forward's
+        (`assert_logits_match`: a batch of 3 x 12 against 1 x n+1) —
         padding and batch company are invisible."""
         model, params = tiny
         rng = np.random.RandomState(3)
@@ -223,8 +244,8 @@ class TestCacheForward:
         for i, n in enumerate(lens):
             solo = model.apply({"params": params}, toks[i:i + 1, :n + 1],
                                train=False)
-            assert bool(jnp.all(pre[i, :n] == solo[0, :n])), f"row {i}"
-            assert bool(jnp.all(lg[i, 0] == solo[0, n])), f"row {i} decode"
+            assert_logits_match(pre[i, :n], solo[0, :n], f"row {i}")
+            assert_logits_match(lg[i, 0], solo[0, n], f"row {i} decode")
 
     def test_kernel_attention_with_cache_raises(self):
         def fake_kernel(q, k, v, mask=None, dtype=jnp.float32):
@@ -725,7 +746,7 @@ class TestServingTelemetry:
 
 class TestMeasureServing:
     def test_bench_row_schema_and_zero_recompiles(self, mesh8, devices):
-        from distributed_pytorch_training_tpu.experiments.harness import (
+        from distributed_pytorch_training_tpu.serving.loadtest import (
             measure_serving,
         )
 
@@ -744,7 +765,7 @@ class TestMeasureServing:
         assert row["checkpoint"] is None  # random-init smoke, says so
 
     def test_bench_rejects_image_models_upfront(self, devices):
-        from distributed_pytorch_training_tpu.experiments.harness import (
+        from distributed_pytorch_training_tpu.serving.loadtest import (
             measure_serving,
         )
 
@@ -756,7 +777,7 @@ class TestMeasureServing:
         """A bert (embedding) bench generates nothing: the row must not
         report a tokens_per_sec, and the decode contract reads as skipped
         rather than error."""
-        from distributed_pytorch_training_tpu.experiments.harness import (
+        from distributed_pytorch_training_tpu.serving.loadtest import (
             measure_serving,
         )
 

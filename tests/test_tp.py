@@ -817,7 +817,7 @@ def test_serving_engine_tp_mesh_matches_1d(devices):
     model axis via the GSPMD rules and the generated greedy tokens match
     the 1-D engine's (multi-chip serving of big models — the ISSUE-13
     motivation's serving half)."""
-    from distributed_pytorch_training_tpu.experiments.harness import (
+    from distributed_pytorch_training_tpu.serving.build import (
         build_serving_engine,
     )
 

@@ -5,7 +5,7 @@ sharded update must (a) train the SAME trajectory as the replicated
 DDP-style update — layout is a performance fact, not a math fact — for both
 SGD-momentum and AdamW, including the grad-accum and bf16 variants; (b)
 actually replace the gradient all-reduces with reduce-scatter + all-gather
-in the compiled HLO (the static census, experiments/trace_analysis.py); and
+in the compiled HLO (the static census, analysis/hlo_rules.py); and
 (c) round-trip its flat-sharded optimizer state through a checkpoint.
 
 Tolerances: SGD parity is tight (the update is elementwise in the gradient,
@@ -168,7 +168,7 @@ def test_zero1_hlo_census_reduce_scatter_replaces_all_reduce(mesh8):
     sized all-reduce; reduce-scatter + all-gather appear instead. Scalar
     psums (metrics, clip norm) are allowed — the census floor excludes
     them."""
-    from distributed_pytorch_training_tpu.experiments.trace_analysis import (
+    from distributed_pytorch_training_tpu.analysis.hlo_rules import (
         verify_zero1_collectives, weight_update_census,
     )
 
@@ -243,8 +243,8 @@ def test_zero1_single_shard_is_replicated_passthrough(devices):
 
 
 def test_zero1_single_shard_passthrough_via_harness_adamw(devices):
-    """The bench canary path (EXTRA_CONFIGS *_zero1 on one chip): AdamW's
-    clip must NOT carry shard axes when the Trainer runs the replicated
+    """zero1 asked for on one chip (`experiments.scaling zero1` there):
+    AdamW's clip must NOT carry shard axes when the Trainer runs the replicated
     fallback — a psum over unbound axis names is a trace-time crash, not a
     passthrough."""
     from distributed_pytorch_training_tpu.experiments.harness import (
