@@ -29,7 +29,8 @@ whole cycle. This module rebuilds the decode loop around SLOTS:
   emitted stream is identical regardless of slot assignment, join order,
   or batch company (the determinism satellite pins this).
   ``temperature=0`` short-circuits to argmax — bitwise the PR 9 greedy
-  path.
+  path — and a step none of whose live rows samples runs the argmax
+  alone (`sample_tokens` branches on the device).
 * `ContinuousScheduler` is the host loop: admit from the queue
   (``RequestQueue.take`` — FIFO, bucket-blind), run the decode step,
   mirror per-slot budgets in Python ints, and complete requests the
@@ -91,24 +92,42 @@ def sample_tokens(logits: jnp.ndarray, keys: jnp.ndarray,
     OWN key (``keys`` (rows, 2) uint32), so a row's token is a function of
     (its logits, its key, its knobs) alone — batch-mates, slot index, and
     pool size are invisible (the determinism contract). ``temperature <= 0``
-    selects plain argmax — bitwise the dense engine's greedy path; the
-    sampled branch's value is computed but discarded by the where."""
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    temps = jnp.maximum(temperatures, 1e-6)[:, None]
-    scaled = logits.astype(jnp.float32) / temps
-    order = jnp.argsort(-scaled, axis=-1)           # descending
-    sorted_l = jnp.take_along_axis(scaled, order, axis=-1)
-    probs = jax.nn.softmax(sorted_l, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    # nucleus: keep the smallest prefix with mass >= top_p; the first
-    # column always survives (cum - prob == 0 < top_p)
-    keep = (cum - probs) < top_ps[:, None]
-    masked = jnp.where(keep, sorted_l, jnp.finfo(jnp.float32).min)
-    choice = jax.vmap(lambda k, row: jax.random.categorical(k, row))(
-        keys, masked)
-    sampled = jnp.take_along_axis(
-        order, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
-    return jnp.where(temperatures <= 0.0, greedy, sampled)
+    selects plain argmax — bitwise the dense engine's greedy path.
+
+    One program, two branches, chosen ON THE DEVICE by the temperatures
+    it is handed: when no row has ``temperature > 0`` the argmax is the
+    whole of it (the sort and the sorted gather over (rows, vocab) are
+    35 of a 38 ms decode step at 64 x 50,257 on a v5e, PERF.md); when
+    some row samples, every row goes through the nucleus branch, whose
+    closing ``where`` still hands a greedy row its argmax. A greedy row's
+    token is therefore the same on both branches and a sampling row only
+    ever sees the second, so the contract above holds whichever is taken.
+    Callers zero the temperature of a row whose token they drop (a dead
+    slot), so that a finished sampling request does not hold later
+    all-greedy steps on the nucleus branch."""
+
+    def greedy_only():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def nucleus():
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        temps = jnp.maximum(temperatures, 1e-6)[:, None]
+        scaled = logits.astype(jnp.float32) / temps
+        order = jnp.argsort(-scaled, axis=-1)           # descending
+        sorted_l = jnp.take_along_axis(scaled, order, axis=-1)
+        probs = jax.nn.softmax(sorted_l, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        # nucleus: keep the smallest prefix with mass >= top_p; the first
+        # column always survives (cum - prob == 0 < top_p)
+        keep = (cum - probs) < top_ps[:, None]
+        masked = jnp.where(keep, sorted_l, jnp.finfo(jnp.float32).min)
+        choice = jax.vmap(lambda k, row: jax.random.categorical(k, row))(
+            keys, masked)
+        sampled = jnp.take_along_axis(
+            order, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+        return jnp.where(temperatures <= 0.0, greedy, sampled)
+
+    return jax.lax.cond(jnp.any(temperatures > 0.0), nucleus, greedy_only)
 
 
 class SlotEngine(InferenceEngine):
@@ -318,9 +337,10 @@ class SlotEngine(InferenceEngine):
         `paged_attention` call. One step on a v5e, GPT-2 124M in bf16, 64
         rows of up to 1024 positions: 197.7 ms of device time on the gather
         read with the pool at rest as (L, pages, page, H, D) (ledger, PR
-        24), 37.9 ms on the kernel read, of which the sampler is 34.9 and
-        the twelve kernel calls 1.8 (my chip runs, PR 25; PERF.md section
-        5)."""
+        24), 37.8 ms on the kernel read with every row through the nucleus
+        (ledger, PR 30: the sampler 34.9, the twelve kernel calls 1.6), and
+        2.4 ms when no live row samples, the sampler 0.012 of it (my chip
+        run, PR 31; PERF.md section 5)."""
         rows = self.config.rows
         fused = self._fused_quantize
         kernel = self.kv_path == "kernel"
@@ -368,7 +388,11 @@ class SlotEngine(InferenceEngine):
             # the token at position p+1, from THIS request's key stream
             step_keys = jax.vmap(jax.random.fold_in)(
                 control["keys"], positions + 1)
-            nxt = sample_tokens(logits[:, 0], step_keys, control["temps"],
+            # a finished slot keeps its temperature until it is admitted
+            # again and its token is dropped below: it must not choose
+            # the sampler's branch
+            nxt = sample_tokens(logits[:, 0], step_keys,
+                                jnp.where(active, control["temps"], 0.0),
                                 control["top_ps"])
             with jax.named_scope("bookkeeping"):
                 act = active.astype(jnp.int32)
@@ -891,6 +915,12 @@ class ContinuousScheduler:
                                    self.running.values()),
                                self.burst_steps))
         self._step_decode_loop(steps)
+        # how often the sampler's argmax branch is the one the device
+        # takes: every slot in `running` is live through the whole burst
+        # (steps <= its `left`), so the host's own mirror decides it
+        telemetry.counter("serving_decode_steps", steps)
+        if all(st.req.temperature <= 0.0 for st in self.running.values()):
+            telemetry.counter("serving_decode_steps_all_greedy", steps)
 
     def _complete_finished(self) -> None:   # lock-held: _lock
         t0 = time.perf_counter()

@@ -231,14 +231,18 @@ class SpeculativeEngine(SlotEngine):
             # sample every window output with ITS position's key — window
             # row j's token is bitwise the plain step's at that position
             # (same logits by the window parity pin, same fold_in key,
-            # and sample_tokens is row-independent)
+            # and sample_tokens is row-independent: whichever of its two
+            # branches the other rows make it take, a row's token is the
+            # same). A dead row's outputs are all dropped below (n_emit
+            # 0), so its stale temperature is zeroed like the plain
+            # step's and cannot choose the branch.
             win_pos = positions[:, None] + jnp.arange(s)[None, :]
             step_keys = jax.vmap(jax.random.fold_in)(
                 jnp.repeat(control["keys"], s, axis=0),
                 (win_pos + 1).reshape(-1))
             outs = sample_tokens(
                 logits.reshape(rows * s, -1), step_keys,
-                jnp.repeat(control["temps"], s),
+                jnp.repeat(jnp.where(active, control["temps"], 0.0), s),
                 jnp.repeat(control["top_ps"], s)).reshape(rows, s)
             # exact-match acceptance: keep the longest prefix of
             # proposals that equals the target-sampled stream, then emit

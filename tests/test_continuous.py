@@ -281,12 +281,17 @@ class TestSlotEngineGreedy:
             np.testing.assert_array_equal(
                 r.tokens, ref_greedy(model, params, s, w))
 
-    def test_zero_recompiles_after_warmup(self, slot_engine):
+    @pytest.mark.parametrize("temperatures", [(0.0,), (0.0, 0.8, 1.0)],
+                             ids=["greedy", "mixed"])
+    def test_zero_recompiles_after_warmup(self, slot_engine, temperatures):
+        """Greedy or mixed, the census stays where `warmup` left it: both
+        branches of the sampler are inside the warmed programs."""
         rng = np.random.RandomState(5)
         before = slot_engine.compiles
         specs = [(rng.randint(0, VOCAB, int(rng.randint(1, 17)))
                   .astype(np.int32),
-                  dict(temperature=0.0,
+                  dict(temperature=float(rng.choice(temperatures)),
+                       seed=int(rng.randint(1, 1000)),
                        max_new_tokens=int(rng.randint(1, 7))))
                  for _ in range(22)]
         res = serve_all(slot_engine, specs)
@@ -317,6 +322,94 @@ class TestSlotEngineGreedy:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("sample")
+def oracle_sample_tokens(logits, keys, temperatures, top_ps):
+    """`sample_tokens` as it stood before it branched (ISSUE 31): every
+    row through the nucleus, the argmax kept by the closing where. The
+    oracle the branching sampler is held to, token for token."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    temps = jnp.maximum(temperatures, 1e-6)[:, None]
+    scaled = logits.astype(jnp.float32) / temps
+    order = jnp.argsort(-scaled, axis=-1)           # descending
+    sorted_l = jnp.take_along_axis(scaled, order, axis=-1)
+    probs = jax.nn.softmax(sorted_l, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = (cum - probs) < top_ps[:, None]
+    masked = jnp.where(keep, sorted_l, jnp.finfo(jnp.float32).min)
+    choice = jax.vmap(lambda k, row: jax.random.categorical(k, row))(
+        keys, masked)
+    sampled = jnp.take_along_axis(
+        order, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+    return jnp.where(temperatures <= 0.0, greedy, sampled)
+
+
+def sub_jaxprs(jaxpr):
+    """``jaxpr`` and every jaxpr nested in its equations' parameters
+    (`pjit` bodies, `cond` branches, ...), depth first."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from sub_jaxprs(sub)
+
+
+def primitives(jaxpr):
+    return [e.primitive.name for j in sub_jaxprs(jaxpr) for e in j.eqns]
+
+
+@pytest.fixture(scope="module")
+def oracle_engine(mesh8, tiny):
+    """A SlotEngine whose programs were traced around the ORACLE sampler:
+    what every stream was before the sampler branched."""
+    from distributed_pytorch_training_tpu.serving import continuous
+
+    model, params = tiny
+    eng = SlotEngine(model, mesh8, paged_cfg(), params)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(continuous, "sample_tokens", oracle_sample_tokens)
+        eng.warmup()
+    return eng
+
+
+def probed_sampler(seen):
+    """`sample_tokens` behind a probe: the predicate of its branch, as the
+    device computes it from the temperatures a caller hands over, lands
+    in ``seen`` as (rows, any row samples) once per execution."""
+    def probed(logits, keys, temperatures, top_ps):
+        jax.debug.callback(
+            lambda hot, n=temperatures.shape[0]: seen.append((n, bool(hot))),
+            jnp.any(temperatures > 0.0))
+        return sample_tokens(logits, keys, temperatures, top_ps)
+
+    return probed
+
+
+def decode_step_counters(events):
+    """(`serving_decode_steps`, `serving_decode_steps_all_greedy`) summed
+    over ``events`` by telemetry's own summary."""
+    from distributed_pytorch_training_tpu.telemetry.__main__ import summarize
+
+    counters = summarize(events)["counters"]
+    return (counters.get("serving_decode_steps", 0),
+            counters.get("serving_decode_steps_all_greedy", 0))
+
+
+# (temperatures, top_ps) of the oracle cases: rows of the decode step and
+# the rows x window of the speculative verify step (each knob repeated)
+_MIXED = ([0.0, 0.8, 0.0, 1.0, 0.0, 0.0, 1.5, 0.0],
+          [1.0, 0.9, 0.3, 1.0, 1.0, 0.9, 0.7, 1.0])
+_KNOBS = {
+    "all_greedy": ([0.0] * 8, [1.0] * 8),
+    "all_sampling": ([0.7, 1.0, 1.3, 0.2, 0.9, 1.0, 2.0, 0.5],
+                     [0.9, 1.0, 0.5, 1.0, 0.95, 0.1, 1.0, 0.8]),
+    "mixed": _MIXED,
+    "one_sampling_row": ([0.0] * 7 + [1.0], [1.0] * 7 + [0.9]),
+    "verify_window": tuple(np.repeat(knob, 3) for knob in _MIXED),
+}
+
+
 class TestSamplingDeterminism:
     def test_temperature_zero_is_argmax(self):
         rng = np.random.RandomState(0)
@@ -325,6 +418,68 @@ class TestSamplingDeterminism:
         toks = sample_tokens(logits, keys, jnp.zeros(5), jnp.ones(5))
         np.testing.assert_array_equal(np.asarray(toks),
                                       np.argmax(np.asarray(logits), -1))
+
+    @pytest.mark.parametrize("row_sharded", [False, True],
+                             ids=["one_device", "rows_over_mesh8"])
+    @pytest.mark.parametrize("case", list(_KNOBS))
+    def test_equals_the_unconditional_sampler(self, mesh8, case,
+                                              row_sharded):
+        """Token for token the sampler that ran every row through the
+        nucleus: greedy rows on either branch, sampling rows on the one
+        branch they ever see, alone or over the mesh's batch shards."""
+        from distributed_pytorch_training_tpu.parallel.sharding import (
+            batch_sharding,
+        )
+
+        temps, top_ps = (np.asarray(x, np.float32) for x in _KNOBS[case])
+        rows = len(temps)
+        rng = np.random.RandomState(rows)
+        logits = rng.randn(rows, VOCAB).astype(np.float32)
+        logits[:, 5] = logits[:, 11]      # a tie in every row
+        keys = np.stack([np.asarray(jax.random.PRNGKey(40 + i), np.uint32)
+                         for i in range(rows)])
+        args = (logits, keys, temps, top_ps)
+        if row_sharded:
+            args = tuple(jax.device_put(a, batch_sharding(mesh8, a.ndim))
+                         for a in args)
+        got = jax.jit(sample_tokens)(*args)
+        want = jax.jit(oracle_sample_tokens)(*args)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        greedy = temps <= 0.0
+        np.testing.assert_array_equal(np.asarray(got)[greedy],
+                                      np.argmax(logits, -1)[greedy])
+
+    def test_one_cond_and_no_sort_on_the_greedy_side(self):
+        """One program with two branches: exactly one `cond`, whose false
+        branch (no row samples) is the argmax and nothing that sorts,
+        gathers or draws; the true branch is where the sort lives."""
+        args = (jnp.zeros((4, VOCAB)), jnp.zeros((4, 2), jnp.uint32),
+                jnp.zeros(4), jnp.ones(4))
+        jaxpr = jax.make_jaxpr(sample_tokens)(*args).jaxpr
+        assert primitives(jaxpr).count("cond") == 1
+        (cond,) = [e for j in sub_jaxprs(jaxpr) for e in j.eqns
+                   if e.primitive.name == "cond"]
+        no_row_samples, some_row_samples = (
+            primitives(b.jaxpr) for b in cond.params["branches"])
+        assert "argmax" in no_row_samples
+        assert not {"sort", "gather", "cumsum", "random_bits",
+                    "threefry2x32"} & set(no_row_samples)
+        assert "sort" in some_row_samples and "argmax" in some_row_samples
+        # nothing that sorts stands outside the cond either
+        outside = [e.primitive.name for e in jaxpr.eqns]
+        assert "sort" not in outside and "pjit" not in outside, outside
+
+    @pytest.mark.parametrize("kind", ["paged_decode", "paged_prefill",
+                                      "paged_resume"])
+    def test_each_program_holds_the_sampler_once(self, slot_engine, kind):
+        """One compiled program per kind, as before the sampler branched:
+        the branch is inside the program (one conditional each), not a
+        host-side choice between two executables."""
+        lowered = {"paged_decode": slot_engine.lower_paged_decode,
+                   "paged_prefill": lambda: slot_engine.lower_paged_prefill(8),
+                   "paged_resume": lambda: slot_engine.lower_paged_resume(8),
+                   }[kind]()
+        assert lowered.as_text().count("stablehlo.case") == 1
 
     def test_stream_ignores_slots_join_order_and_company(self, slot_engine,
                                                          tiny):
@@ -345,6 +500,112 @@ class TestSamplingDeterminism:
         first = serve_all(slot_engine, [(target, t_kw)] + decoys_b)[0]
         np.testing.assert_array_equal(alone.tokens, last.tokens)
         np.testing.assert_array_equal(alone.tokens, first.tokens)
+
+    def test_greedy_stream_ignores_which_branch_its_company_chooses(
+            self, slot_engine, tiny):
+        """A greedy request alone and beside greedy company runs on the
+        sampler's argmax branch; beside a sampling request every step it
+        shares runs on the nucleus branch. Its stream is the solo greedy
+        forward's in all three."""
+        model, params = tiny
+        (target,) = prompts((9,), seed=20)
+        t_kw = dict(temperature=0.0, max_new_tokens=6)
+        greedy_co = [(s, dict(temperature=0.0, max_new_tokens=3 + i))
+                     for i, s in enumerate(prompts((4, 13, 7), seed=21))]
+        sampling_co = [(s, dict(temperature=0.9, top_p=0.95, seed=70 + i,
+                                max_new_tokens=2 + 2 * i))
+                       for i, s in enumerate(prompts((6, 11, 3), seed=22))]
+        want = ref_greedy(model, params, target, 6)
+        for company in ([], greedy_co, sampling_co,
+                        greedy_co + sampling_co):
+            got = serve_all(slot_engine, company + [(target, t_kw)])[-1]
+            np.testing.assert_array_equal(got.tokens, want)
+
+    def test_every_stream_is_the_unconditional_samplers(self, slot_engine,
+                                                        oracle_engine):
+        """Mixed traffic with churn (12 requests over 8 rows) through the
+        engine and through one traced around the oracle sampler: every
+        stream identical, the sampling requests' included — a sampling
+        row's token is what it was before the sampler branched."""
+        rng = np.random.RandomState(23)
+        seqs = prompts([int(rng.randint(1, 17)) for _ in range(12)],
+                       seed=24)
+        specs = [(s, dict(temperature=float(rng.choice([0.0, 0.7, 1.2])),
+                          top_p=float(rng.choice([0.9, 1.0])),
+                          seed=300 + i,
+                          max_new_tokens=int(rng.randint(1, 7))))
+                 for i, s in enumerate(seqs)]
+        assert {kw["temperature"] > 0 for _, kw in specs} == {True, False}
+        got = serve_all(slot_engine, specs)
+        want = serve_all(oracle_engine, specs)
+        for i, (a, b) in enumerate(zip(got, want)):
+            np.testing.assert_array_equal(
+                a.tokens, b.tokens, err_msg=f"request {i} ({specs[i][1]})")
+
+    def test_a_finished_sampling_slot_does_not_choose_the_branch(
+            self, devices, tiny, monkeypatch):
+        """The predicate sees live rows only. A sampling request finishes
+        and its slot lies idle, still holding its temperature, while a
+        greedy request decodes on: those steps hand the sampler no
+        positive temperature (the device's own predicate, probed), and
+        the scheduler's two counters say the same from the host's mirror."""
+        from distributed_pytorch_training_tpu.serving import continuous
+
+        model, params = tiny
+        mesh1 = build_mesh(MeshSpec(data=1), devices=devices[:1])
+        seen = []
+        monkeypatch.setattr(continuous, "sample_tokens",
+                            probed_sampler(seen))
+        eng = SlotEngine(model, mesh1, paged_cfg(rows=4, buckets=(8,)),
+                         params)
+        short, long = prompts((5, 7), seed=25)
+        rec = telemetry.configure()
+        try:
+            res = serve_all(eng, [
+                (short, dict(temperature=0.9, seed=5, max_new_tokens=2)),
+                (long, dict(temperature=0.0, max_new_tokens=6))])
+            jax.effects_barrier()
+            events = rec.tail(10_000)
+        finally:
+            telemetry.reset()
+        assert [len(r.tokens) for r in res] == [2, 6]
+        np.testing.assert_array_equal(
+            res[1].tokens, ref_greedy(model, params, long, 6))
+        # the slot the sampling request left still holds its temperature
+        assert float(jnp.max(eng._control["temps"])) > 0.0
+        decode = [hot for rows, hot in seen if rows == 4]
+        # token #0 comes from the prefill: one shared step ends the short
+        # request, four more finish the long one
+        assert decode == [True] + [False] * 4
+        assert [hot for rows, hot in seen if rows == 1] == [True, False]
+        assert decode_step_counters(events) == (len(decode),
+                                                decode.count(False))
+
+    @pytest.mark.parametrize("sampling", [0, 1], ids=["all_greedy",
+                                                      "one_sampling"])
+    def test_counters_say_how_often_the_argmax_branch_runs(
+            self, slot_engine, sampling):
+        """`serving_decode_steps_all_greedy` counts the decode steps whose
+        every live request is greedy: all of them for greedy traffic,
+        fewer as soon as one request samples (never none here: the
+        sampling request is the shortest)."""
+        specs = [(s, dict(temperature=0.0, max_new_tokens=6))
+                 for s in prompts((5, 9, 12, 3), seed=26)]
+        specs += [(prompts((6,), seed=27)[0],
+                   dict(temperature=1.0, seed=9, max_new_tokens=3))
+                  ] * sampling
+        rec = telemetry.configure()
+        try:
+            serve_all(slot_engine, specs)
+            events = rec.tail(10_000)
+        finally:
+            telemetry.reset()
+        steps, all_greedy = decode_step_counters(events)
+        assert steps >= 5
+        if sampling:
+            assert 0 < all_greedy < steps
+        else:
+            assert all_greedy == steps
 
     def test_distinct_seeds_diverge(self, slot_engine):
         (s,) = prompts((8,), seed=13)

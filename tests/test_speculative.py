@@ -54,6 +54,7 @@ from distributed_pytorch_training_tpu.serving.speculative import (
     SpeculativeEngine, SpeculativeScheduler,
 )
 from distributed_pytorch_training_tpu.utils import locktrace
+from test_continuous import oracle_sample_tokens, probed_sampler
 
 VOCAB = 97
 SPEC_K = 3
@@ -232,12 +233,82 @@ class TestSpecBitwiseParity:
                 err_msg=f"request {i}: speculative stream diverged "
                         f"(kw {kws[i]})")
 
-    def test_zero_recompiles_after_warmup(self, spec_engine):
+    def test_mixed_streams_are_the_unconditional_samplers(
+            self, spec_engine, mesh8, tiny, monkeypatch):
+        """The verify step samples rows x window through the sampler that
+        branches; a plain engine traced around the oracle (every row
+        through the nucleus, as before ISSUE 31) emits the same streams,
+        greedy and sampling requests alike."""
+        from distributed_pytorch_training_tpu.serving import continuous
+
+        model, params = tiny
+        monkeypatch.setattr(continuous, "sample_tokens",
+                            oracle_sample_tokens)
+        oracle = SlotEngine(model, mesh8, paged_cfg(), params)
+        rng = np.random.RandomState(8)
+        seqs = prompts([int(rng.randint(1, 17)) for _ in range(10)],
+                       seed=9)
+        specs = [(s, dict(temperature=float(rng.choice([0.0, 0.7, 1.0])),
+                          top_p=float(rng.choice([0.9, 1.0])),
+                          seed=200 + i,
+                          max_new_tokens=int(rng.randint(2, 7))))
+                 for i, s in enumerate(seqs)]
+        assert {kw["temperature"] > 0 for _, kw in specs} == {True, False}
+        _, want = serve_all(oracle, specs)
+        _, got = serve_all(spec_engine, specs)
+        for i, (a, b) in enumerate(zip(got, want)):
+            np.testing.assert_array_equal(
+                a.tokens, b.tokens, err_msg=f"request {i} ({specs[i][1]})")
+
+    def test_verify_holds_the_sampler_once(self, spec_engine):
+        """One verify program with the branch inside it, as the plain
+        decode step's: no second executable for all-greedy rounds."""
+        text = spec_engine.lower_spec_verify().as_text()
+        assert text.count("stablehlo.case") == 1
+
+    def test_a_finished_sampling_slot_does_not_choose_the_verify_branch(
+            self, devices, tiny, draft_tiny, monkeypatch):
+        """The verify step hands the sampler live rows' temperatures
+        only: once the sampling request is done, its idle slot (which
+        keeps its temperature) no longer sends the greedy request's
+        rounds through the nucleus branch."""
+        from distributed_pytorch_training_tpu.parallel import (
+            MeshSpec, build_mesh,
+        )
+        from distributed_pytorch_training_tpu.serving import speculative
+
+        model, params = tiny
+        dmodel, dparams = draft_tiny
+        mesh1 = build_mesh(MeshSpec(data=1), devices=devices[:1])
+        seen = []
+        monkeypatch.setattr(speculative, "sample_tokens",
+                            probed_sampler(seen))
+        eng = SpeculativeEngine(model, mesh1,
+                                paged_cfg(rows=4, buckets=(8,)), params,
+                                dmodel, dparams, spec_k=SPEC_K)
+        short, long = prompts((5, 7), seed=25)
+        _, res = serve_all(eng, [
+            (short, dict(temperature=0.9, seed=5, max_new_tokens=2)),
+            (long, dict(temperature=0.0, max_new_tokens=6))])
+        jax.effects_barrier()
+        assert [len(r.tokens) for r in res] == [2, 6]
+        np.testing.assert_array_equal(
+            res[1].tokens, ref_greedy(model, params, long, 6))
+        assert float(jnp.max(eng._control["temps"])) > 0.0
+        rounds = [hot for n, hot in seen if n == 4 * (SPEC_K + 1)]
+        # the first round ends the short request (one token left of two)
+        assert rounds[0] is True and len(rounds) >= 2
+        assert not any(rounds[1:])
+
+    @pytest.mark.parametrize("temperatures", [(0.0,), (0.0, 0.8, 1.0)],
+                             ids=["greedy", "mixed"])
+    def test_zero_recompiles_after_warmup(self, spec_engine, temperatures):
         rng = np.random.RandomState(5)
         before = spec_engine.compiles
         specs = [(rng.randint(0, VOCAB, int(rng.randint(1, 17)))
                   .astype(np.int32),
-                  dict(temperature=0.0,
+                  dict(temperature=float(rng.choice(temperatures)),
+                       seed=int(rng.randint(1, 1000)),
                        max_new_tokens=int(rng.randint(1, 7))))
                  for _ in range(20)]
         sched, res = serve_all(spec_engine, specs)
