@@ -509,3 +509,189 @@ class TestRingAttentionChunked:
         for a, b in zip(g_c, g_r):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the tile body: operand dtype, the two bodies, the census, the block rule
+# ---------------------------------------------------------------------------
+
+def _flash_module():
+    import importlib
+
+    # the module, not the function `ops` re-exports under the same name
+    return importlib.import_module(
+        "distributed_pytorch_training_tpu.ops.flash_attention")
+
+
+# name -> (b, sq, sk, h, d, causal, padded keys)
+PARITY_SHAPES = {
+    "d64_s1024": (1, 1024, 1024, 2, 64, True, 0),
+    "d256_s1024": (1, 1024, 1024, 1, 256, True, 0),
+    "sq_ne_sk": (1, 256, 512, 2, 64, True, 0),
+    "non_causal": (1, 512, 512, 2, 64, False, 0),
+    "kv_valid": (2, 512, 512, 1, 64, True, 200),
+}
+# ||got - want|| / ||want|| of (out, dq, dk, dv) against the float32 oracle:
+# float32 tiles are float32 products, so rounding order only; bf16 tiles keep
+# float32 scores, statistics and accumulators and round p, ds (and the
+# outputs) to bf16 once, 2^-9 a value
+PARITY_TOL = {jnp.float32: 2e-6, jnp.bfloat16: 7.5e-3}   # found 6e-7, 2.5e-3
+
+
+def _parity_case(shape, dtype, seed=11):
+    b, sq, sk, h, d, causal, n_pad = PARITY_SHAPES[shape]
+    rng = np.random.RandomState(seed)
+    q, g = (jnp.asarray(rng.randn(b, sq, h, d) * 0.5, dtype) for _ in "qg")
+    k, v = (jnp.asarray(rng.randn(b, sk, h, d) * 0.5, dtype) for _ in "kv")
+    kv_valid = None
+    if n_pad:
+        valid = np.ones((b, sk), np.float32)
+        valid[:, sk - n_pad:] = 0.0        # key 0 stays: no all-masked row
+        kv_valid = jnp.asarray(valid)
+    return q, k, v, g, causal, kv_valid
+
+
+def _out_and_grads(attend, q, k, v, g):
+    out, vjp = jax.vjp(attend, q, k, v)
+    return (out,) + tuple(vjp(g.astype(out.dtype)))
+
+
+def _rel(got, want):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+class TestFlashTileBody:
+    @pytest.mark.parametrize("blocks", ["chosen", "explicit_128"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bf16"])
+    @pytest.mark.parametrize("shape", list(PARITY_SHAPES))
+    def test_parity_with_the_reference(self, shape, dtype, blocks):
+        """Forward and all three gradients against `_reference_attention`
+        in float32 on the same (bf16-valued) inputs."""
+        fa = _flash_module()
+        q, k, v, g, causal, kv_valid = _parity_case(shape, dtype)
+        block = None if blocks == "chosen" else 128
+        scale = 1.0 / np.sqrt(q.shape[-1])
+        got = _out_and_grads(
+            lambda q, k, v: flash_attention(q, k, v, causal, None, block,
+                                            block, kv_valid), q, k, v, g)
+        want = _out_and_grads(
+            lambda q, k, v: fa._reference_attention(q, k, v, causal, scale,
+                                                    kv_valid),
+            *(x.astype(jnp.float32) for x in (q, k, v, g)))
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            assert a.dtype == dtype
+            assert _rel(a, b) < PARITY_TOL[dtype], (name, _rel(a, b))
+        if kv_valid is not None:         # no gradient leaks into padding
+            pad = np.asarray(kv_valid) == 0
+            for grad in got[2:]:
+                assert np.abs(np.asarray(grad, np.float32)[pad]).max() == 0
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bf16"])
+    def test_a_tile_under_the_diagonal_is_bitwise_the_same_masked(
+            self, monkeypatch, dtype):
+        """The unmasked body is the masked body less a select that keeps
+        every score: with no tile ever counted as wholly under the diagonal
+        every live tile builds the mask, and out, lse, dq, dk, dv are the
+        same bits."""
+        fa = _flash_module()
+        q, k, v, g, causal, _ = _parity_case("sq_ne_sk", dtype)
+        static = dict(causal=True, sm_scale=0.125, block_q=64, block_k=64,
+                      interpret=True)
+        assert fa.tile_census(256, 512, 64, 64, True).full == 6
+
+        def run():       # under the jitted calls: their cache keeps a trace
+            out, lse = fa._fwd_call.__wrapped__(q, k, v, None, **static)
+            return (out, lse) + fa._bwd_call.__wrapped__(
+                q, k, v, out, lse, g, None, **static)
+
+        two_bodies = run()
+        monkeypatch.setattr(fa, "_tile_is_full",
+                            lambda qb, kb, block_q, block_k: qb < 0)
+        assert fa.tile_census(256, 512, 64, 64, True)[:3] == (22, 10, 0)
+        for a, b in zip(two_bodies, run()):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+
+    @pytest.mark.parametrize("sq,sk,block_q,block_k,causal", [
+        (1024, 1024, 512, 512, True), (8192, 8192, 512, 512, True),
+        (1024, 1024, 256, 256, True), (1024, 1024, 256, 512, True),
+        (1024, 1024, 512, 128, True), (256, 512, 64, 64, True),
+        (512, 256, 128, 64, True), (96, 96, 48, 48, True),
+        (100, 100, 100, 100, True), (1024, 2048, 512, 512, False),
+    ])
+    def test_census_against_a_count_of_the_mask(self, sq, sk, block_q,
+                                                block_k, causal):
+        fa = _flash_module()
+        keep = np.tril(np.ones((sq, sk), bool)) if causal \
+            else np.ones((sq, sk), bool)
+        tiles = keep.reshape(sq // block_q, block_q, sk // block_k, block_k)
+        some, every = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+        want = (int((~some).sum()), int((some & ~every).sum()),
+                int(every.sum()))
+        got = fa.tile_census(sq, sk, block_q, block_k, causal)
+        assert tuple(got)[:3] == want
+
+    def test_census_at_the_two_timed_shapes(self):
+        """What PERF.md quotes as how often the unmasked body engages."""
+        fa = _flash_module()
+        assert fa.tile_census(1024, 1024, 512, 512, True)[:3] == (1, 2, 1)
+        assert fa.tile_census(8192, 8192, 512, 512, True)[:3] == (120, 16,
+                                                                  120)
+        # and with the blocks the rule picks there
+        q = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16)
+        assert fa._blocks(None, None, q, q) == (1024, 1024)
+        q = jax.ShapeDtypeStruct((1, 8192, 16, 256), jnp.bfloat16)
+        assert fa._blocks(None, None, q, q) == (1024, 1024)
+        one_tile = fa.tile_census(1024, 1024, 1024, 1024, True)
+        assert one_tile[:3] == (0, 1, 0)
+        # the walked tile's rectangles: 2 blocks of 512 rows forward (3 of
+        # 4 quarters), 8 of 128 backward (36 of 64 squares)
+        assert one_tile.scores(512) == 3 * 512 * 512
+        assert one_tile.scores(128) == 36 * 128 * 128
+        assert one_tile.scores(1024) == 1024 * 1024      # taken whole
+        long = fa.tile_census(8192, 8192, 1024, 1024, True)
+        assert long[:3] == (28, 8, 28)
+        assert long.scores(128) == (28 * 64 + 8 * 36) * 128 * 128
+
+
+class TestFlashLowersForATpu:
+    """The lowering a chip would run, Pallas to Mosaic included, from the CPU
+    mesh (nothing compiles, nothing runs): the two timed shapes with the
+    blocks the shape rule picks, as a one-device program and per shard
+    inside `make_flash_attention_fn(mesh=)`'s `shard_map`. A block shape or
+    an operation the interpreter accepts and the TPU lowering refuses fails
+    here, before chip time."""
+
+    # cell -> (per-chip batch, S, heads, d)
+    TIMED = {"gpt2_355m": (8, 1024, 16, 64),
+             "qwen3_next_gated_attn": (1, 8192, 16, 256)}
+
+    @pytest.mark.parametrize("program", ["one_device", "shard_map"])
+    @pytest.mark.parametrize("cell", list(TIMED))
+    def test_forward_and_backward_lower(self, monkeypatch, devices, cell,
+                                        program):
+        fa = _flash_module()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(fa, "_interpret", lambda: False)
+        b, s, h, d = self.TIMED[cell]
+        mesh = None
+        if program == "shard_map":
+            mesh = build_mesh(MeshSpec(data=4), devices=devices[:4])
+            b *= 4
+        attend = make_flash_attention_fn(causal=True, mesh=mesh)
+        x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return attend(q, k, v, dtype=jnp.bfloat16).astype(
+                jnp.float32).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            x, x, x).lower(lowering_platforms=("tpu",)).as_text()
+        # a shard_map lowers to a manual computation over the mesh's axes
+        assert ("manual" in text) == (mesh is not None)
+        assert text.count("tpu_custom_call") >= 3
+        for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+            assert kernel in text

@@ -381,7 +381,7 @@ def test_flash_causal_flops_use_kernel_cost_estimate():
         jaxpr_matmul_flops,
     )
     from distributed_pytorch_training_tpu.ops import flash_attention
-    from distributed_pytorch_training_tpu.ops.flash_attention import _live_pairs
+    from distributed_pytorch_training_tpu.ops.flash_attention import tile_census
 
     b, s, h, d, blk = 1, 1024, 2, 64, 512
     q = jnp.zeros((b, s, h, d), jnp.float32)
@@ -390,9 +390,12 @@ def test_flash_causal_flops_use_kernel_cost_estimate():
         return flash_attention(q, q, q, True, None, blk, blk)
 
     got = jaxpr_matmul_flops(fwd, q)
-    live = _live_pairs(s // blk, s // blk, blk, blk, True)  # 3 of 4 blocks
-    assert live == 3
-    expect = b * h * live * 4 * blk * blk * d
+    census = tile_census(s, s, blk, blk, True)
+    assert census[:3] == (1, 2, 1)  # 3 of 4 blocks; the 2 on the diagonal
+    # are taken whole by the forward kernel (its walk's rows are the block)
+    scores = 3 * blk * blk
+    assert census.scores(blk) == scores
+    expect = b * h * scores * 4 * d
     np.testing.assert_allclose(got, expect, rtol=1e-6)
     # and the non-causal kernel counts the full rectangle
     got_full = jaxpr_matmul_flops(
