@@ -271,6 +271,14 @@ class GPT2LMHead(VocabPaddingMixin, nn.Module):
                              self.hidden_dim // self.num_heads,
                              dtype=self.dtype, quantized=quantized)
 
+    def paged_read_supports(self, page_size: int) -> bool:
+        """Whether the decode step's kernel read (`ops.paged_attention`) can
+        take this model's pool: pages of whole tiles."""
+        from ..ops.paged_attention import paged_attention_supports
+
+        return paged_attention_supports(page_size, self.hidden_dim,
+                                        self.dtype)
+
     @staticmethod
     def partition_rules() -> PartitionRules:
         return tp_fsdp_rules()

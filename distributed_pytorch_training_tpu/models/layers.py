@@ -194,6 +194,73 @@ class PagedKV:
     def quantized(self) -> bool:
         return self.k_scale is not None
 
+    # what a token's cache row is made of, for the functions below that
+    # work over any pool: its leaves as (store, scales or None) pairs, how a
+    # leaf's gathered pages become the dense view a model reads, and how the
+    # rows a model hands back become what a leaf stores
+    def leaves(self):
+        return ((self.k, self.k_scale), (self.v, self.v_scale))
+
+    def view_of(self, leaf: int, pages: jnp.ndarray) -> jnp.ndarray:
+        """(L, rows, T, H*D) -> (L, rows, T, H, D)."""
+        return pages.reshape(pages.shape[:-1] + (
+            self.num_heads, pages.shape[-1] // self.num_heads))
+
+    def rows_of(self, fresh):
+        return fresh
+
+    def with_leaves(self, stores) -> "PagedKV":
+        (k, k_scale), (v, v_scale) = stores
+        return self.replace(k=k, v=v, k_scale=k_scale, v_scale=v_scale)
+
+
+@flax.struct.dataclass
+class PagedLatent:
+    """A paged pool whose row is ONE vector a token a layer, shared by every
+    head: latent attention's compressed key-value ``c`` (``rank`` numbers)
+    and the one rotary key ``k_pe`` beside it (models/deepseek_v2.py: 512 +
+    64). Two leaves, both lane-dense in whole 128-lane tiles, which is what
+    lets `ops.mla_paged_attention` copy a page as it lies (Mosaic refuses a
+    576- or a 64-lane slice of an HBM array): ``c`` is (L, n_pages,
+    page_size, rank); ``pe`` holds the rotary keys of layers 2i and 2i + 1
+    side by side, (ceil(L / 2), n_pages, page_size, 2 * rope) — every write
+    lands in all layers at the same (page, offset), so a pair's row is
+    written whole. At rest a token costs ``rank + rope`` numbers a layer
+    (an odd ``depth`` pads one layer's ``rope``). Page 0 is the scratch page
+    as in `PagedKV`. There is no int8 form."""
+
+    c: jnp.ndarray
+    pe: jnp.ndarray
+    depth: int = flax.struct.field(pytree_node=False, default=1)
+    quantized = False
+
+    def leaves(self):
+        return ((self.c, None), (self.pe, None))
+
+    def view_of(self, leaf: int, pages: jnp.ndarray) -> jnp.ndarray:
+        """``c`` as it is; ``pe`` (L/2, rows, T, 2 * rope) -> (L, rows, T,
+        rope), a pair's two layers taken apart."""
+        if leaf == 0:
+            return pages
+        pairs, rows, t, width = pages.shape
+        apart = pages.reshape(pairs, rows, t, 2, width // 2)
+        return jnp.moveaxis(apart, 3, 1).reshape(
+            2 * pairs, rows, t, width // 2)[:self.depth]
+
+    def rows_of(self, fresh):
+        """(c (L, *idx, rank), k_pe (L, *idx, rope)) -> what the two leaves
+        store: ``c``, and the rotary keys of each pair of layers side by
+        side, (ceil(L / 2), *idx, 2 * rope)."""
+        c, pe = fresh
+        if self.depth % 2:
+            pe = jnp.concatenate([pe, jnp.zeros_like(pe[:1])])
+        paired = pe.reshape((pe.shape[0] // 2, 2) + pe.shape[1:])
+        paired = jnp.moveaxis(paired, 1, -2)
+        return c, paired.reshape(paired.shape[:-2] + (-1,))
+
+    def with_leaves(self, stores) -> "PagedLatent":
+        return self.replace(c=stores[0][0], pe=stores[1][0])
+
 
 @flax.struct.dataclass
 class PagedRead:
@@ -204,7 +271,7 @@ class PagedRead:
     row (the fresh token's own k/v never come from the pool). ``layer`` is
     the block the read is for; the model sets it per block."""
 
-    pool: PagedKV
+    pool: Any            # PagedKV or PagedLatent
     page_table: jnp.ndarray
     live: jnp.ndarray
     layer: int = flax.struct.field(pytree_node=False, default=0)
@@ -225,6 +292,15 @@ def init_paged_kv(depth: int, n_pages: int, page_size: int, num_heads: int,
     # pool, and XLA rejects donating one buffer twice
     return PagedKV(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    num_heads=num_heads)
+
+
+def init_paged_latent(depth: int, n_pages: int, page_size: int, rank: int,
+                      rope: int, dtype: Dtype = jnp.float32) -> PagedLatent:
+    """Zero-filled latent pool for ALL ``depth`` layers (stacked axis 0)."""
+    return PagedLatent(
+        c=jnp.zeros((depth, n_pages, page_size, rank), dtype),
+        pe=jnp.zeros((-(-depth // 2), n_pages, page_size, 2 * rope), dtype),
+        depth=depth)
 
 
 def _dequant_pages(codes: jnp.ndarray, scales: jnp.ndarray,
@@ -251,139 +327,139 @@ def _quant_rows(x: jnp.ndarray, fused: Optional[bool] = None
 
 
 @jax.named_scope("kv_gather")
-def gather_paged_kv(pkv: PagedKV, page_table: jnp.ndarray,
-                    dtype: Dtype = jnp.float32
-                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def gather_paged_kv(pkv, page_table: jnp.ndarray,
+                    dtype: Dtype = jnp.float32) -> Tuple[jnp.ndarray, ...]:
     """Per-slot dense view of the whole pool, the REFERENCE read:
-    ``page_table`` (rows, P) int32 -> (L, rows, P * page_size, H, D) k and
-    v in ``dtype`` (dequantized when the pool is int8) — one gather
-    covering every layer. Per-layer slices of the result feed the
-    bitwise-pinned `decode_dot_product_attention` unchanged; positions
-    beyond a slot's write frontier carry scratch/stale (finite) values the
-    caller's mask zeroes exactly."""
+    ``page_table`` (rows, P) int32 -> one (L, rows, P * page_size, *tail)
+    view a leaf of the pool in ``dtype`` (dequantized when the pool is
+    int8): k and v as (.., H, D) of a `PagedKV`, c and k_pe as (.., rank)
+    and (.., rope) of a `PagedLatent` — one gather a leaf covering every
+    layer. Per-layer slices of the result feed the bitwise-pinned
+    `decode_dot_product_attention` unchanged; positions beyond a slot's
+    write frontier carry scratch/stale (finite) values the caller's mask
+    zeroes exactly."""
     rows, pages = page_table.shape
-    depth, _, ps, width = pkv.k.shape
-    heads = pkv.num_heads
-    view = (depth, rows, pages * ps, heads, width // heads)
 
-    def dense(codes, scales):
-        g = codes[:, page_table].reshape(view)   # from (L, rows, P, ps, H*D)
+    def dense(leaf, codes, scales):
+        ps = codes.shape[2]
+        g = codes[:, page_table]                 # (L, rows, P, ps, W)
+        g = pkv.view_of(leaf, g.reshape(
+            g.shape[0], rows, pages * ps, g.shape[-1]))
         if scales is not None:
-            s = scales[:, page_table].reshape(view[:-1])
+            s = scales[:, page_table].reshape(g.shape[:-1])
             return _dequant_pages(g, s, dtype)
         return g.astype(dtype)
 
-    return dense(pkv.k, pkv.k_scale), dense(pkv.v, pkv.v_scale)
+    return tuple(dense(i, codes, scales)
+                 for i, (codes, scales) in enumerate(pkv.leaves()))
 
 
-def _put_rows(pkv: PagedKV, page: jnp.ndarray, off: jnp.ndarray,
-              k_new: jnp.ndarray, v_new: jnp.ndarray,
-              fused: Optional[bool]) -> PagedKV:
-    """The one write all three scatters share: ``k_new`` / ``v_new``
-    (L, *idx, H, D) land at (page, off), both of shape ``idx``, in every
-    layer, as ONE row scatter over the pool's flattened
-    (L * n_pages * page_size, H*D) view — row ``(l * n_pages + page) *
-    page_size + off``. A write to drop arrives as ``page == n_pages`` and
-    leaves through the far end of the view (``mode="drop"``), so it can
-    never land in the next layer's page 0. The reshape is a bitcast and the
-    scatter in place: indexed as ``store.at[:, page, off]`` XLA copied the
-    whole pool into another layout and back (PERF.md, PR 25)."""
-    depth, n_pages, ps, _ = pkv.k.shape
-    flat = depth * n_pages * ps
-    row = (jnp.arange(depth).reshape((depth,) + (1,) * page.ndim)
-           * n_pages + page[None]) * ps + off[None]
-    row = jnp.where(page[None] < n_pages, row, flat).reshape(-1)
+def _put_rows(pkv, page: jnp.ndarray, off: jnp.ndarray, fresh,
+              fused: Optional[bool]):
+    """The one write all three scatters share: ``fresh``, one (L, *idx,
+    *tail) array a leaf of the pool (k and v of a `PagedKV`), lands at
+    (page, off), both of shape ``idx``, in every layer, as ONE row scatter a
+    leaf over the pool's flattened (L * n_pages * page_size, width) view —
+    row ``(l * n_pages + page) * page_size + off``. A write to drop arrives
+    as ``page == n_pages`` and leaves through the far end of the view
+    (``mode="drop"``), so it can never land in the next layer's page 0. The
+    reshape is a bitcast and the scatter in place: indexed as
+    ``store.at[:, page, off]`` XLA copied the whole pool into another
+    layout and back (PERF.md, PR 25)."""
+    leaves = pkv.leaves()
+    if len(fresh) != len(leaves):
+        raise ValueError(f"a row of this pool has {len(leaves)} leaves, "
+                         f"got {len(fresh)}")
+    fresh = pkv.rows_of(tuple(fresh))
 
-    def put(store, fresh):
-        width = store.shape[-1]
+    def put(store, new):
+        depth, n_pages, ps, width = store.shape
+        flat = depth * n_pages * ps
+        row = (jnp.arange(depth).reshape((depth,) + (1,) * page.ndim)
+               * n_pages + page[None]) * ps + off[None]
+        row = jnp.where(page[None] < n_pages, row, flat).reshape(-1)
         return store.reshape(flat, width).at[row].set(
-            fresh.reshape(-1, width).astype(store.dtype),
+            new.reshape(-1, width).astype(store.dtype),
             mode="drop").reshape(store.shape)
 
     if not pkv.quantized:
-        return pkv.replace(k=put(pkv.k, k_new), v=put(pkv.v, v_new))
-    kq, ks = _quant_rows(k_new, fused=fused)
-    vq, vs = _quant_rows(v_new, fused=fused)
-    return pkv.replace(k=put(pkv.k, kq), v=put(pkv.v, vq),
-                       k_scale=put(pkv.k_scale, ks),
-                       v_scale=put(pkv.v_scale, vs))
+        return pkv.with_leaves([(put(store, new), None)
+                                for (store, _), new in zip(leaves, fresh)])
+    coded = [_quant_rows(new, fused=fused) for new in fresh]
+    return pkv.with_leaves([(put(store, q), put(scales, s)) for
+                            (store, scales), (q, s) in zip(leaves, coded)])
 
 
 @jax.named_scope("kv_scatter")
-def scatter_paged_rows(pkv: PagedKV, page_table: jnp.ndarray,
-                       positions: jnp.ndarray, k_rows: jnp.ndarray,
-                       v_rows: jnp.ndarray, active: jnp.ndarray,
-                       fused: Optional[bool] = None) -> PagedKV:
-    """Write ONE fresh (H, D) k/v row per slot per layer — ``k_rows`` /
-    ``v_rows`` are (L, rows, H, D), or (L, rows, H*D) as the kernel read
-    returns them — at that slot's own position: the paged decode step's
-    write half, ONE scatter covering every layer.
-    ``positions`` (rows,) int32, ``active`` (rows,) bool: inactive rows are
-    dropped by pointing their write at an out-of-range page, so
-    finished/free slots never touch the pool (the token-granular
+def scatter_paged_rows(pkv, page_table: jnp.ndarray, positions: jnp.ndarray,
+                       *rows_then_active, fused: Optional[bool] = None):
+    """Write ONE fresh row per slot per layer at that slot's own position:
+    the paged decode step's write half, ONE scatter a leaf covering every
+    layer. The rows come one array a leaf of the pool, then ``active``:
+    ``(pool, table, positions, k_rows, v_rows, active)`` for a `PagedKV`
+    — (L, rows, H, D) each, or (L, rows, H*D) as the kernel read returns
+    them — and ``(pool, table, positions, c_rows, pe_rows, active)`` for a
+    `PagedLatent`. ``positions`` (rows,) int32, ``active`` (rows,) bool:
+    inactive rows are dropped by pointing their write at an out-of-range
+    page, so finished/free slots never touch the pool (the token-granular
     join/leave substrate). ``fused`` is the int8 codec's PR 6 tri-state
     (`_quant_rows`)."""
-    n_pages, ps = pkv.k.shape[1], pkv.k.shape[2]
+    *fresh, active = rows_then_active
+    _, n_pages, ps, _ = pkv.leaves()[0][0].shape
     rows = positions.shape[0]
     page = page_table[jnp.arange(rows), positions // ps]
     page = jnp.where(active, page, n_pages)         # drop inactive writes
-    return _put_rows(pkv, page, positions % ps, k_rows, v_rows, fused)
+    return _put_rows(pkv, page, positions % ps, fresh, fused)
 
 
 @jax.named_scope("kv_scatter")
-def scatter_paged_window(pkv: PagedKV, page_table: jnp.ndarray,
-                         positions: jnp.ndarray, k_rows: jnp.ndarray,
-                         v_rows: jnp.ndarray, active: jnp.ndarray,
-                         fused: Optional[bool] = None) -> PagedKV:
+def scatter_paged_window(pkv, page_table: jnp.ndarray,
+                         positions: jnp.ndarray, *rows_then_active,
+                         fused: Optional[bool] = None):
     """`scatter_paged_rows` generalized to an S-position window per slot:
-    ``positions`` / ``active`` are (rows, S) and ``k_rows`` / ``v_rows``
-    (L, rows, S, H, D) — the speculative VERIFY step's write half (target
+    ``positions`` / ``active`` are (rows, S) and the rows (L, rows, S,
+    *tail) a leaf — the speculative VERIFY step's write half (target
     k/v for the whole K+1 window) and the draft engine's propose-round
     commit, still ONE scatter covering every layer. Inactive (row, offset)
     pairs — dead slots, positions past the slot's page span — are dropped
     exactly like the one-row form; the caller masks out-of-range window
     positions BEFORE the page lookup here clips them, so a clipped index
     can never alias a live page."""
-    n_pages, ps = pkv.k.shape[1], pkv.k.shape[2]
+    *fresh, active = rows_then_active
+    _, n_pages, ps, _ = pkv.leaves()[0][0].shape
     rows = positions.shape[0]
     page = page_table[jnp.arange(rows)[:, None], positions // ps]  # (rows, S)
     page = jnp.where(active, page, n_pages)         # drop inactive writes
-    return _put_rows(pkv, page, positions % ps, k_rows, v_rows, fused)
+    return _put_rows(pkv, page, positions % ps, fresh, fused)
 
 
 @jax.named_scope("kv_scatter")
-def scatter_paged_prefill(pkv: PagedKV, page_row: jnp.ndarray,
-                          k_seqs: jnp.ndarray, v_seqs: jnp.ndarray,
-                          length: jnp.ndarray,
-                          fused: Optional[bool] = None) -> PagedKV:
-    """Write one slot's prompt k/v — ``k_seqs`` / ``v_seqs`` (L, S, H, D),
-    every layer at once — into its pages, positions [0, length) only: the
-    paged prefill's write half. ``page_row`` (P,) is the slot's page-table
-    row; positions past ``length`` (bucket padding) are dropped, so a
-    shared prefix page is only ever rewritten with its own bytes
-    (identical params + identical tokens -> identical k/v, bitwise — the
-    prefix-sharing safety argument)."""
-    n_pages, ps = pkv.k.shape[1], pkv.k.shape[2]
-    idx = jnp.arange(k_seqs.shape[1])
+def scatter_paged_prefill(pkv, page_row: jnp.ndarray, *seqs_then_length,
+                          fused: Optional[bool] = None):
+    """Write one slot's prompt rows — (L, S, *tail) a leaf of the pool,
+    every layer at once, then ``length``: ``(pool, page_row, k_seqs,
+    v_seqs, length)`` for a `PagedKV` — into its pages, positions [0,
+    length) only: the paged prefill's write half. ``page_row`` (P,) is the
+    slot's page-table row; positions past ``length`` (bucket padding) are
+    dropped, so a shared prefix page is only ever rewritten with its own
+    bytes (identical params + identical tokens -> identical rows, bitwise —
+    the prefix-sharing safety argument)."""
+    *fresh, length = seqs_then_length
+    _, n_pages, ps, _ = pkv.leaves()[0][0].shape
+    idx = jnp.arange(fresh[0].shape[1])
     page = jnp.where(idx < length, page_row[idx // ps], n_pages)
-    return _put_rows(pkv, page, idx % ps, k_seqs, v_seqs, fused)
+    return _put_rows(pkv, page, idx % ps, fresh, fused)
 
 
 def paged_kv_bytes(pool) -> int:
     """At-rest bytes of a paged pool (every block's codes + scales for
     int8 pools, raw elements otherwise) — the serving analogue of
-    grad_sync's wire accounting, compared against `dense_kv_bytes`."""
+    grad_sync's wire accounting, compared against the dense engine's
+    cache (`SlotEngine.dense_baseline_bytes`)."""
     import jax
 
     return int(sum(arr.size * arr.dtype.itemsize
                    for arr in jax.tree_util.tree_leaves(pool)))
-
-
-def dense_kv_bytes(rows: int, cache_len: int, num_heads: int, head_dim: int,
-                   depth: int, itemsize: int = 4) -> int:
-    """The dense engine's at-rest KV bytes at the same config — the
-    baseline the >= 3x int8-paged HBM cut is measured against."""
-    return 2 * depth * rows * cache_len * num_heads * head_dim * itemsize
 
 
 class MultiHeadAttention(nn.Module):

@@ -192,7 +192,8 @@ class MoeMlp(nn.Module):
 class HeldExpertsMoe(nn.Module):
     """One chip's share of a dropless top-k expert layer (the model-configs
     guide's section 4): the router is ``num_experts`` wide and keeps its
-    ``top_k``, this module holds experts ``first_expert ..
+    ``top_k`` (of all experts, or of the experts of its ``topk_group`` best
+    groups), this module holds experts ``first_expert ..
     first_expert + num_experts_held - 1`` and returns the part of the layer's
     result that THOSE experts give. What the absent experts would add is left
     out: there is no exchange here and nothing stands in for one.
@@ -222,18 +223,36 @@ class HeldExpertsMoe(nn.Module):
     first_expert: int = 0
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
+    # the routing rule beside top-k of the softmax, renormalised: with
+    # ``n_group`` > 1 the experts lie in that many groups of consecutive
+    # ids, a group's score is its largest probability, only the
+    # ``topk_group`` best groups' experts can be chosen (group-limited
+    # greedy selection); ``norm_topk_prob`` false keeps the chosen
+    # probabilities as they are; all are multiplied by the scaling factor
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the router's initial scale (the experts' is 0.02 always)
+    router_init_std: float = 0.02
 
     @nn.compact
     def __call__(self, x):
         b, s, d = x.shape
         t, k, held = b * s, self.top_k, self.num_experts_held
+        if self.num_experts % self.n_group \
+                or not 0 < self.topk_group <= self.n_group:
+            raise ValueError(
+                f"{self.num_experts} experts in {self.n_group} groups of "
+                f"which {self.topk_group} stay")
         if not 0 < held <= self.num_experts - self.first_expert:
             raise ValueError(
                 f"experts {self.first_expert}..{self.first_expert + held - 1}"
                 f" are not among {self.num_experts}")
         init = nn.initializers.normal(stddev=0.02)
-        router = self.param("router", init, (d, self.num_experts),
-                            self.param_dtype)
+        router = self.param(
+            "router", nn.initializers.normal(stddev=self.router_init_std),
+            (d, self.num_experts), self.param_dtype)
         w_gate = self.param("gate", init, (held, d, self.expert_dim),
                             self.param_dtype)
         w_up = self.param("up", init, (held, d, self.expert_dim),
@@ -248,8 +267,19 @@ class HeldExpertsMoe(nn.Module):
             probs = jax.nn.softmax(jnp.dot(
                 xf.astype(jnp.float32), router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST), axis=-1)
+            if self.n_group > 1:
+                # `top_k` puts the lower index first among equals, here and
+                # below: a tie goes to the lower group, the lower expert
+                in_groups = probs.reshape(t, self.n_group, -1)
+                _, best = jax.lax.top_k(in_groups.max(-1), self.topk_group)
+                stays = (best[:, :, None] == jnp.arange(self.n_group)).any(1)
+                probs = jnp.where(stays[:, :, None], in_groups,
+                                  0.0).reshape(t, self.num_experts)
             top_p, top_e = jax.lax.top_k(probs, k)
-            kept = top_p / top_p.sum(-1, keepdims=True)  # norm_topk_prob
+            kept = top_p / top_p.sum(-1, keepdims=True) \
+                if self.norm_topk_prob else top_p
+            if self.routed_scaling_factor != 1.0:
+                kept = kept * self.routed_scaling_factor
             # held experts -> 0..held-1, absent ones -> held..E-1
             local = ((top_e - self.first_expert) % self.num_experts
                      ).reshape(t * k)

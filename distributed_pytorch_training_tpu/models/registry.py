@@ -31,9 +31,12 @@ def list_models():
 
 def is_lm_model(name: str) -> bool:
     """The image-vs-token dispatch that the experiment drivers, the trainer
-    builders and the serving CLI all ask of a model's name."""
-    return name.startswith(("gpt2", "bert"))
+    builders and the serving CLI all ask of a model's name: answered by the
+    model it names (one that has a vocabulary), not by the name."""
+    return hasattr(get_model(name), "vocab_size")
 
 
-def lm_vocab(name: str) -> int:
-    return 30522 if name.startswith("bert") else 50257
+def lm_vocab(name: str, **overrides) -> int:
+    """The vocabulary a token model draws its ids from, as built with
+    ``overrides`` (a chip's share of a model holds a slice of it)."""
+    return int(get_model(name, **overrides).vocab_size)

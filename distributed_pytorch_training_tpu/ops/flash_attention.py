@@ -348,7 +348,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs,
         kvm_ref, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = None, refs
     qb, kb = pl.program_id(1), pl.program_id(2)
     nkb = pl.num_programs(2)
-    d = q_ref.shape[-1]
+    d = v_ref.shape[-1]           # the accumulator's width is the values'
 
     @pl.when(kb == 0)
     def _init():
@@ -421,7 +421,7 @@ def _flash_fwd_lse(q, k, v, causal: bool, sm_scale: float,
 def _fwd_call(q, k, v, kv_valid, *, causal: bool, sm_scale: float,
               block_q: int, block_k: int, interpret: bool):
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]
     masked = kv_valid is not None
     folds = _scale_folds(sm_scale)
     qf, kf, vf = _folded(q * sm_scale if folds else q), _folded(k), _folded(v)
@@ -436,7 +436,7 @@ def _fwd_call(q, k, v, kv_valid, *, causal: bool, sm_scale: float,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
         pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda bh, i, j: (bh, j, 0)),
     ]
     operands = [qf, kf, vf]
     if masked:
@@ -452,34 +452,34 @@ def _fwd_call(q, k, v, kv_valid, *, causal: bool, sm_scale: float,
                           masked=masked),
         name="flash_fwd",
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
         ],
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         cost_estimate=_cost(
-            # per computed score: QK^T + PV, 2 * 2 * d
-            flops=b * h * scores * 4 * d,
+            # per computed score: QK^T over d and PV over the values' width
+            flops=b * h * scores * 2 * (d + dv),
             # exp(s - m_new) per score + the finalize log per q row
             transcendentals=b * h * (scores + sq),
             bytes_accessed=(
                 b * h * grid[1] * grid[2] *
-                (block_q * d + 2 * block_k * d) * q.dtype.itemsize
-                + b * h * sq * (d * q.dtype.itemsize + 4))),
+                (block_q * d + block_k * (d + dv)) * q.dtype.itemsize
+                + b * h * sq * (dv * q.dtype.itemsize + 4))),
         interpret=interpret,
     )
     with jax.named_scope("flash_fwd"):
         out, lse = fwd(*operands)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse
+    return out.reshape(b, h, sq, dv).transpose(0, 2, 1, 3), lse
 
 
 # ---------------------------------------------------------------------------
@@ -728,6 +728,10 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Blockwise attention; numerically equivalent to softmax(QK^T*scale)V.
 
+    ``v`` may be (B, S, H, Dv) with ``Dv != D`` (latent attention: keys of
+    192, values of 128): the forward kernel's accumulator and output take
+    the values' width. Forward only: differentiating such a call raises.
+
     `block_q` / `block_k` left ``None`` are chosen from the shapes
     (`_blocks`); an explicit size is honoured (fitted to the axis).
 
@@ -743,6 +747,11 @@ def flash_attention(
 
 
 def _vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_valid=None):
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            "flash_attention: values of another width than the keys "
+            f"({v.shape[-1]} against {q.shape[-1]}) have the forward kernel "
+            "only; the backward kernels fold q, k and v to one width")
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(q.shape[-1])
     out, lse = _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k,
                               kv_valid)

@@ -32,7 +32,7 @@ from .engine import (
     InferenceEngine, QuantizedLeaf, ServeConfig, dequantize_params,
     int8_weight_bytes, quantize_params,
 )
-from ..models.layers import dense_kv_bytes, paged_kv_bytes
+from ..models.layers import paged_kv_bytes
 from .paged import PagedServeConfig, PagePool
 from .router import (
     HttpReplica, InProcessReplica, ReplicaDead, Router, RouterRequest,
@@ -42,7 +42,7 @@ __all__ = [
     "ContinuousScheduler", "HttpReplica", "InProcessReplica",
     "InferenceEngine", "PagePool", "PagedServeConfig", "QuantizedLeaf",
     "ReplicaDead", "Request", "RequestQueue", "Result", "Router",
-    "RouterRequest", "ServeConfig", "SlotEngine", "dense_kv_bytes",
+    "RouterRequest", "ServeConfig", "SlotEngine",
     "dequantize_params", "drain", "int8_weight_bytes", "paged_kv_bytes",
     "quantize_params", "sample_tokens", "serve_continuous",
     "serve_forever",

@@ -253,7 +253,6 @@ def _run(args, buckets) -> int:
     import jax
 
     from .. import telemetry
-    from ..models.registry import is_lm_model, lm_vocab
     from ..training import TrainConfig
     from ..utils.config import parse_model_overrides
     from ..utils.logging import log_main
@@ -384,7 +383,9 @@ def _run(args, buckets) -> int:
                               np.int32)]
     else:
         rng = np.random.RandomState(args.seed)
-        vocab = lm_vocab(args.model) if is_lm_model(args.model) else 256
+        # ids from the SERVED model's vocabulary (a chip's share of a model
+        # holds a slice of it)
+        vocab = int(engine.model.vocab_size)
         prompts = [rng.randint(0, vocab, n).astype(np.int32)
                    for n in (args.prompt_len, max(args.prompt_len // 2, 1),
                              min(args.prompt_len * 2, max(buckets)))]

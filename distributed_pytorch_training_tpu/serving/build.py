@@ -97,7 +97,17 @@ def build_serving_engine(devices: Sequence[jax.Device], model_name: str,
             ckpt_dir, model, mesh, cfg, tx, sample,
             train_config=train_config, rules=serve_rules)
     else:
-        variables = model.init(jax.random.PRNGKey(seed), sample, train=False)
+        # one jitted call that returns the weights alone: the forward pass
+        # `init` traces is then dead code, and every leaf is made on the
+        # devices in its own dtype (a model of billions of parameters has
+        # no float32 copy and no eager forward to wait for)
+        from ..parallel.sharding import replicated
+
+        variables = jax.jit(
+            lambda key: {k: v for k, v in model.init(
+                key, sample, train=False).items()
+                if k in ("params", "batch_stats")},
+            out_shardings=replicated(mesh))(jax.random.PRNGKey(seed))
         engine = cls(model, mesh, cfg, variables["params"],
                      batch_stats=variables.get("batch_stats"),
                      rules=serve_rules)
@@ -108,7 +118,8 @@ def build_slot_engine(devices: Sequence[jax.Device], model_name: str,
                       buckets: Sequence[int] = (8, 16), rows: int = 8,
                       max_new_tokens: int = 8, kv_dtype: str = "fp32",
                       page_size: int = 8, prefix_sharing: bool = True,
-                      n_pages: int = 0, prefix_skip: bool = True, **kw):
+                      n_pages: int = 0, prefix_skip: bool = True,
+                      serve_dtype: str = "fp32", **kw):
     """(SlotEngine, mesh) — the token-granular sibling of
     `build_serving_engine` (same checkpoint templates, mesh validation and
     sizing; ``**kw`` forwards model_overrides/ckpt_dir/train_config/...).
@@ -121,8 +132,9 @@ def build_slot_engine(devices: Sequence[jax.Device], model_name: str,
 
     cfg = PagedServeConfig(
         buckets=tuple(buckets), rows=rows, max_new_tokens=max_new_tokens,
-        page_size=page_size, kv_dtype=kv_dtype, n_pages=n_pages,
-        prefix_sharing=prefix_sharing, prefix_skip=prefix_skip)
+        serve_dtype=serve_dtype, page_size=page_size, kv_dtype=kv_dtype,
+        n_pages=n_pages, prefix_sharing=prefix_sharing,
+        prefix_skip=prefix_skip)
     return build_serving_engine(
         devices, model_name, buckets=buckets, rows=rows,
         max_new_tokens=max_new_tokens, config=cfg, engine_cls=SlotEngine,

@@ -63,24 +63,24 @@ import numpy as np
 from .. import telemetry
 from ..data.pack import bucket_for
 from ..models.layers import (
+    PagedKV,
     PagedRead,
-    dense_kv_bytes,
     gather_paged_kv,
     paged_kv_bytes,
     scatter_paged_prefill,
     scatter_paged_rows,
     scatter_paged_window,
 )
-from ..ops.paged_attention import (
-    paged_attention_backend_supported,
-    paged_attention_supports,
-)
+from ..ops.paged_attention import paged_attention_backend_supported
 from ..parallel.mesh import batch_shard_count
 from ..parallel.sharding import batch_sharding, replicated
 from ..utils.locktrace import named_lock
 from .batching import Request, RequestQueue, Result
 from .engine import InferenceEngine
 from .paged import PagedServeConfig, PageLease, PagePool
+
+
+_COUNTERS = "model_counters"   # the control block's entry for them
 
 
 @jax.named_scope("sample")
@@ -223,7 +223,23 @@ class SlotEngine(InferenceEngine):
             # parity pin), within rounding in bf16 on a TPU, either read
             # (PARITY.md has the chip's measurement))
             "last_pos": jnp.full((rows,), -1, jnp.int32),
+            # what the model's layers count a decode step (``step_counters``
+            # of a model that routes: `models.moe.HeldExpertsMoe`), summed
+            # over the decode steps since the last reset, and those steps'
+            # count last: kept on the device and fetched apart
+            # (`fetch_step_counters`), never inside the loop
+            **({_COUNTERS: jnp.zeros((len(self._counter_names) + 1,),
+                                     jnp.float32)}
+               if self._counter_names else {}),
         }
+
+    @property
+    def _counter_names(self) -> Tuple[str, ...]:
+        return tuple(getattr(self.model, "step_counters", ()))
+
+    def _control_sharding(self, name: str, ndim: int):
+        """Slot state shards by rows; the model's counters are no slot's."""
+        return self._rep if name == _COUNTERS else self._row_sharding(ndim)
 
     def reset_state(self) -> None:
         """(Re)build the device state: zeroed paged pool (page 0 scratch —
@@ -236,7 +252,7 @@ class SlotEngine(InferenceEngine):
             quantized=cfg.kv_dtype == "int8")
         self._pool = jax.device_put(pool, self._rep)
         self._control = {
-            k: jax.device_put(v, self._row_sharding(v.ndim))
+            k: jax.device_put(v, self._control_sharding(k, v.ndim))
             for k, v in self._init_control().items()}
         self._page_table = np.zeros(
             (cfg.rows, cfg.pages_per_slot), np.int32)
@@ -265,7 +281,9 @@ class SlotEngine(InferenceEngine):
             lambda x: self._rep_aval(x.shape, x.dtype), self._pool)
 
     def _control_avals(self):
-        return {k: self._row_aval(v.shape, v.dtype)
+        return {k: jax.ShapeDtypeStruct(
+                    v.shape, v.dtype,
+                    sharding=self._control_sharding(k, v.ndim))
                 for k, v in self._control.items()}
 
     def _make_paged_prefill(self, bucket: int) -> Callable:
@@ -284,12 +302,12 @@ class SlotEngine(InferenceEngine):
             t0 = sample_tokens(last[None, :], k0[None, :], temp[None],
                                top_p[None])[0]
             page_row = page_table[slot]
-            # stack the per-block prompt k/v to (L, S, H, D): the pool is
-            # layer-stacked, so the whole prompt lands in ONE scatter
-            k_seqs = jnp.stack([c[0][0] for c in cache])
-            v_seqs = jnp.stack([c[1][0] for c in cache])
-            new_pool = scatter_paged_prefill(pool, page_row, k_seqs,
-                                             v_seqs, length,
+            # stack each leaf of the per-block prompt rows (k and v of a
+            # K/V cache) to (L, S, ...): the pool is layer-stacked, so the
+            # whole prompt lands in ONE scatter a leaf
+            seqs = [jnp.stack([leaf[0] for leaf in leaves])
+                    for leaves in zip(*cache)]
+            new_pool = scatter_paged_prefill(pool, page_row, *seqs, length,
                                              fused=self._fused_quantize)
             out_row = jnp.zeros((cfg.max_new_tokens,), jnp.int32)
             out_row = out_row.at[0].set(t0)
@@ -324,8 +342,7 @@ class SlotEngine(InferenceEngine):
         cfg: PagedServeConfig = self.config
         kernel = (cfg.kv_dtype != "int8" and self.mesh.size == 1
                   and paged_attention_backend_supported()
-                  and paged_attention_supports(
-                      cfg.page_size, self.model.hidden_dim, self.model.dtype))
+                  and self.model.paged_read_supports(cfg.page_size))
         return "kernel" if kernel else "gather"
 
     def _make_paged_decode(self) -> Callable:
@@ -344,6 +361,9 @@ class SlotEngine(InferenceEngine):
         rows = self.config.rows
         fused = self._fused_quantize
         kernel = self.kv_path == "kernel"
+        counted = self._counter_names
+        if counted:   # the fold of what the layers sowed, as a train step's
+            from ..training.tasks import step_counters
 
         def decode(served, pool, control, page_table):
             params = self._dequant(served)
@@ -362,28 +382,29 @@ class SlotEngine(InferenceEngine):
                 # dense view the bitwise-pinned decode attention consumes
                 # unchanged, per-layer slices of one gather
                 with jax.named_scope("kv_gather"):
-                    k_all, v_all = gather_paged_kv(pool, page_table,
-                                                   dtype=self.model.dtype)
-                    cache = tuple((k_all[l], v_all[l])
+                    views = gather_paged_kv(pool, page_table,
+                                            dtype=self.model.dtype)
+                    cache = tuple(tuple(view[l] for view in views)
                                   for l in range(self.model.depth))
             with jax.named_scope("model"):
-                logits, new_cache = self.model.apply(
+                out = self.model.apply(
                     self._apply_vars(params), tok[:, None], train=False,
-                    cache=cache, cache_positions=positions)
+                    cache=cache, cache_positions=positions,
+                    **({"mutable": ["counters"]} if counted else {}))
+                (logits, new_cache), sown = out if counted else (out, None)
             # write half: ONE fresh row per live slot per layer, stacked
-            # over the layers -> ONE in-place scatter back to the pool
+            # over the layers -> ONE in-place scatter a leaf back to the pool
             with jax.named_scope("kv_scatter"):
                 if not kernel:
                     # the views come back whole: each row's own position
-                    idx = positions[:, None, None, None]
                     new_cache = [
-                        (jnp.take_along_axis(k_new, idx, axis=1)[:, 0],
-                         jnp.take_along_axis(v_new, idx, axis=1)[:, 0])
-                        for k_new, v_new in new_cache]
+                        tuple(jnp.take_along_axis(
+                            view, positions.reshape(
+                                (-1,) + (1,) * (view.ndim - 1)), axis=1)[:, 0]
+                              for view in layer) for layer in new_cache]
                 new_pool = scatter_paged_rows(
                     pool, page_table, positions,
-                    jnp.stack([k_new for k_new, _ in new_cache]),
-                    jnp.stack([v_new for _, v_new in new_cache]),
+                    *(jnp.stack(leaves) for leaves in zip(*new_cache)),
                     active, fused=fused)
             # the token at position p+1, from THIS request's key stream
             step_keys = jax.vmap(jax.random.fold_in)(
@@ -413,6 +434,11 @@ class SlotEngine(InferenceEngine):
                     cap[:, None], logits[:, 0], control["last_buf"])
                 new_control["last_pos"] = jnp.where(
                     cap, -1, control["last_pos"])
+                if counted:
+                    got = step_counters(sown.get("counters", {}))
+                    new_control[_COUNTERS] = control[_COUNTERS] + jnp.stack(
+                        [got[name] for name in counted]
+                        + [jnp.ones((), jnp.float32)])
             return new_pool, new_control
 
         return decode
@@ -473,7 +499,21 @@ class SlotEngine(InferenceEngine):
         chip run, PR 25; PARITY.md)."""
         cfg: PagedServeConfig = self.config
         return (cfg.prefix_sharing and cfg.prefix_skip
-                and cfg.kv_dtype == "fp32")
+                and cfg.kv_dtype == "fp32" and self._windows_supported)
+
+    @property
+    def _windows_supported(self) -> bool:
+        """The skip and resume programs (and the speculative engine's
+        windows) read and write K/V views: a pool of another row format
+        (`layers.PagedLatent`) has the prefill and the S=1 decode step
+        only, and asking for one of the others raises (ROADMAP R5)."""
+        return isinstance(self._pool, PagedKV)
+
+    def _require_windows(self, program: str) -> None:
+        if not self._windows_supported:
+            raise ValueError(
+                f"{program} is K/V-only: this model's pool is a "
+                f"{type(self._pool).__name__} (ROADMAP R5)")
 
     def _make_paged_skip(self) -> Callable:
         cfg: PagedServeConfig = self.config
@@ -561,6 +601,7 @@ class SlotEngine(InferenceEngine):
     def lower_paged_skip(self):
         """The lowered control-only skip admission — every knob traced,
         control DONATED (no pool, no forward: the zero-dispatch path)."""
+        self._require_windows("paged_skip")
         ctrl_avals = self._control_avals()
         scalar_i = self._rep_aval((), jnp.int32)
         scalar_f = self._rep_aval((), jnp.float32)
@@ -573,6 +614,7 @@ class SlotEngine(InferenceEngine):
     def lower_paged_resume(self, bucket: int):
         """The lowered tail-only prefill (partial residency) — pool +
         control DONATED like the full prefill's."""
+        self._require_windows("paged_resume")
         cfg: PagedServeConfig = self.config
         pool_avals = self._pool_avals()
         ctrl_avals = self._control_avals()
@@ -600,7 +642,9 @@ class SlotEngine(InferenceEngine):
                 "paged_skip": self.lower_paged_skip,
                 "paged_resume": lambda: self.lower_paged_resume(bucket),
             }[kind]()
-            path = {"kv_path": self.kv_path} if kind == "paged_decode" else {}
+            path = {"kv_path": self.kv_path,
+                    "cache": type(self._pool).__name__} \
+                if kind == "paged_decode" else {}
             with telemetry.span("compile", program=kind, bucket=bucket,
                                 **path):
                 self._compiled[key] = lowered.compile()
@@ -705,13 +749,24 @@ class SlotEngine(InferenceEngine):
         return paged_kv_bytes(self._pool)
 
     def dense_baseline_bytes(self) -> int:
-        """What the PR 9 dense engine would hold at this config, fp32."""
+        """What the PR 9 dense engine would hold at this config, fp32: the
+        model's own dense cache (`init_cache`) for every row at full
+        length."""
         cfg: PagedServeConfig = self.config
-        return dense_kv_bytes(
-            cfg.rows, max(cfg.buckets) + cfg.max_new_tokens,
-            self.model.num_heads,
-            self.model.hidden_dim // self.model.num_heads,
-            self.model.depth)
+        cache = jax.eval_shape(lambda: self.model.init_cache(
+            cfg.rows, max(cfg.buckets) + cfg.max_new_tokens))
+        return 4 * sum(int(leaf.size)
+                       for leaf in jax.tree_util.tree_leaves(cache))
+
+    def fetch_step_counters(self) -> Dict[str, float]:
+        """ONE host fetch of the model's step counters (`_init_control`):
+        each name's sum over the decode steps since the last reset, and
+        those steps' count under ``"steps"``. Empty for a model that counts
+        nothing. Never called from the decode loop."""
+        if not self._counter_names:
+            return {}
+        got = np.asarray(jax.device_get(self._control[_COUNTERS]))
+        return dict(zip((*self._counter_names, "steps"), map(float, got)))
 
 
 @dataclasses.dataclass
@@ -919,6 +974,12 @@ class ContinuousScheduler:
         # takes: every slot in `running` is live through the whole burst
         # (steps <= its `left`), so the host's own mirror decides it
         telemetry.counter("serving_decode_steps", steps)
+        # positions the steps' reads covered: a slot that has emitted e of
+        # its tokens stands at len(prompt) + e - 1 and reads that many
+        # cached rows, one more each step of the burst
+        telemetry.counter("serving_live_cache_tokens", sum(
+            steps * (len(st.req.tokens) + st.want - 1 - st.left - steps)
+            + steps * (steps - 1) // 2 for st in self.running.values()))
         if all(st.req.temperature <= 0.0 for st in self.running.values()):
             telemetry.counter("serving_decode_steps_all_greedy", steps)
 

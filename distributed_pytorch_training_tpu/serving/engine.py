@@ -352,12 +352,12 @@ class InferenceEngine:
             shape, dtype, sharding=batch_sharding(self.mesh, len(shape)))
 
     def _cache_avals(self, bucket: int):
-        cache_len = bucket + self.config.max_new_tokens
-        head_dim = self.model.hidden_dim // self.model.num_heads
-        z = self._aval(
-            (self.config.rows, cache_len, self.model.num_heads, head_dim),
-            self.model.dtype)
-        return tuple((z, z) for _ in range(self.model.depth))
+        """The model's own dense cache (`init_cache`: k and v a block for
+        GPT-2, a latent and a rotary key for latent attention) as avals."""
+        cache = jax.eval_shape(lambda: self.model.init_cache(
+            self.config.rows, bucket + self.config.max_new_tokens))
+        return jax.tree_util.tree_map(
+            lambda z: self._aval(z.shape, z.dtype), cache)
 
     def _out_batch_shardings(self, tree_like):
         """Pin every output's sharding to batch-over-rows so the prefill
@@ -446,17 +446,12 @@ class InferenceEngine:
         """At-rest bytes of this engine's dense KV cache at ``bucket``
         (default: the top rung — the engine's HBM ceiling). The baseline
         the paged engine's >= 3x int8 cut is measured against
-        (models/layers.dense_kv_bytes; bench serving records both)."""
-        from ..models.layers import dense_kv_bytes
-
+        (`SlotEngine.dense_baseline_bytes`; bench serving records both)."""
         if not self.is_lm:
             return 0
         b = max(self.config.buckets) if bucket is None else int(bucket)
-        return dense_kv_bytes(
-            self.config.rows, b + self.config.max_new_tokens,
-            self.model.num_heads, self.model.hidden_dim // self.model.num_heads,
-            self.model.depth,
-            itemsize=jnp.dtype(self.model.dtype).itemsize)
+        return sum(int(z.size) * z.dtype.itemsize
+                   for z in jax.tree_util.tree_leaves(self._cache_avals(b)))
 
     # -- serving ------------------------------------------------------------
 

@@ -22,7 +22,7 @@ host-side ALLOCATION decision:
   the page returns to general circulation. Allocation fails (request
   stays queued) only when free + evictable together cannot cover a
   request.
-* **Byte accounting**: ``paged_kv_bytes`` vs ``dense_kv_bytes``
+* **Byte accounting**: ``paged_kv_bytes`` vs the dense cache's bytes
   (models/layers.py) is the bench's HBM story — int8 pages store 1 byte
   per element + one fp32 scale per (page, position, head) row, a >= 3x
   cut against the dense fp32 cache at the same config.

@@ -97,6 +97,7 @@ class SpeculativeEngine(SlotEngine):
                 f"draft pages_per_slot * page_size = "
                 f"{self.draft_padded_len} exceeds the draft model's "
                 f"max_position {draft_model.max_position}")
+        self._require_windows("speculative decoding")
         if getattr(draft_model, "vocab_size", None) != getattr(
                 model, "vocab_size", None):
             raise ValueError(
