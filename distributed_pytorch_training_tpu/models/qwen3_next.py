@@ -20,9 +20,10 @@ The equations (``h`` the residual stream, no projection has a bias):
   count).
 * `GatedDeltaNet`: one projection to q, k (16 heads of 128), v, z (32 heads
   of 128), one to the per-head write strength and decay inputs; a depthwise
-  causal convolution of 4 and SiLU over [q, k, v]; q and k l2-normalised;
-  `ops.gated_delta_rule` under scope ``gdn_rule``; a gated RMSNorm per head
-  (``w`` starting at one, times ``silu(z)``); ``out_proj``.
+  causal convolution of 4 and SiLU over [q, k, v]; then, all inside
+  `ops.gated_delta_rule.gated_delta_mixer`: q and k l2-normalised, each key
+  head serving two value heads, the rule under scope ``gdn_rule``, a gated
+  RMSNorm per head (``w`` starting at one, times ``silu(z)``); ``out_proj``.
 * expert block: `models.moe.HeldExpertsMoe` plus `SharedExpert` behind a
   sigmoid gate; the block's output is their sum.
 
@@ -43,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.gated_delta_rule import gated_delta_rule
+from ..ops.gated_delta_rule import gated_delta_mixer
 from ..parallel.mesh import FSDP, MODEL
 from ..parallel.sharding import PartitionRules
 from .layers import (
@@ -177,26 +178,14 @@ class GatedDeltaNet(nn.Module):
         qkv = nn.silu(sum(
             padded[:, j:j + s] * conv_w[j].astype(self.dtype)
             for j in range(self.conv_kernel)))
-        q = qkv[..., :key_dim].reshape(b, s, hk, dk).astype(jnp.float32)
-        k = qkv[..., key_dim:2 * key_dim].reshape(b, s, hk, dk).astype(
-            jnp.float32)
-        v = qkv[..., 2 * key_dim:].reshape(b, s, hv, dv)
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
-        l2 = lambda t: t * jax.lax.rsqrt(  # noqa: E731
-            jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
-        q, k = l2(q) * dk ** -0.5, l2(k)
-        # value head j reads key head j // (hv / hk)
-        q, k = (jnp.repeat(t, hv // hk, axis=2) for t in (q, k))
-        with jax.named_scope("gdn_rule"):
-            o = gated_delta_rule(q, k, v, g, beta,
-                                 head_block=RULE_HEAD_BLOCK)
-        o = o * jax.lax.rsqrt(
-            jnp.mean(o * o, axis=-1, keepdims=True) + self.epsilon)
-        o = o * norm_w.astype(jnp.float32) * nn.silu(
-            z.reshape(b, s, hv, dv).astype(jnp.float32))
-        return dense(hidden, "out_proj")(
-            o.astype(self.dtype).reshape(b, s, value_dim))
+        # l2 norm of q and k, each key head for its hv / hk value heads, the
+        # rule (scope ``gdn_rule``), the gated norm: one call, which on a
+        # TPU's own program reads and writes these tables and no others
+        o = gated_delta_mixer(qkv, z, g, beta, norm_w, self.epsilon,
+                              key_heads=hk, head_block=RULE_HEAD_BLOCK)
+        return dense(hidden, "out_proj")(o)
 
 
 class SharedExpert(nn.Module):

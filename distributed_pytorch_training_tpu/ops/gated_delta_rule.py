@@ -6,6 +6,12 @@ intermediates in VMEM, the rule's own backward); everywhere else XLA
 operations, forward and (by XLA's own transpose) backward, which is also the
 tests' second oracle beside the recurrence. `gated_delta_rule` chooses by
 what it can observe of the backend, the shapes and the trace: no option.
+`gated_delta_mixer` is the entry a Gated DeltaNet layer calls: the rule
+between the tables the layer holds anyway (the convolution's output, the
+gate's columns, the norm's weight), so that on the kernel path q's and k's
+l2 norm, the key heads' repeat and the gated output norm happen in VMEM and
+their float32 tables never exist; its other form is those lines as XLA
+operations around `gated_delta_rule`.
 
 Per head, with a state ``S`` of shape (key, value) starting at zero::
 
@@ -40,8 +46,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .gdn_rule_kernels import (
-    gated_delta_rule_kernels, gdn_rule_backend_supported,
-    gdn_rule_one_device_trace, gdn_rule_supports,
+    L2_EPSILON, gated_delta_mixer_kernels, gated_delta_rule_kernels,
+    gdn_rule_backend_supported, gdn_rule_one_device_trace, gdn_rule_supports,
 )
 
 CHUNK = 64
@@ -109,6 +115,57 @@ def gated_delta_rule(q, k, v, g, beta, *, head_block: int):
     out = lax.map(jax.checkpoint(lambda xs: _chunked_rule(*xs)),
                   tuple(blocks(x) for x in (q, k, v, g, beta)))
     return jnp.moveaxis(out, 0, 2).reshape(*q.shape[:3], v.shape[-1])
+
+
+def gated_delta_mixer(qkv, z, g, beta, norm_w, epsilon, *, key_heads: int,
+                      head_block: int):
+    """The rule as a Gated DeltaNet mixer holds it, between the tables the
+    mixer has anyway. qkv: (B, S, 2 * key_heads * Dk + H * Dv), the
+    convolution's output, its columns q | k | v, with q and k at
+    ``key_heads`` heads; z: (B, S, H * Dv), the output gate's columns of the
+    in-projection; g, beta: (B, S, H) float32; norm_w: (Dv,), the gated
+    norm's weight, and ``epsilon`` its constant. Returns (B, S, H * Dv) in
+    qkv's dtype, what ``out_proj`` reads::
+
+        q, k = l2(q) * Dk ** -0.5, l2(k)     # l2(x) = x rsqrt(sum(x x) + 1e-6)
+        o = rule(q, k of value head j = those of key head j // (H / key_heads),
+                 v, g, beta)
+        out = o rsqrt(mean(o o) + epsilon) * norm_w * silu(z)
+
+    all of it in float32 and rounded once, at the end. Two forms, chosen as
+    `gated_delta_rule` chooses and by the same three gates, the shapes' gate
+    asked about the key heads too. The kernels read qkv and z as they stand
+    and do the first and the last line in VMEM, chunk by chunk, so that no
+    float32 table of q, k or o, and no repeated one, is ever in HBM; their
+    backward gives qkv's cotangent at the key heads' width in qkv's dtype.
+    Everywhere else the three lines above are XLA operations around
+    `gated_delta_rule`'s XLA form: the tests' oracle, and what a
+    multi-device GSPMD program runs. The rule itself lies under scope
+    ``gdn_rule`` in both."""
+    b, s, h = g.shape
+    dv = norm_w.shape[-1]
+    key_dim = (qkv.shape[-1] - h * dv) // 2       # q's columns, and k's
+    dk = key_dim // key_heads
+    if gdn_rule_backend_supported() \
+            and gdn_rule_supports(h, dk, dv, key_heads) \
+            and gdn_rule_one_device_trace():
+        with jax.named_scope("gdn_rule"):
+            return gated_delta_mixer_kernels(qkv, z, g, beta, norm_w,
+                                             epsilon, key_heads=key_heads)
+    q, k = (qkv[..., first:first + key_dim].reshape(
+        b, s, key_heads, dk).astype(jnp.float32) for first in (0, key_dim))
+    v = qkv[..., 2 * key_dim:].reshape(b, s, h, dv)
+    l2 = lambda t: t * lax.rsqrt(  # noqa: E731
+        jnp.sum(t * t, axis=-1, keepdims=True) + L2_EPSILON)
+    q, k = l2(q) * dk ** -0.5, l2(k)
+    # value head j reads key head j // (h / key_heads)
+    q, k = (jnp.repeat(t, h // key_heads, axis=2) for t in (q, k))
+    with jax.named_scope("gdn_rule"):
+        o = gated_delta_rule(q, k, v, g, beta, head_block=head_block)
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + epsilon)
+    o = o * norm_w.astype(jnp.float32) * jax.nn.silu(
+        z.reshape(b, s, h, dv).astype(jnp.float32))
+    return o.astype(qkv.dtype).reshape(b, s, h * dv)
 
 
 def _chunked_rule(q, k, v, g, beta):

@@ -1,17 +1,46 @@
 """The chunked gated delta rule as a pair of Pallas TPU kernels with the
 rule's own backward (`gdn_rule_fwd`, `gdn_rule_bwd`): what
-`ops/gated_delta_rule.py::gated_delta_rule` runs in a one-device program on
-a TPU.
+`ops/gated_delta_rule.py` runs in a one-device program on a TPU, for the
+model (`gated_delta_mixer`) and for a caller with the rule's own operands
+(`gated_delta_rule`).
 
 One grid step is one chunk of CHUNK positions of eight heads. The chunk axis
 is the innermost, sequential one; each head's (key, value) state lives in
 VMEM scratch across it, zeroed at chunk 0. Everything a chunk needs besides
 that state (the in-chunk decays, ``A``, the inverse of ``I + A``, the scores)
 is made in VMEM from the chunk's rows of q, k, v, g, beta: nothing per chunk
-goes to HBM but the output rows and the backward's two residuals. q, k, v
-are read as the mixer holds them, ``(B, S, H * D)`` (a reshape, no copy);
-only g and beta, a megabyte each, are regrouped to ``(B, H / heads, S,
-heads)`` so that a step's block of them is whole in its last dimension.
+goes to HBM but the output rows and the backward's two residuals.
+
+q, k, v are read as the mixer holds them (since PR 39 literally): the
+convolution's output, ``(B, S, q | k | v columns)`` in the model's dtype
+with q and k at the KEY heads' count, is handed over three times and each
+operand's block found in it by column (a step's eight value heads read the
+four key heads that serve them: 64 kB of bf16 a chunk for q, the same for
+k). The PROLOGUE does in VMEM what the mixer did in HBM: cast up, scale each
+head's rows to unit length (``x * rsqrt(sum(x x) + 1e-6)``), scale q by
+``Dk ** -0.5``, and hand a PACK of two value heads the one key head both
+read (value head j reads key head j // 2: the repeat is the kernel's own
+layout, no copy). The EPILOGUE applies the mixer's gated norm to a chunk's
+rows before they leave, ``o * rsqrt(mean(o o) + eps) * w * silu(z)`` with z a
+block of the in-projection's own columns, and writes what ``out_proj``
+reads, in the model's dtype. The backward undoes both in VMEM: the gated
+norm's backward from the output's cotangent (dz, and the weight's cotangent
+summed over chunks in an output block that stays while they pass), the sum
+over the value heads a key head served, the scaling's backward, and dq, dk
+written once, at the key heads' width, in the operand's dtype. No float32
+table of q, k or o, repeated or not, is in HBM on this path (at 8,192 x 32
+heads of 128 the mixer's own lines moved ~2.3 GB a layer for them). What the
+backward needs of o it makes again (``[k; q_in] S`` as the forward stacks
+it, and ``scores u``: one product more of twenty-one) rather than keep 134
+MB a layer; PERF.md, PR 39, has both measured. Only g and beta, a megabyte
+each, are regrouped outside, to ``(B, H / heads, S, heads)``, so that a
+step's block of them is whole in its last dimension.
+
+A caller with the rule's own operands (float32 or not, a head each:
+`gated_delta_rule_kernels`, which the benchmark's rule check reaches) runs
+the SAME two bodies: what the operands are is a static description
+(`_Form`), and where it says they are raw, prologue and epilogue are not
+traced. So the check holds the recurrence the model's step runs.
 
 The mathematics is `gated_delta_rule._chunked_rule`'s (its docstring has the
 derivation), in another order of summation::
@@ -48,7 +77,7 @@ token) and cancel catastrophically.
 
 The backward runs the chunks in reverse with the state's cotangent in VMEM
 scratch, recomputes a chunk's other intermediates, and gives gradients for
-all five inputs. Residuals besides the inputs: the state each chunk STARTED
+all its inputs. Residuals besides the inputs: the state each chunk STARTED
 from, ``(B, S / CHUNK, H, Dk, Dv)`` float32 (268 MB a layer at 8,192 x 32
 heads of 128 x 128), and each chunk's ``T``, packed, ``(B, S / CHUNK, H /
 PACK, CHUNK, PACK * CHUNK)`` (67 MB): a quarter of the states for ten of the
@@ -57,20 +86,27 @@ only (under the layer's remat its first pass is that one too); a forward
 that nothing differentiates (evaluation, the benchmark's rule check) is the
 same kernel without the two outputs and puts neither in HBM.
 
-Where it runs (`gated_delta_rule` asks the three gates below): on a TPU, for
-heads in whole PACKs of sizes in whole 128-lane tiles, in a program that is
-one device's. A multi-device GSPMD program takes the XLA form, as it did
-before there were kernels: GSPMD cannot partition a Mosaic kernel, and no
-mesh reaches the rule to `shard_map` it over.
+Where it runs (`gated_delta_mixer` and `gated_delta_rule` ask the three
+gates below): on a TPU, for heads in whole PACKs of sizes in whole 128-lane
+tiles (and, for the mixer's tables, two value heads a key head or one), in a
+program that is one device's. A multi-device GSPMD program takes the XLA
+form, as it did before there were kernels: GSPMD cannot partition a Mosaic
+kernel, and no mesh reaches the rule to `shard_map` it over.
 
-Precision: every product is float32 at HIGHEST, the state, the decays and
-``T`` are float32, inputs are cast up on the chip and never rounded down.
+Precision: every product is float32 at HIGHEST; the state, the decays,
+``T``, both norms and the gate are float32. Inputs are cast up on the chip.
+Nothing is rounded that the XLA lines around the rule do not round: q, k, v
+and z enter as the model's dtype they already are, the mixer's output is
+rounded to it once (where ``o.astype(dtype)`` stood), and a cotangent is
+rounded once to its operand's dtype, dq and dk after the l2 norm's backward
+as XLA's transpose of the cast does. Raw operands' output stays float32.
 """
 
 from __future__ import annotations
 
 import functools
 import itertools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +117,7 @@ from jax.experimental.pallas import tpu as pltpu
 CHUNK = 64              # the kernels' own: ten 64-wide products invert I + A
 HEADS_PER_STEP = 8      # at most: four packs in turn a step; 16 measured the same
 PACK = 128 // CHUNK     # heads whose (CHUNK, CHUNK) tables share 128 lanes
+L2_EPSILON = 1e-6       # the mixer's, under the root that scales q and k
 
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
@@ -96,13 +133,23 @@ def gdn_rule_backend_supported() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def gdn_rule_supports(heads: int, dk: int, dv: int) -> bool:
+def gdn_rule_supports(heads: int, dk: int, dv: int,
+                      key_heads: Optional[int] = None) -> bool:
     """The shapes the kernels are written for and were compiled for on the
     chip: heads in whole PACKs, and head sizes of whole 128-lane tiles (a
     step's block of q, k or v is then lane-aligned whatever the group). The
-    interpreter has no tiles and takes any head size."""
-    return heads % PACK == 0 and (
-        _interpret() or (dk % 128 == 0 and dv % 128 == 0))
+    interpreter has no tiles and takes any head size. With ``key_heads``,
+    the mixer's own tables besides: a key head serves a PACK's heads or one
+    (two value heads a key head as published, or one: a ratio the PACKs
+    cannot hold has no shared rows to hand a pack), and v's columns start at
+    a whole block of a step's value heads in the convolution's output."""
+    if heads % PACK or not (_interpret()
+                            or (dk % 128 == 0 and dv % 128 == 0)):
+        return False
+    if key_heads is None:
+        return True
+    return (heads % key_heads == 0 and PACK % (heads // key_heads) == 0
+            and 2 * key_heads * dk % (_heads_per_step(heads) * dv) == 0)
 
 
 def gdn_rule_one_device_trace() -> bool:
@@ -264,6 +311,20 @@ class _Pack:
             [beta * m for beta, m in zip(self.betas, self.missings)]), _NN),
             self.p)
 
+    def outputs(self, states):
+        """The chunk's ``u`` and then ``outs``, the rule's output rows a
+        head: [k; q_in] S is what the state holds of the keys and what it
+        answers the queries, ``scores u`` what the chunk itself answers."""
+        c = CHUNK
+        reads = [_dot(_stack([k, q_in]), state, _NN)
+                 for k, q_in, state in zip(self.ks, self.q_ins, states)]
+        yield
+        self.solve([read[:c] for read in reads])
+        yield
+        answers = _split(_dot(self.scores, _diagonal(self.us), _NN), self.p)
+        self.outs = [read[c:] + answer
+                     for read, answer in zip(reads, answers)]
+
 
 def _in_turn(steps):
     """Advances the generators one ``yield`` each, round after round."""
@@ -294,81 +355,200 @@ def _packs(heads: int):
     return [range(first, first + PACK) for first in range(0, heads, PACK)]
 
 
-def _pack(q_ref, k_ref, v_ref, g_ref, sums, beta_ref, members, dk, dv):
-    rows = lambda ref, d: [  # noqa: E731
-        ref[0, :, h * d:(h + 1) * d].astype(jnp.float32) for h in members]
+class _Form(NamedTuple):
+    """What a call's operands are, known when it is traced. With a gated
+    norm's ``epsilon`` they are the mixer's own tables: q and k at
+    ``key_heads`` heads in the convolution's dtype, to be scaled to unit
+    length (and q by ``dk ** -0.5``) before the rule, each key head serving
+    ``heads / key_heads`` value heads in a row, and the result goes through
+    the gated norm before it leaves. With ``epsilon`` None q and k
+    are the rule's operands as they stand, a head each, and the result is
+    the rule's, float32: the same kernel bodies with prologue and epilogue
+    left out where they are traced."""
+
+    heads: int
+    key_heads: int
+    dk: int
+    dv: int
+    epsilon: Optional[float]
+
+    @property
+    def mixer(self) -> bool:
+        return self.epsilon is not None
+
+    @property
+    def step(self) -> int:
+        """Value heads a grid step takes."""
+        return _heads_per_step(self.heads)
+
+    @property
+    def ratio(self) -> int:
+        return self.heads // self.key_heads
+
+
+class _Unit:
+    """Rows scaled to unit length as the mixer does it, ``x * rsqrt(sum(x x)
+    + 1e-6)``, and that scaling's backward."""
+
+    def __init__(self, x):
+        self.factor = lax.rsqrt(_rowsum(x * x) + L2_EPSILON)
+        self.rows = x * self.factor
+
+    def backward(self, d):
+        """The cotangent of ``rows`` -> that of ``x``."""
+        return self.factor * (d - self.rows * _rowsum(d * self.rows))
+
+
+class _GatedNorm:
+    """One head's rows of the mixer's output norm: ``y = o * rsqrt(mean(o o)
+    + eps) * w * silu(z)``, float32 throughout; ``w`` is (1, Dv)."""
+
+    def __init__(self, o, z, w, epsilon):
+        self.z, self.w = z, w
+        self.factor = lax.rsqrt(
+            _rowsum(o * o) * (1.0 / o.shape[1]) + epsilon)
+        self.normed = o * self.factor
+        self.sigmoid = 1.0 / (1.0 + jnp.exp(-z))
+        self.silu = z * self.sigmoid
+
+    @property
+    def out(self):
+        return self.normed * self.w * self.silu
+
+    def backward(self, dy):
+        """The cotangent of ``out`` -> those of o, z and ``w`` (1, Dv)."""
+        dnormed = dy * (self.w * self.silu)
+        do = self.factor * (dnormed - self.normed * (
+            _rowsum(dnormed * self.normed) * (1.0 / dy.shape[1])))
+        through = dy * self.normed
+        dz = through * self.w * (self.sigmoid * (
+            1.0 + self.z * (1.0 - self.sigmoid)))
+        return do, dz, jnp.sum(through * self.silu, axis=0, keepdims=True)
+
+
+def _rows(ref, d, h):
+    return ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+
+
+def _pack(form, q_ref, k_ref, v_ref, g_ref, sums, beta_ref, members):
+    """The chunk of the step's value heads ``members``. In the mixer's form
+    a key head's rows are read and scaled once for the value heads it
+    serves (a PACK of two shares one key head at the published ratio);
+    ``units`` keeps, by key head, what the scaling's backward needs."""
     columns = lambda x: [x[:, h:h + 1].astype(jnp.float32)  # noqa: E731
                          for h in members]
-    return _Pack(rows(q_ref, dk), rows(k_ref, dk), rows(v_ref, dv),
-                 columns(g_ref[0, 0]), columns(sums[0]), columns(sums[1]),
-                 columns(beta_ref[0, 0]))
+    if form.mixer:
+        keys = [h // form.ratio for h in members]
+        units = {key: (_Unit(_rows(q_ref, form.dk, key)),
+                       _Unit(_rows(k_ref, form.dk, key)))
+                 for key in dict.fromkeys(keys)}
+        scaled = {key: q.rows * form.dk ** -0.5
+                  for key, (q, _) in units.items()}
+        qs = [scaled[key] for key in keys]
+        ks = [units[key][1].rows for key in keys]
+    else:
+        keys, units = list(members), None
+        qs = [_rows(q_ref, form.dk, h) for h in members]
+        ks = [_rows(k_ref, form.dk, h) for h in members]
+    pk = _Pack(qs, ks, [_rows(v_ref, form.dv, h) for h in members],
+               columns(g_ref[0, 0]), columns(sums[0]), columns(sums[1]),
+               columns(beta_ref[0, 0]))
+    pk.keys, pk.units = keys, units
+    return pk
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
-                heads: int, dk: int, dv: int):
-    """``rest``: the backward's two residuals where they are asked for (each
-    chunk's starting states, its packs' inverses), then the state scratch."""
-    *residuals, state_scr = rest
+def _fwd_kernel(*refs, form: _Form):
+    """Operands: q, k, v, g, beta and, in the mixer's form, z and the norm's
+    weight; then the output, the backward's two residuals where they are
+    asked for (each chunk's starting states, its packs' inverses), and the
+    state scratch."""
+    q_ref, k_ref, v_ref, g_ref, beta_ref, *rest = refs
+    if form.mixer:
+        z_ref, w_ref, *rest = rest
+    o_ref, *residuals, state_scr = rest
 
     @pl.when(pl.program_id(2) == 0)
     def _first_chunk():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    c = CHUNK
+    dv = form.dv
     sums = _running_sums(g_ref[0, 0])
 
     def forward(n, members):
-        pk = _pack(q_ref, k_ref, v_ref, g_ref, sums, beta_ref, members, dk,
-                   dv)
+        pk = _pack(form, q_ref, k_ref, v_ref, g_ref, sums, beta_ref, members)
         yield from pk.tables()
         states = [state_scr[h] for h in members]
-        # [k; q_in] S: what the state holds of the keys, and what it answers
-        reads = [_dot(_stack([k, q_in]), state, _NN)
-                 for k, q_in, state in zip(pk.ks, pk.q_ins, states)]
-        yield
-        pk.solve([read[:c] for read in reads])
-        yield
-        answers = _split(_dot(pk.scores, _diagonal(pk.us), _NN), pk.p)
+        yield from pk.outputs(states)
         if residuals:
             start_ref, t_ref = residuals
             t_ref[0, 0, n] = pk.t
             for i, h in enumerate(members):
                 start_ref[0, 0, h] = states[i]
         for i, h in enumerate(members):
-            o_ref[0, :, h * dv:(h + 1) * dv] = reads[i][c:] + answers[i]
+            o = pk.outs[i]
+            if form.mixer:
+                o = _GatedNorm(o, _rows(z_ref, dv, h), w_ref[...],
+                               form.epsilon).out
+            o_ref[0, :, h * dv:(h + 1) * dv] = o.astype(o_ref.dtype)
             state_scr[h] = states[i] * pk.keeps[i] + _dot(
                 pk.k_outs[i], pk.us[i], _TN)
 
     _in_turn([forward(n, members)
-              for n, members in enumerate(_packs(heads))])
+              for n, members in enumerate(_packs(form.step))])
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, t_ref,
-                do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                dstate_scr, *, heads: int, dk: int, dv: int):
-    """Chunks arrive last first; ``dstate_scr`` is the cotangent of the state
-    the chunk LEAVES, zero behind the last chunk."""
+def _bwd_kernel(*refs, form: _Form):
+    """Operands: the forward's, its two residuals and the output's
+    cotangent; then the cotangents of q, k, v, g, beta and, in the mixer's
+    form, of z and (summed over the step's heads and the chunks so far) of
+    the norm's weight; then the scratch. Chunks arrive last first;
+    ``dstate_scr`` is the cotangent of the state the chunk LEAVES, zero
+    behind the last chunk."""
+    q_ref, k_ref, v_ref, g_ref, beta_ref, *rest = refs
+    if form.mixer:
+        z_ref, w_ref, *rest = rest
+    start_ref, t_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, \
+        *rest = rest
+    if form.mixer:
+        dz_ref, dw_ref, *rest = rest
+    dstate_scr, = rest
+
     @pl.when(pl.program_id(2) == 0)
     def _last_chunk():
         dstate_scr[...] = jnp.zeros_like(dstate_scr)
+        if form.mixer:
+            dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    c = CHUNK
+    c, dk, dv = CHUNK, form.dk, form.dv
+    heads = form.step
     sums = _running_sums(g_ref[0, 0])
     last = lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
     dgcs, dbetas = {}, {}       # head of the step -> its (CHUNK, 1) column
+    dws = []                    # (1, Dv) a head
 
     def backward(n, members):
-        pk = _pack(q_ref, k_ref, v_ref, g_ref, sums, beta_ref, members, dk,
-                   dv)
+        pk = _pack(form, q_ref, k_ref, v_ref, g_ref, sums, beta_ref, members)
         p = pk.p
         yield from pk.tables(t_ref[0, 0, n])
         states = [start_ref[0, 0, h] for h in members]
         dstates = [dstate_scr[h] for h in members]
-        dos = [do_ref[0, :, h * dv:(h + 1) * dv].astype(jnp.float32)
-               for h in members]
-        helds = [_dot(k, state, _NN) for k, state in zip(pk.ks, states)]
-        yield
-        pk.solve(helds)
+        dos = [_rows(do_ref, dv, h) for h in members]
+        if form.mixer:
+            # the rule's output once more, as the forward made it: the
+            # gated norm's backward needs it, and it is a product of
+            # twenty-one where keeping it is 134 MB a layer
+            yield from pk.outputs(states)
+            yield
+            for i, h in enumerate(members):
+                norm = _GatedNorm(pk.outs[i], _rows(z_ref, dv, h),
+                                  w_ref[...], form.epsilon)
+                dos[i], dz, dw = norm.backward(dos[i])
+                dz_ref[0, :, h * dv:(h + 1) * dv] = dz.astype(dz_ref.dtype)
+                dws.append(dw)
+        else:
+            helds = [_dot(k, state, _NN) for k, state in zip(pk.ks, states)]
+            yield
+            pk.solve(helds)
         # o = q_in S + scores u;  S' = keep S + k_out^T u. A packed table's
         # transpose times the pack's rows gives every pairing of heads; each
         # head's own is a diagonal block.
@@ -401,14 +581,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, t_ref,
         yield
         dtable = da * pk.a + dscores * pk.scores     # d(gc_i - gc_j)
         dtable_columns = _rowsum(dtable.T)           # (p * CHUNK, 1)
+        dqs, dks = [], []       # of the rule's own q and k, a value head each
         for i, h in enumerate(members):
             q, k, beta, state = pk.qs[i], pk.ks[i], pk.betas[i], states[i]
             dk_beta, dk_out, dq_in = right[i][:c], dk_outs[i], through[i][:c]
-            dq_ref[0, :, h * dk:(h + 1) * dk] = (
-                right[i][c:] + pk.intos[i] * dq_in).astype(dq_ref.dtype)
-            dk_ref[0, :, h * dk:(h + 1) * dk] = (
-                through[i][c:] + dk_beta * beta + left[i]
-                + pk.to_ends[i] * dk_out).astype(dk_ref.dtype)
+            dqs.append(right[i][c:] + pk.intos[i] * dq_in)
+            dks.append(through[i][c:] + dk_beta * beta + left[i]
+                       + pk.to_ends[i] * dk_out)
             dv_ref[0, :, h * dv:(h + 1) * dv] = dmissings[i].astype(
                 dv_ref.dtype)
             dbetas[h] = _rowsum(drs[i] * pk.missings[i]) \
@@ -425,6 +604,22 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, t_ref,
                        + jnp.where(last, dg_end, 0.0))
             dstate_scr[h] = dstates[i] * pk.keeps[i] + _dot(
                 _stack([pk.q_ins[i], k]), _stack([dos[i], dhelds[i]]), _TN)
+        if form.mixer:
+            # a key head's cotangent is the sum over the value heads it
+            # served, sent back through the scaling and written once
+            for key, (q_unit, k_unit) in pk.units.items():
+                served = [i for i in range(p) if pk.keys[i] == key]
+                dq_ref[0, :, key * dk:(key + 1) * dk] = q_unit.backward(
+                    sum(dqs[i] for i in served) * dk ** -0.5).astype(
+                        dq_ref.dtype)
+                dk_ref[0, :, key * dk:(key + 1) * dk] = k_unit.backward(
+                    sum(dks[i] for i in served)).astype(dk_ref.dtype)
+        else:
+            for i, h in enumerate(members):
+                dq_ref[0, :, h * dk:(h + 1) * dk] = dqs[i].astype(
+                    dq_ref.dtype)
+                dk_ref[0, :, h * dk:(h + 1) * dk] = dks[i].astype(
+                    dk_ref.dtype)
 
     _in_turn([backward(n, members)
               for n, members in enumerate(_packs(heads))])
@@ -439,6 +634,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, start_ref, t_ref,
     dg_ref[0, 0] = _dot(upper.astype(jnp.float32), dgc_all,
                         _NN).astype(dg_ref.dtype)
     dbeta_ref[0, 0] = dbeta_all.astype(dbeta_ref.dtype)
+    if form.mixer:
+        dw_ref[0, 0] += sum(dws)
 
 
 def _heads_per_step(h: int) -> int:
@@ -456,17 +653,39 @@ def _ungrouped(x):     # back
     return jnp.moveaxis(x, 1, 2).reshape(b, s, groups * hb)
 
 
-def _specs(hb, dk, dv, n, *, reverse):
-    chunk_of = (lambda c: n - 1 - c) if reverse else (lambda c: c)
-    wide = lambda d: pl.BlockSpec(  # noqa: E731
-        (1, CHUNK, hb * d), lambda i, j, c: (i, chunk_of(c), j))
-    narrow = pl.BlockSpec((1, 1, CHUNK, hb),
-                          lambda i, j, c: (i, j, chunk_of(c), 0))
-    start = pl.BlockSpec((1, 1, hb, dk, dv),
-                         lambda i, j, c: (i, chunk_of(c), j, 0, 0))
-    inverse = pl.BlockSpec((1, 1, hb // PACK, CHUNK, PACK * CHUNK),
-                           lambda i, j, c: (i, chunk_of(c), j, 0, 0))
-    return wide, narrow, start, inverse
+class _Specs:
+    """The blocks of one call: ``q``, ``k``, ``v`` of the operands (in the
+    mixer's form three ranges of columns of ONE array, the convolution's
+    output, each found by the index of its first block), ``key`` and
+    ``wide`` of tables that hold nothing else, a step's key heads or value
+    heads wide."""
+
+    def __init__(self, form: _Form, n: int, *, reverse: bool):
+        chunk_of = (lambda c: n - 1 - c) if reverse else (lambda c: c)
+        hb, dk, dv = form.step, form.dk, form.dv
+        kb = hb // form.ratio
+
+        def columns(width, first=0):
+            return pl.BlockSpec((1, CHUNK, width),
+                                lambda i, j, c: (i, chunk_of(c), first + j))
+
+        self.key, self.wide = columns(kb * dk), columns(hb * dv)
+        self.q, self.k, self.v = self.key, self.key, self.wide
+        if form.mixer:
+            self.k = columns(kb * dk, form.key_heads // kb)
+            self.v = columns(hb * dv, 2 * form.key_heads * dk // (hb * dv))
+        self.narrow = pl.BlockSpec(
+            (1, 1, CHUNK, hb), lambda i, j, c: (i, j, chunk_of(c), 0))
+        self.start = pl.BlockSpec(
+            (1, 1, hb, dk, dv), lambda i, j, c: (i, chunk_of(c), j, 0, 0))
+        self.inverse = pl.BlockSpec(
+            (1, 1, hb // PACK, CHUNK, PACK * CHUNK),
+            lambda i, j, c: (i, chunk_of(c), j, 0, 0))
+        self.weight = pl.BlockSpec((1, dv), lambda i, j, c: (0, 0))
+        # a step's sum over its chunks: the block stays while they pass
+        self.dweight = pl.BlockSpec((1, 1, 1, dv),
+                                    lambda i, j, c: (i, j, 0, 0))
+        self.gate = [self.wide, self.weight] if form.mixer else []
 
 
 # four packs' tables live at once, beside double-buffered blocks of eight
@@ -486,77 +705,139 @@ _SEQUENTIAL_CHUNKS = pltpu.CompilerParams(
 # is lowered once for all sites and its operations lose the path the
 # region metrics read). Tracing is paid by every process, compile cache or
 # not: without this `setup_s` rose by 9 s.
-@functools.partial(jax.jit, inline=True, static_argnames="residuals")
-def _forward(q, k, v, g, beta, *, residuals: bool):
+@functools.partial(jax.jit, inline=True, static_argnames=("form", "residuals"))
+def _forward(q, k, v, g, beta, gate, *, form: _Form, residuals: bool):
     """(the output,) and, with ``residuals``, what the backward needs besides
-    the inputs: each chunk's starting states and its packs' inverses."""
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
-    hb, n = _heads_per_step(h), s // CHUNK
-    wide, narrow, start, inverse = _specs(hb, dk, dv, n, reverse=False)
+    the inputs: each chunk's starting states and its packs' inverses. q, k,
+    v: the (B, S, columns) arrays that hold them; ``gate``: (z, the norm's
+    weight as a float32 row) in the mixer's form, whose output is q's dtype,
+    else () and float32."""
+    b, s, h = g.shape
+    dk, dv, hb, n = form.dk, form.dv, form.step, s // CHUNK
+    sp = _Specs(form, n, reverse=False)
     kept = residuals * [
-        (start, jax.ShapeDtypeStruct((b, n, h, dk, dv), jnp.float32)),
-        (inverse, jax.ShapeDtypeStruct(
+        (sp.start, jax.ShapeDtypeStruct((b, n, h, dk, dv), jnp.float32)),
+        (sp.inverse, jax.ShapeDtypeStruct(
             (b, n, h // PACK, CHUNK, PACK * CHUNK), jnp.float32))]
     call = pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=hb, dk=dk, dv=dv),
+        functools.partial(_fwd_kernel, form=form),
         name="gdn_rule_fwd", grid=(b, h // hb, n),
-        in_specs=[wide(dk), wide(dk), wide(dv), narrow, narrow],
-        out_specs=[wide(dv)] + [spec for spec, _ in kept],
-        out_shape=[jax.ShapeDtypeStruct((b, s, h * dv), jnp.float32)]
+        in_specs=[sp.q, sp.k, sp.v, sp.narrow, sp.narrow, *sp.gate],
+        out_specs=[sp.wide] + [spec for spec, _ in kept],
+        out_shape=[jax.ShapeDtypeStruct(
+            (b, s, h * dv), q.dtype if form.mixer else jnp.float32)]
         + [shape for _, shape in kept],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
         compiler_params=_SEQUENTIAL_CHUNKS, interpret=_interpret())
     with jax.named_scope("gdn_rule_fwd"):
-        out, *written = call(
-            q.reshape(b, s, h * dk), k.reshape(b, s, h * dk),
-            v.reshape(b, s, h * dv), _grouped(g, hb), _grouped(beta, hb))
-    return (out.reshape(b, s, h, dv), *written)
+        return tuple(call(q, k, v, _grouped(g, hb), _grouped(beta, hb),
+                          *gate))
 
 
-@functools.partial(jax.jit, inline=True)
-def _backward(q, k, v, g, beta, starts, inverses, do):
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
-    hb, n = _heads_per_step(h), s // CHUNK
-    wide, narrow, start, inverse = _specs(hb, dk, dv, n, reverse=True)
-    like = lambda x, shape: jax.ShapeDtypeStruct(shape, x.dtype)  # noqa: E731
+@functools.partial(jax.jit, inline=True, static_argnames="form")
+def _backward(q, k, v, g, beta, gate, starts, inverses, do, *, form: _Form):
+    """The cotangents of q, k (a table of ``key_heads`` each), v, g, beta
+    and, in the mixer's form, of z and of the norm's weight, the last as a
+    row for every group of heads, to be summed."""
+    b, s, h = g.shape
+    dk, dv, hb, n = form.dk, form.dv, form.step, s // CHUNK
+    sp = _Specs(form, n, reverse=True)
+    like = lambda x, *shape: jax.ShapeDtypeStruct(shape, x.dtype)  # noqa: E731
     narrow_shape = (b, h // hb, s, hb)
+    gated = [(sp.wide, like(gate[0], b, s, h * dv)),
+             (sp.dweight, jax.ShapeDtypeStruct((b, h // hb, 1, dv),
+                                               jnp.float32))
+             ] if form.mixer else []
     call = pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=hb, dk=dk, dv=dv),
+        functools.partial(_bwd_kernel, form=form),
         name="gdn_rule_bwd", grid=(b, h // hb, n),
-        in_specs=[wide(dk), wide(dk), wide(dv), narrow, narrow, start,
-                  inverse, wide(dv)],
-        out_specs=[wide(dk), wide(dk), wide(dv), narrow, narrow],
-        out_shape=[like(q, (b, s, h * dk)), like(k, (b, s, h * dk)),
-                   like(v, (b, s, h * dv)), like(g, narrow_shape),
-                   like(beta, narrow_shape)],
+        in_specs=[sp.q, sp.k, sp.v, sp.narrow, sp.narrow, *sp.gate, sp.start,
+                  sp.inverse, sp.wide],
+        out_specs=[sp.key, sp.key, sp.wide, sp.narrow, sp.narrow]
+        + [spec for spec, _ in gated],
+        out_shape=[like(q, b, s, form.key_heads * dk),
+                   like(k, b, s, form.key_heads * dk), like(v, b, s, h * dv),
+                   like(g, *narrow_shape), like(beta, *narrow_shape)]
+        + [shape for _, shape in gated],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
         compiler_params=_SEQUENTIAL_CHUNKS, interpret=_interpret())
     with jax.named_scope("gdn_rule_bwd"):
-        dq, dk_, dv_, dg, dbeta = call(
-            q.reshape(b, s, h * dk), k.reshape(b, s, h * dk),
-            v.reshape(b, s, h * dv), _grouped(g, hb), _grouped(beta, hb),
-            starts, inverses, do.reshape(b, s, h * dv))
-    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
-            _ungrouped(dg), _ungrouped(dbeta))
+        dq, dk_, dv_, dg, dbeta, *dgate = call(
+            q, k, v, _grouped(g, hb), _grouped(beta, hb), *gate, starts,
+            inverses, do)
+    return (dq, dk_, dv_, _ungrouped(dg), _ungrouped(dbeta), *dgate)
+
+
+def _flat(x):   # (B, S, H, D) -> (B, S, H * D): a reshape, no copy
+    return x.reshape(*x.shape[:2], -1)
+
+
+def _raw_form(q, v) -> _Form:
+    return _Form(q.shape[2], q.shape[2], q.shape[3], v.shape[3], None)
 
 
 @jax.custom_vjp
 def _rule(q, k, v, g, beta):
-    return _forward(q, k, v, g, beta, residuals=False)[0]
+    out, = _forward(_flat(q), _flat(k), _flat(v), g, beta, (),
+                    form=_raw_form(q, v), residuals=False)
+    return out.reshape(v.shape)
 
 
 def _rule_fwd(q, k, v, g, beta):
-    out, starts, inverses = _forward(q, k, v, g, beta, residuals=True)
-    return out, (q, k, v, g, beta, starts, inverses)
+    out, starts, inverses = _forward(
+        _flat(q), _flat(k), _flat(v), g, beta, (), form=_raw_form(q, v),
+        residuals=True)
+    return out.reshape(v.shape), (q, k, v, g, beta, starts, inverses)
 
 
 def _rule_bwd(residuals, do):
-    return _backward(*residuals, do)
+    q, k, v, g, beta, starts, inverses = residuals
+    dq, dk, dv, dg, dbeta = _backward(
+        _flat(q), _flat(k), _flat(v), g, beta, (), starts, inverses,
+        _flat(do), form=_raw_form(q, v))
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dg, dbeta)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def _gate(z, norm_w):
+    return z, norm_w.astype(jnp.float32).reshape(1, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _mixer(qkv, z, g, beta, norm_w, form):
+    return _forward(qkv, qkv, qkv, g, beta, _gate(z, norm_w), form=form,
+                    residuals=False)[0]
+
+
+def _mixer_fwd(qkv, z, g, beta, norm_w, form):
+    out, starts, inverses = _forward(
+        qkv, qkv, qkv, g, beta, _gate(z, norm_w), form=form, residuals=True)
+    return out, (qkv, z, g, beta, norm_w, starts, inverses)
+
+
+def _mixer_bwd(form, residuals, dout):
+    qkv, z, g, beta, norm_w, starts, inverses = residuals
+    dq, dk, dv, dg, dbeta, dz, dweight = _backward(
+        qkv, qkv, qkv, g, beta, _gate(z, norm_w), starts, inverses, dout,
+        form=form)
+    return (jnp.concatenate([dq, dk, dv], axis=-1), dz, dg, dbeta,
+            dweight.sum(axis=(0, 1, 2)).astype(norm_w.dtype))
+
+
+_mixer.defvjp(_mixer_fwd, _mixer_bwd)
+
+
+def _padded(s, *tables):
+    """The tables with their S axis filled to whole chunks by positions
+    that leave the state alone (k = v = 0, beta = 0, g = 0)."""
+    pad = -s % CHUNK
+    if not pad:
+        return tables
+    return tuple(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                 for x in tables)
 
 
 def gated_delta_rule_kernels(q, k, v, g, beta):
@@ -569,9 +850,23 @@ def gated_delta_rule_kernels(q, k, v, g, beta):
     if h % PACK:
         raise ValueError(f"gated_delta_rule_kernels: {h} heads are not whole "
                          f"packs of {PACK}")
-    pad = -s % CHUNK
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-            for x in (q, k, v, g, beta))
-    return _rule(q, k, v, g, beta)[:, :s]
+    return _rule(*_padded(s, q, k, v, g, beta))[:, :s]
+
+
+def gated_delta_mixer_kernels(qkv, z, g, beta, norm_w, epsilon, *,
+                              key_heads: int):
+    """The rule between the mixer's own tables, by the same two kernels:
+    `gated_delta_rule.gated_delta_mixer` has the equations. qkv: (B, S, 2 *
+    key_heads * Dk + H * Dv), the convolution's output, its columns q | k |
+    v; z: (B, S, H * Dv); g, beta: (B, S, H) float32; norm_w: (Dv,).
+    Returns (B, S, H * Dv) in qkv's dtype. `gdn_rule_supports` with
+    ``key_heads`` says which shapes may be sent here."""
+    s, h = g.shape[1:]
+    dv = norm_w.shape[-1]
+    dk = (qkv.shape[-1] - h * dv) // (2 * key_heads)
+    if not gdn_rule_supports(h, dk, dv, key_heads):
+        raise ValueError(
+            f"gated_delta_mixer_kernels: {h} value heads of {dv} over "
+            f"{key_heads} key heads of {dk} are no shape of the kernels'")
+    form = _Form(h, key_heads, dk, dv, float(epsilon))
+    return _mixer(*_padded(s, qkv, z, g, beta), norm_w, form)[:, :s]

@@ -387,3 +387,352 @@ def test_hybrid_train_step_carries_both_kernels_under_gdn_rule(monkeypatch):
     assert any("transpose" in p for p in paths if "/gdn_rule_bwd" in p)
     # what the XLA form's head blocks were: a `while` under the rule's scope
     assert not any("gdn_rule/while" in p for p in paths)
+
+
+# ---------------------------------------------------------------------------
+# the mixer's own tables (PR 39): l2 norm, query scale and key-head
+# repeat in the kernels' prologue, the gated norm in their epilogue
+# ---------------------------------------------------------------------------
+
+EPSILON = 1e-6
+
+
+def mixer_inputs(length, decay, *, key_heads, heads, dtype=jnp.float32, b=2,
+                 dk=16, dv=8):
+    """(the convolution's output q | k | v, z, g, beta, the norm's weight)
+    as `GatedDeltaNet` hands them over: q and k at ``key_heads`` heads,
+    neither scaled nor repeated."""
+    ks = jax.random.split(jax.random.PRNGKey(length + heads), 5)
+    qkv = jax.nn.silu(jax.random.normal(
+        ks[0], (b, length, 2 * key_heads * dk + heads * dv))).astype(dtype)
+    z = jax.random.normal(ks[1], (b, length, heads * dv)).astype(dtype)
+    g = -decay * jax.random.uniform(ks[2], (b, length, heads))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (b, length, heads)))
+    norm_w = 1.0 + 0.1 * jax.random.normal(ks[4], (dv,))
+    return qkv, z, g, beta, norm_w
+
+
+def stepwise_mixer(qkv, z, g, beta, norm_w, *, key_heads):
+    """The oracle: the mixer's norms written out, in float32, around the
+    position-by-position rule."""
+    b, s, h = g.shape
+    dv = norm_w.shape[0]
+    key_dim = (qkv.shape[-1] - h * dv) // 2
+    dk = key_dim // key_heads
+    qkv, z = qkv.astype(jnp.float32), z.astype(jnp.float32)
+    unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+        (x * x).sum(-1, keepdims=True) + 1e-6)
+    q = unit(qkv[..., :key_dim].reshape(b, s, key_heads, dk)) * dk ** -0.5
+    k = unit(qkv[..., key_dim:2 * key_dim].reshape(b, s, key_heads, dk))
+    q, k = (jnp.repeat(t, h // key_heads, axis=2) for t in (q, k))
+    o = gdr.gated_delta_rule_stepwise(
+        q, k, qkv[..., 2 * key_dim:].reshape(b, s, h, dv), g, beta)
+    o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + EPSILON)
+    return (o * norm_w * jax.nn.silu(z.reshape(b, s, h, dv))).reshape(
+        b, s, h * dv)
+
+
+def mixer_forms(key_heads):
+    """The new entry's three: its kernels (interpreted, here), what
+    `gated_delta_mixer` itself runs off a TPU (its XLA form), the oracle."""
+    return (lambda *a: kernels.gated_delta_mixer_kernels(
+                *a, EPSILON, key_heads=key_heads),
+            lambda *a: gdr.gated_delta_mixer(
+                *a, EPSILON, key_heads=key_heads, head_block=2),
+            lambda *a: stepwise_mixer(*a, key_heads=key_heads))
+
+
+def mixer_grads(form, args):
+    """Gradients of all five operands, of a loss that weighs every output
+    differently (a plain sum of squares is blind to the gated norm's
+    scale)."""
+    weights = jax.random.normal(jax.random.PRNGKey(7), args[1].shape)
+    return jax.grad(lambda *a: (form(*a).astype(jnp.float32) * weights).sum(),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+
+
+def columns_of(dqkv, key_heads, dk=16):
+    """dq, dk (at the key heads' width) and dv of the convolution's
+    cotangent."""
+    key_dim = key_heads * dk
+    return (dqkv[..., :key_dim], dqkv[..., key_dim:2 * key_dim],
+            dqkv[..., 2 * key_dim:])
+
+
+@pytest.mark.parametrize("ratio", [2, 1], ids=["two_a_key", "one_a_key"])
+@pytest.mark.parametrize("decay", [0.05, 30.0],
+                         ids=["weak_decay", "strong_decay"])
+@pytest.mark.parametrize("length", [64, 100, 200])
+def test_mixer_kernels_match_the_xla_form_and_the_stepwise_rule(
+        length, decay, ratio):
+    args = mixer_inputs(length, decay, key_heads=4 // ratio, heads=4)
+    from_kernels, from_xla, from_steps = (
+        form(*args) for form in mixer_forms(4 // ratio))
+    assert from_kernels.dtype == args[0].dtype
+    assert from_kernels.shape == args[1].shape == from_xla.shape
+    assert bool(jnp.isfinite(from_kernels).all())
+    assert rel(from_kernels, from_xla) < FWD_TOL
+    assert rel(from_kernels, from_steps) < FWD_TOL
+
+
+@pytest.mark.parametrize("length,decay,ratio", [
+    (64, 0.05, 2), (100, 30.0, 2), (200, 0.05, 2), (100, 0.05, 1),
+    (200, 30.0, 1)])
+def test_mixer_kernels_gradients_match_both_other_forms(length, decay, ratio):
+    """All five operands: the convolution's output (dq and dk arrive at the
+    key heads' width, summed over the value heads a key head served and
+    sent back through the l2 norm inside the kernel), z, g, beta and the
+    norm's weight."""
+    key_heads = 4 // ratio
+    args = mixer_inputs(length, decay, key_heads=key_heads, heads=4)
+    from_kernels, from_xla, from_steps = (
+        mixer_grads(form, args) for form in mixer_forms(key_heads))
+    for want in (from_xla, from_steps):
+        for got, other in zip(from_kernels, want):
+            assert got.shape == other.shape and got.dtype == other.dtype
+            assert bool(jnp.isfinite(got).all())
+            assert rel(got, other) < GRAD_TOL
+        for got, other in zip(columns_of(from_kernels[0], key_heads),
+                              columns_of(want[0], key_heads)):
+            assert rel(got, other) < GRAD_TOL
+
+
+@pytest.mark.parametrize("heads,key_heads", [
+    (kernels.HEADS_PER_STEP * 2, kernels.HEADS_PER_STEP),
+    (kernels.HEADS_PER_STEP + 2, kernels.HEADS_PER_STEP // 2 + 1),
+    (kernels.HEADS_PER_STEP * 2, kernels.HEADS_PER_STEP * 2)])
+def test_mixer_heads_beyond_one_grid_step_and_a_batch(heads, key_heads):
+    """Two grid steps of eight value heads over four key heads each; five
+    of two over one; two of eight with a key head each: a step's blocks of
+    q and k are found at the key heads' stride, v's and z's at the value
+    heads', every batch row starts from zero and the norm weight's
+    cotangent is summed over steps and rows."""
+    args = mixer_inputs(130, 0.1, key_heads=key_heads, heads=heads)
+    from_kernels, _, from_steps = mixer_forms(key_heads)
+    assert rel(from_kernels(*args), from_steps(*args)) < FWD_TOL
+    for got, want in zip(mixer_grads(from_kernels, args),
+                         mixer_grads(from_steps, args)):
+        assert rel(got, want) < GRAD_TOL
+
+
+def test_mixer_bf16_tables_are_cast_up_in_the_kernel_and_rounded_once():
+    """q, k, v and z enter as the bf16 the mixer holds; everything between
+    is float32; the output is rounded to bf16 once, and so is each
+    operand's cotangent (g's, beta's and the weight's stay float32)."""
+    args = mixer_inputs(200, 0.05, key_heads=2, heads=4, dtype=jnp.bfloat16)
+    from_kernels, _, from_steps = mixer_forms(2)
+    got, want = from_kernels(*args), from_steps(*args)
+    assert got.dtype == jnp.bfloat16
+    assert rel(got.astype(jnp.float32), want) < 2 ** -8
+    got_grads = mixer_grads(from_kernels, args)
+    assert [x.dtype for x in got_grads] == [x.dtype for x in args]
+    up = tuple(x.astype(jnp.float32) for x in args)
+    # the bf16 output hands back a bf16 cotangent: every gradient carries
+    # that rounding, the two tables' their own besides
+    for got, want in zip(got_grads, mixer_grads(from_steps, up)):
+        assert rel(got.astype(jnp.float32), want) < 2 ** -7
+
+
+def test_raw_operands_and_the_mixers_tables_reach_one_kernel_body(
+        monkeypatch):
+    """`gated_delta_rule(q, k, v, g, beta, head_block=)`, what the
+    benchmark's rule check calls, runs the body the mixer's entry runs,
+    with prologue and epilogue left out when it is traced: a static
+    description of the operands (`_Form`), no argument of either entry."""
+    seen = []
+    for name in ("_fwd_kernel", "_bwd_kernel"):
+        body = getattr(kernels, name)
+
+        def spy(*refs, form, body=body, name=name):
+            seen.append((name, form.mixer, form.ratio))
+            return body(*refs, form=form)
+
+        monkeypatch.setattr(kernels, name, spy)
+    # shapes no other test of this file traces: the two calls are jitted
+    raw = odd_rule_inputs(72, 0.05, h=6)
+    jax.grad(lambda *a: kernels.gated_delta_rule_kernels(*a).sum())(*raw)
+    assert seen == [("_fwd_kernel", False, 1), ("_bwd_kernel", False, 1)]
+    del seen[:]
+    args = mixer_inputs(72, 0.05, key_heads=3, heads=6)
+    jax.grad(lambda *a: kernels.gated_delta_mixer_kernels(
+        *a, EPSILON, key_heads=3).sum())(*args)
+    assert seen == [("_fwd_kernel", True, 2), ("_bwd_kernel", True, 2)]
+    assert list(inspect.signature(gdr.gated_delta_mixer).parameters) == [
+        "qkv", "z", "g", "beta", "norm_w", "epsilon", "key_heads",
+        "head_block"]
+
+
+def pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from pallas_calls(inner)
+
+
+def test_the_mixers_forward_keeps_the_two_residuals_and_not_its_output():
+    """Undifferentiated: one output, in the tables' dtype. Differentiated:
+    the chunk-start states and the inverses besides, as for raw operands;
+    the rule's float32 output is not among them (the backward makes it
+    again from what it has)."""
+    args = mixer_inputs(128, 0.05, key_heads=2, heads=4, dtype=jnp.bfloat16)
+    form = mixer_forms(2)[0]
+
+    def outputs(f):
+        [call] = pallas_calls(jax.make_jaxpr(f)(*args).jaxpr)
+        assert call.params["name"] == "gdn_rule_fwd"
+        return [(v.aval.shape, v.aval.dtype.name) for v in call.outvars]
+
+    assert outputs(form) == [((2, 128, 4 * 8), "bfloat16")]
+    assert outputs(lambda *a: jax.vjp(form, *a)[0]) == [
+        ((2, 128, 4 * 8), "bfloat16"), ((2, 2, 4, 16, 8), "float32"),
+        ((2, 2, 2, 64, 128), "float32")]
+
+
+def test_a_ratio_the_packs_cannot_hold_goes_to_the_xla_form(monkeypatch):
+    """Two value heads a key head or one are the kernels'; four, or three,
+    are not: the shapes' gate says so, the kernels' entry refuses them, and
+    on a TPU `gated_delta_mixer` then runs its XLA lines around the rule
+    (whose raw operands the kernels do take)."""
+    assert kernels.gdn_rule_supports(4, 16, 8, key_heads=2)
+    assert kernels.gdn_rule_supports(4, 16, 8, key_heads=4)
+    assert not kernels.gdn_rule_supports(8, 16, 8, key_heads=2)
+    assert not kernels.gdn_rule_supports(6, 16, 8, key_heads=2)
+    assert not kernels.gdn_rule_supports(6, 16, 8, key_heads=4)
+    # v's columns begin inside a block of a step's value heads
+    assert not kernels.gdn_rule_supports(8, 8, 16, key_heads=4)
+    args = mixer_inputs(100, 0.05, key_heads=2, heads=8)
+    with pytest.raises(ValueError, match="no shape of the kernels'"):
+        kernels.gated_delta_mixer_kernels(*args, EPSILON, key_heads=2)
+    monkeypatch.setattr(gdr, "gdn_rule_backend_supported", lambda: True)
+    monkeypatch.setattr(gdr, "gdn_rule_one_device_trace", lambda: True)
+    calls = []
+    for name in ("gated_delta_mixer_kernels", "gated_delta_rule_kernels"):
+        real = getattr(gdr, name)
+        monkeypatch.setattr(
+            gdr, name, lambda *a, real=real, name=name, **kw: (
+                calls.append(name), real(*a, **kw))[1])
+    want = stepwise_mixer(*args, key_heads=2)
+    assert rel(gdr.gated_delta_mixer(*args, EPSILON, key_heads=2,
+                                     head_block=8), want) < FWD_TOL
+    assert calls == ["gated_delta_rule_kernels"]
+    args = mixer_inputs(100, 0.05, key_heads=4, heads=8)
+    assert rel(gdr.gated_delta_mixer(*args, EPSILON, key_heads=4,
+                                     head_block=8),
+               stepwise_mixer(*args, key_heads=4)) < FWD_TOL
+    assert calls[1:] == ["gated_delta_mixer_kernels"]
+
+
+@pytest.mark.parametrize("program", ["gspmd", "shard_map"])
+def test_the_mixer_lowered_for_a_tpu_on_two_devices(monkeypatch, program):
+    """`test_lowered_for_a_tpu_on_two_devices` for the mixer's entry: the
+    two-device GSPMD program is the XLA form as it was (a `while` over
+    chunks, no Mosaic kernel), and per shard in a `shard_map` the kernels'
+    new bodies go through the Pallas-to-Mosaic lowering at lane-aligned
+    head sizes, bf16 tables in."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_pytorch_training_tpu.parallel.collectives import (
+        shard_map,
+    )
+    from distributed_pytorch_training_tpu.parallel.mesh import BATCH_AXES
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    b, s, key_heads, h, d = 2, 128, 1, 2, 128
+    shapes = [((b, s, (2 * key_heads + h) * d), jnp.bfloat16),
+              ((b, s, h * d), jnp.bfloat16), ((b, s, h), jnp.float32),
+              ((b, s, h), jnp.float32), ((d,), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(*shape) for shape in shapes]
+    mesh = two_device_mesh()
+    mixer = lambda *a: gdr.gated_delta_mixer(  # noqa: E731
+        *a, EPSILON, key_heads=key_heads, head_block=8)
+    specs = (P(BATCH_AXES),) * 4 + (P(),)
+    if program == "shard_map":
+        mixer = shard_map(mixer, mesh, in_specs=specs,
+                          out_specs=P(BATCH_AXES))
+    mixer = jax.jit(mixer, in_shardings=tuple(
+        NamedSharding(mesh, spec) for spec in specs),
+        out_shardings=NamedSharding(mesh, P(BATCH_AXES)))
+    step = jax.jit(jax.grad(
+        lambda *a: mixer(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4)))
+    text = step.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    for kernel in ("gdn_rule_fwd", "gdn_rule_bwd"):
+        assert (kernel in text) == (program == "shard_map")
+    assert ("tpu_custom_call" in text) == (program == "shard_map")
+    assert ("stablehlo.while" in text) == (program == "gspmd")
+
+
+def test_hybrid_train_step_hands_the_kernels_the_mixers_own_tables(
+        monkeypatch):
+    """Does the mechanism engage: by shapes, in the one-device hybrid train
+    step on the kernel path. Every `gdn_rule_fwd` reads q, k and v from ONE
+    bf16 array, the convolution's output, in blocks a step's KEY heads wide
+    for q and k; z is bf16; no float32 table a value head wide, repeated or
+    not, feeds it; what it writes for ``out_proj`` is bf16. `gdn_rule_bwd`
+    writes dq and dk at the key heads' width in bf16."""
+    from distributed_pytorch_training_tpu.parallel import MeshSpec, build_mesh
+    from distributed_pytorch_training_tpu.parallel.sharding import shard_batch
+    from distributed_pytorch_training_tpu.training.loop import (
+        TrainConfig, Trainer,
+    )
+    from distributed_pytorch_training_tpu.training.optim import (
+        make_optimizer, make_schedule,
+    )
+    from distributed_pytorch_training_tpu.training.tasks import (
+        LanguageModelingTask,
+    )
+
+    monkeypatch.setattr(gdr, "gdn_rule_backend_supported", lambda: True)
+    monkeypatch.setattr(gdr, "gdn_rule_one_device_trace", lambda: True)
+    config = json.loads(
+        (ROOT / "benchmark/configs/qwen3_next_80b_a3b.json").read_text())
+    mesh = build_mesh(MeshSpec(data=1), devices=jax.devices()[:1])
+    model = get_model("qwen3_next_80b_a3b", dtype=jnp.bfloat16, remat=True,
+                      **config["rehearsal"]["model_overrides"])
+    hk, hv = model.linear_num_key_heads, model.linear_num_value_heads
+    dk, dv = model.linear_key_head_dim, model.linear_value_head_dim
+    assert hv == 2 * hk
+    trainer = Trainer(LanguageModelingTask(compute_dtype=jnp.bfloat16), mesh,
+                      TrainConfig(per_device_batch=2, bf16=True),
+                      rules=type(model).partition_rules())
+    state = trainer.init_state(
+        model, np.zeros((1, 64), np.int32),
+        make_optimizer("adamw", make_schedule("constant", 3e-4)),
+        jax.random.PRNGKey(0))
+    batch = shard_batch({"input_ids": np.zeros((2, 64), np.int32),
+                         "weight": np.ones(2, np.float32)}, mesh)
+    traced = trainer._train_step.trace(state, batch, jax.random.PRNGKey(0))
+    calls = {"gdn_rule_fwd": [], "gdn_rule_bwd": []}
+    for call in pallas_calls(traced.jaxpr.jaxpr):
+        if call.params["name"] in calls:
+            calls[call.params["name"]].append(call)
+    layers = sum((i + 1) % model.full_attention_interval != 0
+                 for i in range(model.depth))
+    # a layer's forward, its remat, its backward
+    assert len(calls["gdn_rule_fwd"]) == 2 * layers
+    assert len(calls["gdn_rule_bwd"]) == layers
+    step_heads = kernels._heads_per_step(hv)
+    conv_dim = 2 * hk * dk + hv * dv
+    value_wide = lambda aval: aval.shape[-1] == hv * dv or \
+        aval.shape[-2:] == (hv, dv)  # noqa: E731
+    for call in calls["gdn_rule_fwd"] + calls["gdn_rule_bwd"]:
+        q, k, v, _, _, z, weight = call.invars[:7]
+        assert q is k is v
+        assert (q.aval.shape[-1], q.aval.dtype) == (conv_dim, jnp.bfloat16)
+        assert (z.aval.shape[-1], z.aval.dtype) == (hv * dv, jnp.bfloat16)
+        assert weight.aval.shape == (1, dv)
+        blocks = call.params["grid_mapping"].block_mappings
+        assert [m.block_shape[-1].block_size for m in blocks[:3]] == [
+            step_heads // 2 * dk, step_heads // 2 * dk, step_heads * dv]
+    for call in calls["gdn_rule_fwd"]:
+        assert not any(value_wide(x.aval) and x.aval.dtype == jnp.float32
+                       for x in call.invars)
+        out = call.outvars[0].aval
+        assert (out.shape[-1], out.dtype) == (hv * dv, jnp.bfloat16)
+    for call in calls["gdn_rule_bwd"]:
+        dq, dk_, dv_ = (x.aval for x in call.outvars[:3])
+        assert dq.shape[-1] == dk_.shape[-1] == hk * dk
+        assert dv_.shape[-1] == hv * dv
+        assert {dq.dtype, dk_.dtype, dv_.dtype} == {jnp.dtype(jnp.bfloat16)}
