@@ -36,7 +36,11 @@ whole cycle. This module rebuilds the decode loop around SLOTS:
   mirror per-slot budgets in Python ints, and complete requests the
   moment THEIR budget hits zero (host fetches happen here, outside the
   AST-pinned ``_step_decode_loop``). ``slot_wait`` spans and the
-  slot-occupancy / page-pool gauges are emitted here.
+  slot-occupancy / page-pool gauges are emitted here, and the iteration
+  itself as five phase spans that tile ``step`` (``sched_pull`` ..
+  ``sched_complete``, sharing their boundaries and the iteration's
+  ``iter``) with ``page_alloc`` / ``page_table_put`` / ``slot_fetch``
+  inside the admission and the completion.
 
 Layout: the page POOL is replicated over the mesh (pages are
 slot-agnostic — prefix sharing crosses slots), while the per-slot
@@ -818,6 +822,9 @@ class ContinuousScheduler:
         # admissions skipped prefill entirely vs prefilled only a tail
         self.prefill_skips = 0                              # guarded-by: _lock
         self.tail_resumes = 0                               # guarded-by: _lock
+        # serial number of the scheduling iteration: what the spans of one
+        # `step` share (``iter``), the five phases and their children
+        self.iteration = 0                                  # guarded-by: _lock
 
     # -- admission -----------------------------------------------------------
 
@@ -856,7 +863,12 @@ class ContinuousScheduler:
         want = cfg.max_new_tokens if req.max_new_tokens is None else \
             min(int(req.max_new_tokens), cfg.max_new_tokens)
         want = max(want, 1)
+        slot = self.free_slots[-1]
+        who = dict(iter=self.iteration, slot=slot, request=req.id)
+        t0 = time.perf_counter()
         lease = self.pool.alloc(req.tokens, len(req.tokens) + want)
+        telemetry.span_event("page_alloc", time.perf_counter() - t0,
+                             ok=lease is not None, **who)
         if lease is None:
             return False
         if not self._draft_admit(req, lease, want):
@@ -866,8 +878,11 @@ class ContinuousScheduler:
             # of the same prompt would skip-admit onto garbage KV
             self.pool.rollback(lease)
             return False
-        slot = self.free_slots.pop()
+        self.free_slots.pop()
+        t0 = time.perf_counter()
         self.engine.set_page_row(slot, lease.pages)
+        telemetry.span_event("page_table_put", time.perf_counter() - t0,
+                             at="admit", **who)
         n = len(req.tokens)
         covered = len(lease.shared) * cfg.page_size
         t0 = time.perf_counter()
@@ -879,8 +894,7 @@ class ContinuousScheduler:
             left = want   # nothing emitted yet: decode emits all `want`
             self.prefill_skips += 1
             telemetry.span_event("prefill_skip", time.perf_counter() - t0,
-                                 slot=slot, request=req.id,
-                                 resident=covered)
+                                 resident=covered, **who)
         elif skip_ok and covered > 0:
             bucket = self.engine.admit_resume(
                 slot, req.tokens, covered, want, req.temperature,
@@ -888,15 +902,14 @@ class ContinuousScheduler:
             left = want - 1
             self.tail_resumes += 1
             telemetry.span_event("prefill", time.perf_counter() - t0,
-                                 bucket=bucket, slot=slot, request=req.id,
-                                 resumed=covered)
+                                 bucket=bucket, resumed=covered, **who)
         else:
             bucket = self.engine.admit(slot, req.tokens, want,
                                        req.temperature, req.top_p,
                                        req.seed)
             left = want - 1
             telemetry.span_event("prefill", time.perf_counter() - t0,
-                                 bucket=bucket, slot=slot, request=req.id)
+                                 bucket=bucket, **who)
         now = time.perf_counter()
         # t_first_token stays None until the NEXT step fence — admission
         # only dispatched device work; step() stamps it once the fence
@@ -925,25 +938,30 @@ class ContinuousScheduler:
     def _post_complete(self, slot: int) -> None:   # lock-held: _lock
         """Speculative hook: a slot finished — release its draft lease."""
 
-    def _admit_pending(self) -> None:   # lock-held: _lock
+    def _admit_pending(self) -> int:   # lock-held: _lock
+        """Try every pending request once; how many were admitted."""
         still: List[Request] = []
         for req in self.pending:
             if not self._try_admit(req):
                 still.append(req)
+        admitted = len(self.pending) - len(still)
         self.pending = still
+        return admitted
 
-    def _pull(self, timeout: float = 0.005) -> None:   # lock-held: _lock
+    def _pull(self, timeout: float = 0.005) -> int:   # lock-held: _lock
+        """Take requests off the queue onto ``pending``; how many."""
         # keep at most ~2 pool-fulls on deck; never block while slots are
         # actively decoding (the queue wait is for the idle loop only)
         cap = 2 * self.engine.config.rows - len(self.pending)
         if cap <= 0:
-            return
+            return 0
         got = self.queue.take(cap,
                               timeout=0.0 if self.running else timeout)
         now = time.perf_counter()
         for req in got:
             self._t_popped[req.id] = now
         self.pending.extend(got)
+        return len(got)
 
     # -- the decode hot loop -------------------------------------------------
 
@@ -958,12 +976,20 @@ class ContinuousScheduler:
                 if st.left > 0:
                     st.left -= 1
 
-    def _advance(self) -> None:   # lock-held: _lock
+    def _advance(self) -> Tuple[int, int, int]:   # lock-held: _lock
         """Advance every live slot: the plain scheduler runs 1..burst
         compiled decode steps (one token each); the speculative scheduler
         (serving/speculative.py) overrides this with one draft-propose +
         verify round (up to K+1 tokens per fence). Either way the caller
-        fences afterwards and completes finished slots."""
+        fences afterwards and completes finished slots. Returns (steps
+        dispatched, slots that went in with budget left, tokens they
+        emit): what the iteration's `sched_dispatch` and `sched_fence`
+        spans say."""
+        # a slot admitted with a budget of one has emitted it in its
+        # prefill and only waits for the fence: no step decrements it
+        # (counted for the spans alone, so only while someone records)
+        live = sum(st.left > 0 for st in self.running.values()) \
+            if telemetry.is_configured() else 0
         steps = 1
         if not self.pending and not len(self.queue):
             steps = max(1, min(min(st.left for st in
@@ -982,14 +1008,20 @@ class ContinuousScheduler:
             + steps * (steps - 1) // 2 for st in self.running.values()))
         if all(st.req.temperature <= 0.0 for st in self.running.values()):
             telemetry.counter("serving_decode_steps_all_greedy", steps)
+        return steps, live, live * steps
 
-    def _complete_finished(self) -> None:   # lock-held: _lock
+    def _complete_finished(self) -> int:   # lock-held: _lock
+        """Fetch, release and resolve every slot whose budget is spent;
+        how many."""
         t0 = time.perf_counter()
         done = [slot for slot, st in self.running.items() if st.left == 0]
         for slot in done:
             st = self.running.pop(slot)
+            who = dict(iter=self.iteration, slot=slot, request=st.req.id)
+            t_fetch = time.perf_counter()
             toks, last = self.engine.fetch_slot(slot)
             now = time.perf_counter()
+            telemetry.span_event("slot_fetch", now - t_fetch, **who)
             first = st.req.t_first_token or t0
             res = Result(tokens=np.asarray(toks[:st.want], np.int32),
                          last_logits=np.asarray(last),
@@ -997,14 +1029,19 @@ class ContinuousScheduler:
                          queue_wait_s=max(0.0, first - st.req.t_submit),
                          decode_s=max(0.0, now - first))
             self.pool.release(st.lease)
+            t_put = time.perf_counter()
             self.engine.set_page_row(
                 slot, np.zeros(self.engine.config.pages_per_slot, np.int32))
+            telemetry.span_event("page_table_put",
+                                 time.perf_counter() - t_put,
+                                 at="complete", **who)
             self._post_complete(slot)
             self.free_slots.append(slot)
             st.req.set_result(res)
             self.served += 1
         if done:
             self._gauges()
+        return len(done)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -1038,10 +1075,17 @@ class ContinuousScheduler:
         with self._lock:
             if self.killed:
                 return False
-            self._pull()
-            self._admit_pending()
+            self.iteration += 1
+            marks = [time.perf_counter()]
+            took = self._pull()
+            marks.append(time.perf_counter())
+            skips = self.prefill_skips
+            admitted = self._admit_pending()
+            marks.append(time.perf_counter())
+            work = None
             if self.running:
-                self._advance()
+                steps, live, tokens = self._advance()
+                marks.append(time.perf_counter())
                 jax.block_until_ready(self.engine._control["tok"])
                 # the fence proves every dispatched prefill's token #0
                 # landed: the honest (if slightly late) TTFT stamp
@@ -1049,8 +1093,48 @@ class ContinuousScheduler:
                 for st in self.running.values():
                     if st.req.t_first_token is None:
                         st.req.t_first_token = now
-                self._complete_finished()
+                marks.append(time.perf_counter())
+                completed = self._complete_finished()
+                marks.append(time.perf_counter())
+                # an admission's prefill emits its token #0, and this
+                # fence is where it lands; a skip admission's first token
+                # is one of the steps' own
+                first = admitted - (self.prefill_skips - skips)
+                work = (steps, live, tokens + first, completed)
+            if telemetry.is_configured():
+                self._emit_phases(marks, took, admitted, work)
             return bool(self.running or self.pending)
+
+    def _emit_phases(self, marks: List[float], took: int, admitted: int,
+                     work: Optional[Tuple[int, int, int, int]]
+                     ) -> None:   # lock-held: _lock
+        """The iteration as spans that tile it: ``marks`` are its phase
+        boundaries on `time.perf_counter` (three for an iteration with
+        nothing running, six for one that advanced, whose ``work`` is
+        (steps, live, tokens, completed)), moved onto the wall clock by ONE
+        offset, so that each span starts where the one before it ends. An
+        idle poll (nothing taken, nothing waiting, nothing running) emits
+        nothing: an idle server's stream stays silent."""
+        if work is None and not took and not self.pending:
+            return
+        wall = time.time() - time.perf_counter()
+        it = self.iteration
+        telemetry.span_event("sched_pull", marks[1] - marks[0],
+                             wall + marks[0], iter=it, took=took)
+        telemetry.span_event("sched_admit", marks[2] - marks[1],
+                             wall + marks[1], iter=it, admitted=admitted,
+                             pending=len(self.pending))
+        if work is None:
+            return
+        steps, live, tokens, completed = work
+        telemetry.span_event("sched_dispatch", marks[3] - marks[2],
+                             wall + marks[2], iter=it, steps=steps,
+                             live=live)
+        telemetry.span_event("sched_fence", marks[4] - marks[3],
+                             wall + marks[3], iter=it, steps=steps,
+                             live=live, tokens=tokens)
+        telemetry.span_event("sched_complete", marks[5] - marks[4],
+                             wall + marks[4], iter=it, completed=completed)
 
     def run(self, stop: threading.Event, log=None) -> int:
         """Serve until ``stop`` is set AND everything accepted has

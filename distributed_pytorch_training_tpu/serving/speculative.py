@@ -35,7 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -480,15 +480,20 @@ class SpeculativeScheduler(ContinuousScheduler):
 
     # -- the speculative round -----------------------------------------------
 
-    def _advance(self) -> None:   # lock-held: _lock
+    def _advance(self) -> Tuple[int, int, int]:   # lock-held: _lock
         """One propose + verify round: up to K+1 tokens per slot per
         fence. The n_emit fetch is the round's one host sync — the
         accepted counts ARE host state (budget mirrors, completion), and
         the caller fences right after anyway; the per-token
         no-host-sync contract (`_step_decode_loop`) is untouched because
-        this path never runs it."""
+        this path never runs it. Returns the base class's triple: one
+        round, the slots with budget left, the tokens the round emitted
+        within those budgets."""
         eng: SpeculativeEngine = self.engine
         live = len(self.running)
+        budgeted = sum(st.left > 0 for st in self.running.values()) \
+            if telemetry.is_configured() else 0
+        emitted = 0
         t0 = time.perf_counter()
         eng.draft_propose()
         t1 = time.perf_counter()
@@ -499,6 +504,7 @@ class SpeculativeScheduler(ContinuousScheduler):
         telemetry.span_event("spec_verify", t2 - t1, slots=live)
         for slot, st in self.running.items():
             got = int(n_emit[slot])
+            emitted += min(got, st.left)
             st.left = max(st.left - got, 0)
             # emitted - 1 of each round's tokens came from accepted
             # proposals (the +1 is the target's own token); the clamp to
@@ -512,6 +518,7 @@ class SpeculativeScheduler(ContinuousScheduler):
             # for external readers and this method already holds it
             telemetry.gauge("spec_accept_ratio",
                             self.spec_accepted / self.spec_proposed)
+        return 1, budgeted, emitted
 
 
 def serve_speculative(engine: SpeculativeEngine, queue: RequestQueue,

@@ -111,7 +111,7 @@ ELASTIC_SPAN_NAMES = ("elastic_replan", "elastic_reshard", "elastic_grow",
 
 # Registered-but-unaccounted span names: visible in the spans table, never
 # summed into the step-time split (the `compile` double-count rationale
-# above). Together the five tuples are THE span-name registry — the
+# above). Together the six tuples are THE span-name registry — the
 # `span-names-registered` AST rule (analysis/ast_rules.py) flags any
 # in-repo emission whose literal name is not in it, because `telemetry
 # summary` silently buckets unknown names into "unaccounted": a typo'd
@@ -127,9 +127,23 @@ AUX_SPAN_NAMES = ("compile",)
 # never summed into the step-time split.
 CONTROL_SPAN_NAMES = ("control_apply", "control_retune")
 
+# The token server's scheduler iteration (serving/continuous.py): five
+# phases that tile `ContinuousScheduler.step` under its lock, sharing their
+# boundaries (`sched_pull`, `sched_admit`, `sched_dispatch`, `sched_fence`,
+# `sched_complete`; every span of one iteration carries its ``iter``), and
+# three children inside the two phases that touch the device one request
+# at a time: `page_alloc` and `page_table_put` under `sched_admit` beside
+# `prefill`, `slot_fetch` and `page_table_put` under `sched_complete`.
+# Registered-but-unaccounted like `compile`: the phases run around
+# `prefill` and inside `slot_wait`, so summing them into the step-time
+# split would count the same wall time twice.
+SCHEDULER_SPAN_NAMES = ("sched_pull", "sched_admit", "sched_dispatch",
+                        "sched_fence", "sched_complete", "page_alloc",
+                        "page_table_put", "slot_fetch")
+
 REGISTERED_SPAN_NAMES = (SPAN_NAMES + SERVING_SPAN_NAMES
                          + ELASTIC_SPAN_NAMES + AUX_SPAN_NAMES
-                         + CONTROL_SPAN_NAMES)
+                         + CONTROL_SPAN_NAMES + SCHEDULER_SPAN_NAMES)
 
 # Event kind of one ControlDecision record (control/decisions.py): the
 # policy layer's typed decisions ride the same stream as every other
@@ -281,11 +295,15 @@ class Recorder:
 
     # -- typed helpers ----------------------------------------------------
 
-    def span_event(self, name: str, dur_s: float, **attrs: Any) -> dict:
+    def span_event(self, name: str, dur_s: float,
+                   t0: Optional[float] = None, **attrs: Any) -> dict:
         """A span whose duration the CALLER measured (the hot-loop form:
         one perf_counter pair at the call site, no context-manager
-        overhead). ``t0`` is reconstructed as now - dur."""
-        return self.emit("span", name, t0=time.time() - dur_s,
+        overhead). ``t0`` is reconstructed as now - dur, unless the caller
+        gives the wall-clock start itself: a span emitted after it ended,
+        or adjacent spans that have to share their boundary."""
+        return self.emit("span", name,
+                         t0=time.time() - dur_s if t0 is None else t0,
                          dur_ms=round(dur_s * 1e3, 4), **attrs)
 
     def span(self, name: str, **attrs: Any) -> "_Span":
@@ -420,9 +438,10 @@ def span(name: str, **attrs: Any):
     return _RECORDER.span(name, **attrs)
 
 
-def span_event(name: str, dur_s: float, **attrs: Any) -> None:
+def span_event(name: str, dur_s: float, t0: Optional[float] = None,
+               **attrs: Any) -> None:
     if _RECORDER is not None:
-        _RECORDER.span_event(name, dur_s, **attrs)
+        _RECORDER.span_event(name, dur_s, t0, **attrs)
 
 
 def counter(name: str, value: float, **attrs: Any) -> None:

@@ -791,6 +791,243 @@ class TestServingTelemetry:
 
 
 # ---------------------------------------------------------------------------
+# The scheduler iteration as spans: five phases that tile `step`, children
+# inside `sched_admit` and `sched_complete`, exact token counts at the fence
+# ---------------------------------------------------------------------------
+
+PHASES = ("sched_pull", "sched_admit", "sched_dispatch", "sched_fence",
+          "sched_complete")
+# what may lie inside which phase
+CHILDREN = {"page_alloc": ("sched_admit",),
+            "prefill": ("sched_admit",), "prefill_skip": ("sched_admit",),
+            "page_table_put": ("sched_admit", "sched_complete"),
+            "slot_fetch": ("sched_complete",)}
+# a wall-clock second of the 2020s has 2.4e-7 s between neighbouring
+# doubles and `dur_ms` is rounded to 1e-4 ms: "the same instant" and
+# "inside" are held to a microsecond
+CLOCK_EPS_S = 1e-6
+
+
+@pytest.fixture(scope="module")
+def spec_slot_engine(mesh8, tiny):
+    from distributed_pytorch_training_tpu.serving.speculative import (
+        SpeculativeEngine,
+    )
+
+    model, params = tiny
+    draft = tiny_model(hidden_dim=16, depth=1, num_heads=2)
+    dparams = draft.init(jax.random.PRNGKey(7), np.zeros((1, 8), np.int32),
+                         train=False)["params"]
+    eng = SpeculativeEngine(model, mesh8, paged_cfg(), params, draft,
+                            dparams, spec_k=3)
+    eng.warmup()
+    return eng
+
+
+def emitted_so_far(sched, reqs):
+    """Tokens of every result so far plus those the running slots have
+    emitted, from the host mirror (``want - left``)."""
+    done = sum(len(r.result(timeout=0).tokens) for r in reqs
+               if r.t_done is not None)
+    return done + sum(st.want - st.left for st in sched.running.values())
+
+
+@pytest.fixture(scope="module", params=["plain", "speculative"])
+def phase_run(request, slot_engine, spec_slot_engine):
+    """A run of mixed admissions and completions under the recorder: more
+    requests than rows (so some wait), budgets of 1..6 (so slots leave at
+    different fences), one prompt three times (so the plain engine takes
+    its skip admission), a second wave that joins mid-run. The token
+    count of `sched_fence` is read against the host mirror twice: with
+    requests still running, and after the drain."""
+    from distributed_pytorch_training_tpu.serving.speculative import (
+        SpeculativeScheduler,
+    )
+
+    engine, cls = {"plain": (slot_engine, ContinuousScheduler),
+                   "speculative": (spec_slot_engine, SpeculativeScheduler),
+                   }[request.param]
+    engine.reset_state()
+    q = RequestQueue(engine.config.buckets)
+    sched = cls(engine, q)
+    again = prompts((8,), seed=4)[0]
+    first = [(p, w) for p, w in zip(
+        prompts((5, 9, 12, 3, 16, 7, 11, 4, 6, 13), seed=21),
+        (6, 3, 1, 6, 2, 6, 5, 4, 6, 2))] + [(again, 4)]
+    second = [(again, 6), (prompts((10,), seed=5)[0], 3), (again, 1)]
+    rec = telemetry.configure(None, ring_size=1 << 16)
+    try:
+        reqs = [q.submit(p, max_new_tokens=w) for p, w in first]
+        for _ in range(4):
+            sched.step()
+        events = [e for e in rec.tail(1 << 16) if e["kind"] == "span"]
+        midway = (sum(e["tokens"] for e in events
+                      if e["name"] == "sched_fence"),
+                  emitted_so_far(sched, reqs), len(sched.running))
+        reqs += [q.submit(p, max_new_tokens=w) for p, w in second]
+        sched.drain()
+        results = [r.result(timeout=120.0) for r in reqs]
+        events = [e for e in rec.tail(1 << 16) if e["kind"] == "span"]
+    finally:
+        telemetry.reset()
+    return dict(kind=request.param, sched=sched, events=events,
+                results=results, midway=midway,
+                wants=[w for _, w in first + second])
+
+
+def by_iteration(events):
+    out = collections.defaultdict(list)
+    for e in events:
+        if "iter" in e:
+            out[e["iter"]].append(e)
+    return out
+
+
+class TestSchedulerPhases:
+    def test_phases_tile_every_iteration(self, phase_run):
+        """The phase spans of one ``iter`` are the five in order (or the
+        first two, for an iteration with nothing running), each starting
+        where the one before it ends, so that their durations sum to
+        `sched_pull`'s start .. `sched_complete`'s end; iterations follow
+        each other without overlapping."""
+        iterations = by_iteration(phase_run["events"])
+        assert len(iterations) >= 6
+        advanced, last_end = 0, 0.0
+        for it in sorted(iterations):
+            spans = [e for e in iterations[it] if e["name"] in PHASES]
+            names = tuple(e["name"] for e in spans)
+            assert names in (PHASES, PHASES[:2]), (it, names)
+            advanced += names == PHASES
+            assert spans[0]["t0"] >= last_end - CLOCK_EPS_S
+            for a, b in zip(spans, spans[1:]):
+                assert a["t0"] + a["dur_ms"] / 1e3 == pytest.approx(
+                    b["t0"], abs=CLOCK_EPS_S)
+            last_end = spans[-1]["t0"] + spans[-1]["dur_ms"] / 1e3
+            assert sum(e["dur_ms"] for e in spans) / 1e3 == pytest.approx(
+                last_end - spans[0]["t0"], abs=CLOCK_EPS_S)
+        assert advanced >= 6
+
+    def test_children_lie_inside_their_phase(self, phase_run):
+        """Every child span carries its iteration, slot and request, lies
+        inside one phase of that iteration that may hold it, and the
+        children of a phase leave it a self time that is not negative."""
+        iterations = by_iteration(phase_run["events"])
+        seen = collections.Counter()
+        for it, events in iterations.items():
+            phases = {e["name"]: e for e in events if e["name"] in PHASES}
+            inside = collections.Counter()
+            for e in events:
+                if e["name"] not in CHILDREN:
+                    continue
+                assert {"slot", "request"} <= set(e), e
+                a, b = e["t0"], e["t0"] + e["dur_ms"] / 1e3
+                home = [n for n in CHILDREN[e["name"]] if n in phases
+                        and phases[n]["t0"] - CLOCK_EPS_S <= a
+                        and b <= phases[n]["t0"] + phases[n]["dur_ms"] / 1e3
+                        + CLOCK_EPS_S]
+                assert len(home) == 1, (it, e, phases)
+                if e["name"] == "page_table_put":
+                    assert e["at"] == home[0][len("sched_"):]
+                inside[home[0]] += e["dur_ms"]
+                seen[e["name"]] += 1
+            for name, ms in inside.items():
+                assert ms <= phases[name]["dur_ms"] + 1e3 * CLOCK_EPS_S
+        n = len(phase_run["results"])
+        assert seen["page_alloc"] >= n and seen["slot_fetch"] == n
+        assert seen["page_table_put"] == 2 * n
+        assert seen["prefill"] + seen["prefill_skip"] == n
+        if phase_run["kind"] == "plain":   # the repeated prompt skipped
+            assert seen["prefill_skip"] == \
+                phase_run["sched"].prefill_skips > 0
+
+    def test_fence_tokens_are_exact(self, phase_run):
+        """`tokens` summed over `sched_fence` is what the server has
+        emitted: the tokens of all results plus those of requests still
+        running, mid-run and after the drain; the phases' other counts
+        add up to the requests."""
+        fenced, mirrored, running = phase_run["midway"]
+        assert running > 0 and fenced == mirrored > 0
+        events, results = phase_run["events"], phase_run["results"]
+        assert [len(r.tokens) for r in results] == phase_run["wants"]
+        total = lambda name, key: sum(  # noqa: E731
+            e[key] for e in events if e["name"] == name)
+        assert total("sched_fence", "tokens") == sum(phase_run["wants"])
+        assert total("sched_pull", "took") == len(results)
+        assert total("sched_admit", "admitted") == len(results)
+        assert total("sched_complete", "completed") == len(results)
+        # fence by fence: what the steps emit, plus the token #0 of every
+        # admission of the iteration that went through a prefill
+        for it, spans in by_iteration(events).items():
+            fence = [e for e in spans if e["name"] == "sched_fence"]
+            if not fence:
+                continue
+            prefills = sum(e["name"] == "prefill" for e in spans)
+            stepped = fence[0]["tokens"] - prefills
+            per_slot = 1 if phase_run["kind"] == "plain" else 4  # K + 1
+            assert fence[0]["live"] * fence[0]["steps"] <= stepped <= \
+                fence[0]["live"] * fence[0]["steps"] * per_slot, (it, spans)
+
+    def test_an_idle_poll_and_an_unconfigured_step_emit_nothing(
+            self, slot_engine):
+        slot_engine.reset_state()
+        q = RequestQueue(slot_engine.config.buckets)
+        sched = ContinuousScheduler(slot_engine, q)
+        req = q.submit(prompts((6,), seed=2)[0], max_new_tokens=2)
+        assert telemetry.get() is None
+        while sched.step():      # unconfigured: the whole request
+            pass
+        assert len(req.result(timeout=60.0).tokens) == 2
+        rec = telemetry.configure(None, ring_size=256)
+        try:
+            before = sched.iteration
+            assert sched.step() is False     # an idle poll
+            assert sched.iteration == before + 1
+            assert [e for e in rec.tail(256) if e["kind"] != "meta"] == []
+        finally:
+            telemetry.reset()
+
+    @pytest.mark.parametrize("program", ["paged_decode", "paged_prefill"])
+    def test_paged_programs_lower_the_same_with_the_recorder_on(
+            self, slot_engine, program):
+        """The spans are host-side only: the lowered text of the paged
+        decode and prefill programs cannot tell a configured recorder
+        from none (the train step's pin, `test_telemetry.py`, for the
+        programs the scheduler dispatches)."""
+        lower = {"paged_decode": slot_engine.lower_paged_decode,
+                 "paged_prefill": lambda: slot_engine.lower_paged_prefill(8),
+                 }[program]
+        assert telemetry.get() is None
+        off = lower().as_text()
+        telemetry.configure(None, ring_size=16)
+        try:
+            on = lower().as_text()
+        finally:
+            telemetry.reset()
+        assert on == off
+
+    def test_scheduler_spans_are_registered_unaccounted(self):
+        from distributed_pytorch_training_tpu.telemetry import metrics_http
+        from distributed_pytorch_training_tpu.telemetry.__main__ import (
+            summarize,
+        )
+        from distributed_pytorch_training_tpu.telemetry.recorder import (
+            REGISTERED_SPAN_NAMES, SCHEDULER_SPAN_NAMES,
+        )
+
+        assert set(SCHEDULER_SPAN_NAMES) == set(PHASES) | {
+            "page_alloc", "page_table_put", "slot_fetch"}
+        assert set(SCHEDULER_SPAN_NAMES) <= set(REGISTERED_SPAN_NAMES)
+        assert not set(SCHEDULER_SPAN_NAMES) & set(metrics_http._PHASES)
+        # they run around `prefill` and inside `slot_wait`: in the spans
+        # table, never in the step-time split
+        summary = summarize(
+            [{"kind": "span", "name": n, "dur_ms": 5.0}
+             for n in (*SCHEDULER_SPAN_NAMES, "prefill")])
+        assert set(SCHEDULER_SPAN_NAMES) <= set(summary["spans"])
+        assert set(summary["step_split_pct"]) == {"prefill"}
+
+
+# ---------------------------------------------------------------------------
 # The serving_paged contract + paged-pool-donated rule (mutation-tested)
 # ---------------------------------------------------------------------------
 
