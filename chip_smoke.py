@@ -10,8 +10,10 @@ width and weights made from a seed:
               has no CPU mode);
   1. kernels  the Pallas kernels, compiled by Mosaic, against their
               references on the chip: flash attention forward + gradient at
-              the shapes phase 2 trains at and at BERT's, and the two int8
-              codec kernels against the XLA-composed codec;
+              the shapes phase 2 trains at and at BERT's, the two int8
+              codec kernels against the XLA-composed codec, and the Gated
+              DeltaNet mixer's convolution pair against its XLA form at the
+              hybrid cell's shape;
   2. train    ``train.main([...])``: eight optimizer steps + validation +
               a manifest-verified checkpoint;
   3. serve    the token-granular server (SlotEngine + PagePool) from that
@@ -64,6 +66,12 @@ FLASH_REL_TOL = 2e-2
 CODE_MAX_DIFF = 1
 CODE_DIFF_FRACTION = 1e-3
 FP32_REL_TOL = 1e-6
+# The mixer's convolution pair is float32 inside and rounds a bf16 table
+# once: against the XLA form in float32 from the same bf16 inputs a table
+# sits within bf16's rounding, 2^-8, as ||kernel - ref|| / ||ref||; the
+# taps' gradient is a float32 sum over 8,192 rows in another order.
+CONV_TABLE_TOL = 2 ** -8
+CONV_TAPS_TOL = 1e-4
 
 
 class SmokeFailure(Exception):
@@ -250,9 +258,57 @@ def phase_kernels() -> None:
                                              np.asarray(total_ref))
                  else f"within {sum_err:.1e} of max|sum|"))
 
+    def conv_case(b, s, conv_dim, z_dim):
+        from distributed_pytorch_training_tpu.ops.gated_delta_rule import (
+            causal_conv_silu,
+        )
+        from distributed_pytorch_training_tpu.ops.gdn_conv_kernels import (
+            conv_silu_backward, conv_silu_forward,
+        )
+
+        ks = jax.random.split(jax.random.PRNGKey(conv_dim), 4)
+        width = conv_dim + z_dim
+        qkvz, into = (jax.random.normal(k, (b, s, width), jnp.bfloat16)
+                      for k in ks[:2])
+        dout = jax.random.normal(ks[2], (b, s, conv_dim), jnp.bfloat16)
+        taps = jax.random.uniform(ks[3], (4, conv_dim), jnp.float32,
+                                  -0.5, 0.5)
+        out = jax.jit(conv_silu_forward)(qkvz, taps)
+        # the table's cotangent as the rule's backward leaves it: q | k | v
+        # at 16, 16 and 32 heads of conv_dim / 64 columns
+        cuts = (0, conv_dim // 4, conv_dim // 2, conv_dim)
+        dqkvz, dtaps = jax.jit(conv_silu_backward)(
+            qkvz, taps, tuple(dout[..., lo:hi] for lo, hi
+                              in zip(cuts, cuts[1:])), into)
+
+        out_ref, vjp = jax.vjp(
+            causal_conv_silu, qkvz[..., :conv_dim].astype(jnp.float32), taps)
+        dx_ref, dtaps_ref = jax.jit(vjp)(dout.astype(jnp.float32))
+
+        def norm_err(got, want):
+            got, want = (np.asarray(x, np.float32) for x in (got, want))
+            return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+        errs = {"out": norm_err(out, out_ref),
+                "dqkv": norm_err(dqkvz[..., :conv_dim], dx_ref),
+                "dtaps": norm_err(dtaps, dtaps_ref)}
+        kept = bool(jnp.array_equal(dqkvz[..., conv_dim:],
+                                    into[..., conv_dim:]))
+        check(kept and all(np.isfinite(e) for e in errs.values())
+              and errs["out"] <= CONV_TABLE_TOL
+              and errs["dqkv"] <= CONV_TABLE_TOL
+              and errs["dtaps"] <= CONV_TAPS_TOL,
+              f"gdn_conv_fwd / gdn_conv_bwd (B={b} S={s}, {conv_dim} of "
+              f"{width} columns, bf16) against the XLA form in float32: "
+              + " ".join(f"{n}={e:.2e}" for n, e in errs.items())
+              + (", z's columns kept bitwise" if kept
+                 else ", z's columns CHANGED"))
+
     codec_case(1, 25 * 2 ** 20 // 4)      # one 25 MB gradient bucket
     codec_case(4, 25 * 2 ** 20 // 16)     # its four multihop chunks
     codec_case(4, 100_003)                # a length no block divides
+    # train_qwen3_next_s8192_1chip's: q | k | v of 16 + 16 + 32 heads of 128
+    conv_case(1, 8192, 8192, 4096)
 
 
 def phase_train(n_devices: int) -> Path:

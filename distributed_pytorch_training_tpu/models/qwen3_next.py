@@ -19,9 +19,9 @@ The equations (``h`` the residual stream, no projection has a bias):
   repeated to 16 before ``attention_fn`` (the flash kernel folds one head
   count).
 * `GatedDeltaNet`: one projection to q, k (16 heads of 128), v, z (32 heads
-  of 128), one to the per-head write strength and decay inputs; a depthwise
-  causal convolution of 4 and SiLU over [q, k, v]; then, all inside
-  `ops.gated_delta_rule.gated_delta_mixer`: q and k l2-normalised, each key
+  of 128), one to the per-head write strength and decay inputs; then, all
+  inside `ops.gated_delta_rule.gated_delta_mixer`: a depthwise causal
+  convolution of 4 and SiLU over [q, k, v], q and k l2-normalised, each key
   head serving two value heads, the rule under scope ``gdn_rule``, a gated
   RMSNorm per head (``w`` starting at one, times ``silu(z)``); ``out_proj``.
 * expert block: `models.moe.HeldExpertsMoe` plus `SharedExpert` behind a
@@ -149,7 +149,7 @@ class GatedDeltaNet(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        b, s, hidden = x.shape
+        hidden = x.shape[-1]
         hk, hv = self.num_k_heads, self.num_v_heads
         dk, dv = self.head_k_dim, self.head_v_dim
         key_dim, value_dim = hk * dk, hv * dv
@@ -158,13 +158,12 @@ class GatedDeltaNet(nn.Module):
         # columns: [q | k | v | z] and [b | a], the program's own order
         qkvz = dense(2 * key_dim + 2 * value_dim, "in_proj_qkvz")(x)
         ba = dense(2 * hv, "in_proj_ba")(x).astype(jnp.float32)
-        conv_dim = 2 * key_dim + value_dim
-        qkv, z = qkvz[..., :conv_dim], qkvz[..., conv_dim:]
-        # torch's Conv1d default for a depthwise kernel of 4: U(+-1/2)
+        # torch's Conv1d default for a depthwise kernel of 4: U(+-1/2);
+        # tap j weighs the input 3 - j back
         conv_w = self.param(
             "conv1d", lambda key, shape, dt: jax.random.uniform(
                 key, shape, dt, -0.5, 0.5),
-            (self.conv_kernel, conv_dim), self.param_dtype)
+            (self.conv_kernel, 2 * key_dim + value_dim), self.param_dtype)
         a_log = self.param(
             "A_log", lambda key, shape, dt: jnp.log(jax.random.uniform(
                 key, shape, dt, 1e-3, 16.0)), (hv,), jnp.float32)
@@ -173,17 +172,14 @@ class GatedDeltaNet(nn.Module):
         norm_w = self.param("norm", nn.initializers.ones, (dv,),
                             self.param_dtype)
 
-        # depthwise causal convolution: tap j weighs the input 3 - j back
-        padded = jnp.pad(qkv, ((0, 0), (self.conv_kernel - 1, 0), (0, 0)))
-        qkv = nn.silu(sum(
-            padded[:, j:j + s] * conv_w[j].astype(self.dtype)
-            for j in range(self.conv_kernel)))
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
-        # l2 norm of q and k, each key head for its hv / hk value heads, the
-        # rule (scope ``gdn_rule``), the gated norm: one call, which on a
-        # TPU's own program reads and writes these tables and no others
-        o = gated_delta_mixer(qkv, z, g, beta, norm_w, self.epsilon,
+        # the depthwise causal convolution of q | k | v with its SiLU, the l2
+        # norm of q and k, each key head for its hv / hk value heads, the
+        # rule (scope ``gdn_rule``), the norm gated by z: one call, which on
+        # a TPU's own program reads qkvz where it stands and writes between
+        # it and ``out_proj`` one table, the convolution's
+        o = gated_delta_mixer(qkvz, conv_w, g, beta, norm_w, self.epsilon,
                               key_heads=hk, head_block=RULE_HEAD_BLOCK)
         return dense(hidden, "out_proj")(o)
 

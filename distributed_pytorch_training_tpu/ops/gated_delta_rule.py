@@ -6,12 +6,15 @@ intermediates in VMEM, the rule's own backward); everywhere else XLA
 operations, forward and (by XLA's own transpose) backward, which is also the
 tests' second oracle beside the recurrence. `gated_delta_rule` chooses by
 what it can observe of the backend, the shapes and the trace: no option.
-`gated_delta_mixer` is the entry a Gated DeltaNet layer calls: the rule
-between the tables the layer holds anyway (the convolution's output, the
-gate's columns, the norm's weight), so that on the kernel path q's and k's
-l2 norm, the key heads' repeat and the gated output norm happen in VMEM and
-their float32 tables never exist; its other form is those lines as XLA
-operations around `gated_delta_rule`.
+`gated_delta_mixer` is the entry a Gated DeltaNet layer calls: the mixer
+between its two projections (the causal convolution with its SiLU, the
+rule, the gated output norm), handed the in-projection's output whole and
+the parameters the layer holds anyway. On the kernel path the convolution
+is one pass that finds q | k | v by column (`gdn_conv_kernels`), q's and
+k's l2 norm, the key heads' repeat and the gated output norm happen in the
+rule's kernels' VMEM, z is read where it stands, and neither a slice of the
+projection nor a float32 table ever exists; its other form is those lines
+as XLA operations around `gated_delta_rule`.
 
 Per head, with a state ``S`` of shape (key, value) starting at zero::
 
@@ -45,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .gdn_conv_kernels import gdn_conv_supports
 from .gdn_rule_kernels import (
     L2_EPSILON, gated_delta_mixer_kernels, gated_delta_rule_kernels,
     gdn_rule_backend_supported, gdn_rule_one_device_trace, gdn_rule_supports,
@@ -117,41 +121,60 @@ def gated_delta_rule(q, k, v, g, beta, *, head_block: int):
     return jnp.moveaxis(out, 0, 2).reshape(*q.shape[:3], v.shape[-1])
 
 
-def gated_delta_mixer(qkv, z, g, beta, norm_w, epsilon, *, key_heads: int,
-                      head_block: int):
-    """The rule as a Gated DeltaNet mixer holds it, between the tables the
-    mixer has anyway. qkv: (B, S, 2 * key_heads * Dk + H * Dv), the
-    convolution's output, its columns q | k | v, with q and k at
-    ``key_heads`` heads; z: (B, S, H * Dv), the output gate's columns of the
-    in-projection; g, beta: (B, S, H) float32; norm_w: (Dv,), the gated
-    norm's weight, and ``epsilon`` its constant. Returns (B, S, H * Dv) in
-    qkv's dtype, what ``out_proj`` reads::
+def causal_conv_silu(x, taps):
+    """The depthwise causal convolution of a Gated DeltaNet mixer with its
+    SiLU, as XLA operations. x: (B, S, C); taps: (K, C), tap j weighing the
+    input K - 1 - j back, zeros before position 0. Float32 inside and
+    rounded once, to x's dtype: `gdn_conv_kernels`' contract, and the oracle
+    of its tests."""
+    k, s = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    pre = sum(padded[:, j:j + s] * taps[j].astype(jnp.float32)
+              for j in range(k))
+    return (pre * jax.nn.sigmoid(pre)).astype(x.dtype)
 
+
+def gated_delta_mixer(qkvz, taps, g, beta, norm_w, epsilon, *,
+                      key_heads: int, head_block: int):
+    """A Gated DeltaNet mixer between its two projections. qkvz: (B, S, 2 *
+    key_heads * Dk + 2 * H * Dv), the in-projection's output, its columns q
+    | k | v | z with q and k at ``key_heads`` heads; taps: (K, the q | k | v
+    columns), the convolution's; g, beta: (B, S, H) float32; norm_w: (Dv,),
+    the gated norm's weight, and ``epsilon`` its constant. Returns (B, S, H
+    * Dv) in qkvz's dtype, what ``out_proj`` reads::
+
+        q | k | v = silu(causal convolution of those columns)    # rounded
         q, k = l2(q) * Dk ** -0.5, l2(k)     # l2(x) = x rsqrt(sum(x x) + 1e-6)
         o = rule(q, k of value head j = those of key head j // (H / key_heads),
                  v, g, beta)
-        out = o rsqrt(mean(o o) + epsilon) * norm_w * silu(z)
+        out = o rsqrt(mean(o o) + epsilon) * norm_w * silu(z)    # rounded
 
-    all of it in float32 and rounded once, at the end. Two forms, chosen as
-    `gated_delta_rule` chooses and by the same three gates, the shapes' gate
-    asked about the key heads too. The kernels read qkv and z as they stand
-    and do the first and the last line in VMEM, chunk by chunk, so that no
-    float32 table of q, k or o, and no repeated one, is ever in HBM; their
-    backward gives qkv's cotangent at the key heads' width in qkv's dtype.
-    Everywhere else the three lines above are XLA operations around
-    `gated_delta_rule`'s XLA form: the tests' oracle, and what a
-    multi-device GSPMD program runs. The rule itself lies under scope
-    ``gdn_rule`` in both."""
+    each of the two parts in float32 and rounded once, at its end. Two
+    forms, chosen as `gated_delta_rule` chooses and by the same three gates,
+    the shapes' gate asked about the key heads and the convolution's blocks
+    too, so that a program has both kernel pairs or neither. The kernels
+    read qkvz as it stands: the convolution's pair finds q | k | v by
+    column and writes the one table the rule's pair reads, which does the
+    second and the last line in VMEM, chunk by chunk, and finds z by
+    column; no slice of qkvz and no float32 table of q, k or o, repeated or
+    not, is ever in HBM, and their backward writes qkvz's cotangent once
+    (`gdn_rule_kernels._mixer_bwd`). Everywhere else the four lines above
+    are XLA operations around `gated_delta_rule`'s XLA form: the tests'
+    oracle, and what a multi-device GSPMD program runs. The rule itself
+    lies under scope ``gdn_rule`` in both."""
     b, s, h = g.shape
     dv = norm_w.shape[-1]
-    key_dim = (qkv.shape[-1] - h * dv) // 2       # q's columns, and k's
+    conv_dim = taps.shape[-1]
+    key_dim = (conv_dim - h * dv) // 2            # q's columns, and k's
     dk = key_dim // key_heads
     if gdn_rule_backend_supported() \
             and gdn_rule_supports(h, dk, dv, key_heads) \
+            and gdn_conv_supports((key_dim, key_dim, h * dv),
+                                  taps.shape[0]) \
             and gdn_rule_one_device_trace():
-        with jax.named_scope("gdn_rule"):
-            return gated_delta_mixer_kernels(qkv, z, g, beta, norm_w,
-                                             epsilon, key_heads=key_heads)
+        return gated_delta_mixer_kernels(qkvz, taps, g, beta, norm_w,
+                                         epsilon, key_heads=key_heads)
+    qkv = causal_conv_silu(qkvz[..., :conv_dim], taps)
     q, k = (qkv[..., first:first + key_dim].reshape(
         b, s, key_heads, dk).astype(jnp.float32) for first in (0, key_dim))
     v = qkv[..., 2 * key_dim:].reshape(b, s, h, dv)
@@ -164,8 +187,8 @@ def gated_delta_mixer(qkv, z, g, beta, norm_w, epsilon, *, key_heads: int,
         o = gated_delta_rule(q, k, v, g, beta, head_block=head_block)
     o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + epsilon)
     o = o * norm_w.astype(jnp.float32) * jax.nn.silu(
-        z.reshape(b, s, h, dv).astype(jnp.float32))
-    return o.astype(qkv.dtype).reshape(b, s, h * dv)
+        qkvz[..., conv_dim:].reshape(b, s, h, dv).astype(jnp.float32))
+    return o.astype(qkvz.dtype).reshape(b, s, h * dv)
 
 
 def _chunked_rule(q, k, v, g, beta):

@@ -36,6 +36,18 @@ MB a layer; PERF.md, PR 39, has both measured. Only g and beta, a megabyte
 each, are regrouped outside, to ``(B, H / heads, S, heads)``, so that a
 step's block of them is whole in its last dimension.
 
+The mixer's entry (`gated_delta_mixer_kernels`, one `custom_vjp`) starts a
+step earlier, at the in-projection's output q | k | v | z as it stands: the
+convolution + SiLU of `gdn_conv_kernels` writes the one table these kernels
+read q, k and v from, and z is found in the projection's output by column
+(its first block lies ``conv_dim`` columns in), so no slice of the
+projection exists. Its backward writes the projection's cotangent once, by
+two kernels into one array: `gdn_rule_bwd` puts dz where z's columns are in
+an array of the projection's width, `gdn_conv_bwd` takes that array as its
+output (aliased), reads dq, dk and dv as they were left (three tables: it
+finds a column block in the one that holds it) and fills q | k | v's columns
+beside dz.
+
 A caller with the rule's own operands (float32 or not, a head each:
 `gated_delta_rule_kernels`, which the benchmark's rule check reaches) runs
 the SAME two bodies: what the operands are is a static description
@@ -114,6 +126,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .gdn_conv_kernels import (
+    conv_silu_backward, conv_silu_forward, gdn_conv_supports,
+)
+
 CHUNK = 64              # the kernels' own: ten 64-wide products invert I + A
 HEADS_PER_STEP = 8      # at most: four packs in turn a step; 16 measured the same
 PACK = 128 // CHUNK     # heads whose (CHUNK, CHUNK) tables share 128 lanes
@@ -142,7 +158,9 @@ def gdn_rule_supports(heads: int, dk: int, dv: int,
     the mixer's own tables besides: a key head serves a PACK's heads or one
     (two value heads a key head as published, or one: a ratio the PACKs
     cannot hold has no shared rows to hand a pack), and v's columns start at
-    a whole block of a step's value heads in the convolution's output."""
+    a whole block of a step's value heads in the convolution's output (z's,
+    a whole count of such blocks further in the in-projection's, then do
+    too)."""
     if heads % PACK or not (_interpret()
                             or (dk % 128 == 0 and dv % 128 == 0)):
         return False
@@ -384,6 +402,12 @@ class _Form(NamedTuple):
     @property
     def ratio(self) -> int:
         return self.heads // self.key_heads
+
+    @property
+    def conv_dim(self) -> int:
+        """The mixer's q | k | v columns: those before z in the
+        in-projection's output."""
+        return 2 * self.key_heads * self.dk + self.heads * self.dv
 
 
 class _Unit:
@@ -656,9 +680,9 @@ def _ungrouped(x):     # back
 class _Specs:
     """The blocks of one call: ``q``, ``k``, ``v`` of the operands (in the
     mixer's form three ranges of columns of ONE array, the convolution's
-    output, each found by the index of its first block), ``key`` and
-    ``wide`` of tables that hold nothing else, a step's key heads or value
-    heads wide."""
+    output, each found by the index of its first block, and ``z`` the
+    columns behind them in the in-projection's), ``key`` and ``wide`` of
+    tables that hold nothing else, a step's key heads or value heads wide."""
 
     def __init__(self, form: _Form, n: int, *, reverse: bool):
         chunk_of = (lambda c: n - 1 - c) if reverse else (lambda c: c)
@@ -671,9 +695,15 @@ class _Specs:
 
         self.key, self.wide = columns(kb * dk), columns(hb * dv)
         self.q, self.k, self.v = self.key, self.key, self.wide
+        self.weight = pl.BlockSpec((1, dv), lambda i, j, c: (0, 0))
+        self.gate = []
         if form.mixer:
             self.k = columns(kb * dk, form.key_heads // kb)
             self.v = columns(hb * dv, 2 * form.key_heads * dk // (hb * dv))
+            # z, and its cotangent: the in-projection's columns behind
+            # q | k | v
+            self.z = columns(hb * dv, form.conv_dim // (hb * dv))
+            self.gate = [self.z, self.weight]
         self.narrow = pl.BlockSpec(
             (1, 1, CHUNK, hb), lambda i, j, c: (i, j, chunk_of(c), 0))
         self.start = pl.BlockSpec(
@@ -681,11 +711,9 @@ class _Specs:
         self.inverse = pl.BlockSpec(
             (1, 1, hb // PACK, CHUNK, PACK * CHUNK),
             lambda i, j, c: (i, chunk_of(c), j, 0, 0))
-        self.weight = pl.BlockSpec((1, dv), lambda i, j, c: (0, 0))
         # a step's sum over its chunks: the block stays while they pass
         self.dweight = pl.BlockSpec((1, 1, 1, dv),
                                     lambda i, j, c: (i, j, 0, 0))
-        self.gate = [self.wide, self.weight] if form.mixer else []
 
 
 # four packs' tables live at once, beside double-buffered blocks of eight
@@ -737,14 +765,16 @@ def _forward(q, k, v, g, beta, gate, *, form: _Form, residuals: bool):
 @functools.partial(jax.jit, inline=True, static_argnames="form")
 def _backward(q, k, v, g, beta, gate, starts, inverses, do, *, form: _Form):
     """The cotangents of q, k (a table of ``key_heads`` each), v, g, beta
-    and, in the mixer's form, of z and of the norm's weight, the last as a
-    row for every group of heads, to be summed."""
+    and, in the mixer's form, of z and of the norm's weight: z's in the
+    columns z has in the in-projection's output, an array of that width
+    whose other columns are NOT written (`conv_silu_backward` fills them);
+    the weight's as a row for every group of heads, to be summed."""
     b, s, h = g.shape
     dk, dv, hb, n = form.dk, form.dv, form.step, s // CHUNK
     sp = _Specs(form, n, reverse=True)
     like = lambda x, *shape: jax.ShapeDtypeStruct(shape, x.dtype)  # noqa: E731
     narrow_shape = (b, h // hb, s, hb)
-    gated = [(sp.wide, like(gate[0], b, s, h * dv)),
+    gated = [(sp.z, like(gate[0], *gate[0].shape)),
              (sp.dweight, jax.ShapeDtypeStruct((b, h // hb, 1, dv),
                                                jnp.float32))
              ] if form.mixer else []
@@ -802,28 +832,45 @@ def _rule_bwd(residuals, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def _gate(z, norm_w):
-    return z, norm_w.astype(jnp.float32).reshape(1, -1)
+def _gate(qkvz, norm_w):
+    """The rule's kernels' last two operands: z where it stands in the
+    in-projection's output, the norm's weight as a float32 row."""
+    return qkvz, norm_w.astype(jnp.float32).reshape(1, -1)
+
+
+def _mixed(qkvz, taps, g, beta, norm_w, form, *, residuals: bool):
+    """The convolution's kernel, then the rule's on its output and on z
+    where it stands in ``qkvz``: (the convolution's output, the rule's
+    outputs). The rule's part alone lies under scope ``gdn_rule``."""
+    qkv = conv_silu_forward(qkvz, taps)
+    with jax.named_scope("gdn_rule"):
+        return qkv, _forward(qkv, qkv, qkv, g, beta, _gate(qkvz, norm_w),
+                             form=form, residuals=residuals)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _mixer(qkv, z, g, beta, norm_w, form):
-    return _forward(qkv, qkv, qkv, g, beta, _gate(z, norm_w), form=form,
-                    residuals=False)[0]
+def _mixer(qkvz, taps, g, beta, norm_w, form):
+    return _mixed(qkvz, taps, g, beta, norm_w, form, residuals=False)[1][0]
 
 
-def _mixer_fwd(qkv, z, g, beta, norm_w, form):
-    out, starts, inverses = _forward(
-        qkv, qkv, qkv, g, beta, _gate(z, norm_w), form=form, residuals=True)
-    return out, (qkv, z, g, beta, norm_w, starts, inverses)
+def _mixer_fwd(qkvz, taps, g, beta, norm_w, form):
+    qkv, (out, starts, inverses) = _mixed(qkvz, taps, g, beta, norm_w, form,
+                                          residuals=True)
+    return out, (qkvz, taps, qkv, g, beta, norm_w, starts, inverses)
 
 
 def _mixer_bwd(form, residuals, dout):
-    qkv, z, g, beta, norm_w, starts, inverses = residuals
-    dq, dk, dv, dg, dbeta, dz, dweight = _backward(
-        qkv, qkv, qkv, g, beta, _gate(z, norm_w), starts, inverses, dout,
-        form=form)
-    return (jnp.concatenate([dq, dk, dv], axis=-1), dz, dg, dbeta,
+    """The in-projection's cotangent is written once, by two kernels into
+    one array: the rule's backward leaves dz in z's columns, the
+    convolution's reads dq, dk and dv where that left them and fills q | k
+    | v's columns beside dz, in place."""
+    qkvz, taps, qkv, g, beta, norm_w, starts, inverses = residuals
+    with jax.named_scope("gdn_rule"):
+        dq, dk, dv, dg, dbeta, dz, dweight = _backward(
+            qkv, qkv, qkv, g, beta, _gate(qkvz, norm_w), starts, inverses,
+            dout, form=form)
+    dqkvz, dtaps = conv_silu_backward(qkvz, taps, (dq, dk, dv), dz)
+    return (dqkvz, dtaps.astype(taps.dtype), dg, dbeta,
             dweight.sum(axis=(0, 1, 2)).astype(norm_w.dtype))
 
 
@@ -853,20 +900,25 @@ def gated_delta_rule_kernels(q, k, v, g, beta):
     return _rule(*_padded(s, q, k, v, g, beta))[:, :s]
 
 
-def gated_delta_mixer_kernels(qkv, z, g, beta, norm_w, epsilon, *,
+def gated_delta_mixer_kernels(qkvz, taps, g, beta, norm_w, epsilon, *,
                               key_heads: int):
-    """The rule between the mixer's own tables, by the same two kernels:
-    `gated_delta_rule.gated_delta_mixer` has the equations. qkv: (B, S, 2 *
-    key_heads * Dk + H * Dv), the convolution's output, its columns q | k |
-    v; z: (B, S, H * Dv); g, beta: (B, S, H) float32; norm_w: (Dv,).
-    Returns (B, S, H * Dv) in qkv's dtype. `gdn_rule_supports` with
-    ``key_heads`` says which shapes may be sent here."""
+    """The mixer between its two projections, by two kernel pairs: the
+    convolution's (`gdn_conv_kernels`) and the rule's between the tables
+    that leaves. `gated_delta_rule.gated_delta_mixer` has the equations.
+    qkvz: (B, S, 2 * key_heads * Dk + 2 * H * Dv), the in-projection's
+    output, its columns q | k | v | z; taps: (K, 2 * key_heads * Dk + H *
+    Dv); g, beta: (B, S, H) float32; norm_w: (Dv,). Returns (B, S, H * Dv)
+    in qkvz's dtype. `gdn_rule_supports` with ``key_heads`` and
+    `gdn_conv_supports` say which shapes may be sent here."""
     s, h = g.shape[1:]
     dv = norm_w.shape[-1]
-    dk = (qkv.shape[-1] - h * dv) // (2 * key_heads)
-    if not gdn_rule_supports(h, dk, dv, key_heads):
+    dk = (qkvz.shape[-1] - 2 * h * dv) // (2 * key_heads)
+    if not (gdn_rule_supports(h, dk, dv, key_heads) and gdn_conv_supports(
+            (key_heads * dk, key_heads * dk, h * dv), taps.shape[0])):
         raise ValueError(
             f"gated_delta_mixer_kernels: {h} value heads of {dv} over "
-            f"{key_heads} key heads of {dk} are no shape of the kernels'")
+            f"{key_heads} key heads of {dk} under {taps.shape[0]} taps are "
+            "no shape of the kernels'")
     form = _Form(h, key_heads, dk, dv, float(epsilon))
-    return _mixer(*_padded(s, qkv, z, g, beta), norm_w, form)[:, :s]
+    return _mixer(*_padded(s, qkvz), taps, *_padded(s, g, beta), norm_w,
+                  form)[:, :s]

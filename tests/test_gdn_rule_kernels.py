@@ -19,6 +19,7 @@ Pins, in order:
   the kernel path carries both kernels under ``gdn_rule``.
 """
 
+import functools
 import importlib
 import inspect
 import json
@@ -380,18 +381,23 @@ def test_hybrid_train_step_carries_both_kernels_under_gdn_rule(monkeypatch):
     paths = set(re.findall(r'loc\("(jit\([^"]*)"',
                            lowered.as_text(debug_info=True)))
     regions = HYBRID_TRAIN_STEP[1]
-    for kernel in ("gdn_rule_fwd", "gdn_rule_bwd"):
+    # the convolution's pair (PR 41) lies under the module's ``gdn`` and
+    # outside the rule's scope: `hybrid_gdn_proj_ms` goes on counting it
+    for kernel, region in (("gdn_rule_fwd", "gdn_rule"),
+                           ("gdn_rule_bwd", "gdn_rule"),
+                           ("gdn_conv_fwd", "gdn"), ("gdn_conv_bwd", "gdn")):
         under = [p for p in paths if f"/{kernel}" in p]
         assert under, kernel
-        assert {_regions.region_of(p, regions) for p in under} == {"gdn_rule"}
-    assert any("transpose" in p for p in paths if "/gdn_rule_bwd" in p)
+        assert {_regions.region_of(p, regions) for p in under} == {region}
+        assert any("transpose" in p for p in under) or "fwd" in kernel
     # what the XLA form's head blocks were: a `while` under the rule's scope
     assert not any("gdn_rule/while" in p for p in paths)
 
 
 # ---------------------------------------------------------------------------
-# the mixer's own tables (PR 39): l2 norm, query scale and key-head
-# repeat in the kernels' prologue, the gated norm in their epilogue
+# the mixer between its projections (PR 39, PR 41): the convolution's kernel
+# pair, then l2 norm, query scale and key-head repeat in the rule's kernels'
+# prologue, the gated norm in their epilogue, z found by column
 # ---------------------------------------------------------------------------
 
 EPSILON = 1e-6
@@ -399,27 +405,52 @@ EPSILON = 1e-6
 
 def mixer_inputs(length, decay, *, key_heads, heads, dtype=jnp.float32, b=2,
                  dk=16, dv=8):
-    """(the convolution's output q | k | v, z, g, beta, the norm's weight)
-    as `GatedDeltaNet` hands them over: q and k at ``key_heads`` heads,
-    neither scaled nor repeated."""
+    """(the in-projection's output q | k | v | z, the convolution's taps, g,
+    beta, the norm's weight) as `GatedDeltaNet` hands them over: q and k at
+    ``key_heads`` heads, neither convolved, scaled nor repeated."""
     ks = jax.random.split(jax.random.PRNGKey(length + heads), 5)
-    qkv = jax.nn.silu(jax.random.normal(
-        ks[0], (b, length, 2 * key_heads * dk + heads * dv))).astype(dtype)
-    z = jax.random.normal(ks[1], (b, length, heads * dv)).astype(dtype)
+    conv_dim = 2 * key_heads * dk + heads * dv
+    qkvz = jax.random.normal(
+        ks[0], (b, length, conv_dim + heads * dv)).astype(dtype)
+    taps = jax.random.uniform(ks[1], (4, conv_dim), jnp.float32, -0.5, 0.5)
     g = -decay * jax.random.uniform(ks[2], (b, length, heads))
     beta = jax.nn.sigmoid(jax.random.normal(ks[3], (b, length, heads)))
     norm_w = 1.0 + 0.1 * jax.random.normal(ks[4], (dv,))
-    return qkv, z, g, beta, norm_w
+    return qkvz, taps, g, beta, norm_w
 
 
-def stepwise_mixer(qkv, z, g, beta, norm_w, *, key_heads):
-    """The oracle: the mixer's norms written out, in float32, around the
+def stepwise_conv_silu(x, taps):
+    """The convolution position by position: output row t is the taps
+    times the rows t - (K - 1) .. t, those before row 0 zero."""
+    k = taps.shape[0]
+
+    def step(last, row):          # last: (B, K, C), the K rows up to here
+        last = jnp.concatenate([last[:, 1:], row[:, None]], axis=1)
+        pre = (last * taps).sum(1)
+        return last, pre * jax.nn.sigmoid(pre)
+
+    x = x.astype(jnp.float32)
+    _, out = jax.lax.scan(
+        step, jnp.zeros((x.shape[0], k, x.shape[2])), jnp.moveaxis(x, 1, 0))
+    return jnp.moveaxis(out, 0, 1)
+
+
+def stepwise_mixer(qkvz, taps, g, beta, norm_w, *, key_heads,
+                   table_dtype=None):
+    """The oracle: the convolution position by position (rounded to the
+    tables' dtype, as the contract has it: to qkvz's, or ``table_dtype``
+    for operands cast up; the rounding passes cotangents as they are),
+    then the mixer's norms written out, in float32, around the
     position-by-position rule."""
     b, s, h = g.shape
     dv = norm_w.shape[0]
-    key_dim = (qkv.shape[-1] - h * dv) // 2
+    conv_dim = taps.shape[1]
+    key_dim = (conv_dim - h * dv) // 2
     dk = key_dim // key_heads
-    qkv, z = qkv.astype(jnp.float32), z.astype(jnp.float32)
+    qkv = stepwise_conv_silu(qkvz[..., :conv_dim], taps)
+    qkv = qkv + jax.lax.stop_gradient(qkv.astype(
+        table_dtype or qkvz.dtype).astype(jnp.float32) - qkv)
+    z = qkvz[..., conv_dim:].astype(jnp.float32)
     unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
         (x * x).sum(-1, keepdims=True) + 1e-6)
     q = unit(qkv[..., :key_dim].reshape(b, s, key_heads, dk)) * dk ** -0.5
@@ -442,21 +473,28 @@ def mixer_forms(key_heads):
             lambda *a: stepwise_mixer(*a, key_heads=key_heads))
 
 
+def out_shape(args):
+    (b, s, h), (dv,) = args[2].shape, args[4].shape
+    return b, s, h * dv
+
+
 def mixer_grads(form, args):
     """Gradients of all five operands, of a loss that weighs every output
     differently (a plain sum of squares is blind to the gated norm's
     scale)."""
-    weights = jax.random.normal(jax.random.PRNGKey(7), args[1].shape)
+    weights = jax.random.normal(jax.random.PRNGKey(7), out_shape(args))
     return jax.grad(lambda *a: (form(*a).astype(jnp.float32) * weights).sum(),
                     argnums=(0, 1, 2, 3, 4))(*args)
 
 
-def columns_of(dqkv, key_heads, dk=16):
-    """dq, dk (at the key heads' width) and dv of the convolution's
-    cotangent."""
+def columns_of(dqkvz, key_heads, dk=16, dv=8):
+    """The in-projection's cotangent by its columns: those of q and k (at
+    the key heads' width), of v and of z."""
     key_dim = key_heads * dk
-    return (dqkv[..., :key_dim], dqkv[..., key_dim:2 * key_dim],
-            dqkv[..., 2 * key_dim:])
+    value_dim = (dqkvz.shape[-1] - 2 * key_dim) // 2
+    return (dqkvz[..., :key_dim], dqkvz[..., key_dim:2 * key_dim],
+            dqkvz[..., 2 * key_dim:2 * key_dim + value_dim],
+            dqkvz[..., 2 * key_dim + value_dim:])
 
 
 @pytest.mark.parametrize("ratio", [2, 1], ids=["two_a_key", "one_a_key"])
@@ -469,7 +507,7 @@ def test_mixer_kernels_match_the_xla_form_and_the_stepwise_rule(
     from_kernels, from_xla, from_steps = (
         form(*args) for form in mixer_forms(4 // ratio))
     assert from_kernels.dtype == args[0].dtype
-    assert from_kernels.shape == args[1].shape == from_xla.shape
+    assert from_kernels.shape == out_shape(args) == from_xla.shape
     assert bool(jnp.isfinite(from_kernels).all())
     assert rel(from_kernels, from_xla) < FWD_TOL
     assert rel(from_kernels, from_steps) < FWD_TOL
@@ -479,10 +517,11 @@ def test_mixer_kernels_match_the_xla_form_and_the_stepwise_rule(
     (64, 0.05, 2), (100, 30.0, 2), (200, 0.05, 2), (100, 0.05, 1),
     (200, 30.0, 1)])
 def test_mixer_kernels_gradients_match_both_other_forms(length, decay, ratio):
-    """All five operands: the convolution's output (dq and dk arrive at the
-    key heads' width, summed over the value heads a key head served and
-    sent back through the l2 norm inside the kernel), z, g, beta and the
-    norm's weight."""
+    """All five operands: the in-projection's output (dq and dk arrive at
+    the key heads' width, summed over the value heads a key head served and
+    sent back through the l2 norm inside the rule's kernel, then with dv
+    through the convolution's; dz in z's own columns beside them), the
+    taps, g, beta and the norm's weight."""
     key_heads = 4 // ratio
     args = mixer_inputs(length, decay, key_heads=key_heads, heads=4)
     from_kernels, from_xla, from_steps = (
@@ -516,9 +555,11 @@ def test_mixer_heads_beyond_one_grid_step_and_a_batch(heads, key_heads):
 
 
 def test_mixer_bf16_tables_are_cast_up_in_the_kernel_and_rounded_once():
-    """q, k, v and z enter as the bf16 the mixer holds; everything between
-    is float32; the output is rounded to bf16 once, and so is each
-    operand's cotangent (g's, beta's and the weight's stay float32)."""
+    """q, k, v and z enter as the bf16 the in-projection writes; the
+    convolution's table between the two kernel pairs is bf16, rounded once;
+    everything else between is float32; the output is rounded to bf16
+    once, and so is the projection's cotangent (the taps', g's, beta's and
+    the weight's stay float32)."""
     args = mixer_inputs(200, 0.05, key_heads=2, heads=4, dtype=jnp.bfloat16)
     from_kernels, _, from_steps = mixer_forms(2)
     got, want = from_kernels(*args), from_steps(*args)
@@ -527,8 +568,10 @@ def test_mixer_bf16_tables_are_cast_up_in_the_kernel_and_rounded_once():
     got_grads = mixer_grads(from_kernels, args)
     assert [x.dtype for x in got_grads] == [x.dtype for x in args]
     up = tuple(x.astype(jnp.float32) for x in args)
+    from_steps = functools.partial(stepwise_mixer, key_heads=2,
+                                   table_dtype=jnp.bfloat16)
     # the bf16 output hands back a bf16 cotangent: every gradient carries
-    # that rounding, the two tables' their own besides
+    # that rounding, the table's and the projection's their own besides
     for got, want in zip(got_grads, mixer_grads(from_steps, up)):
         assert rel(got.astype(jnp.float32), want) < 2 ** -7
 
@@ -558,16 +601,20 @@ def test_raw_operands_and_the_mixers_tables_reach_one_kernel_body(
         *a, EPSILON, key_heads=3).sum())(*args)
     assert seen == [("_fwd_kernel", True, 2), ("_bwd_kernel", True, 2)]
     assert list(inspect.signature(gdr.gated_delta_mixer).parameters) == [
-        "qkv", "z", "g", "beta", "norm_w", "epsilon", "key_heads",
+        "qkvz", "taps", "g", "beta", "norm_w", "epsilon", "key_heads",
         "head_block"]
 
 
-def pallas_calls(jaxpr):
+def all_eqns(jaxpr):
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
+        yield eqn
         for inner in jax.core.jaxprs_in_params(eqn.params):
-            yield from pallas_calls(inner)
+            yield from all_eqns(inner)
+
+
+def pallas_calls(jaxpr):
+    return (eqn for eqn in all_eqns(jaxpr)
+            if eqn.primitive.name == "pallas_call")
 
 
 def test_the_mixers_forward_keeps_the_two_residuals_and_not_its_output():
@@ -579,8 +626,9 @@ def test_the_mixers_forward_keeps_the_two_residuals_and_not_its_output():
     form = mixer_forms(2)[0]
 
     def outputs(f):
-        [call] = pallas_calls(jax.make_jaxpr(f)(*args).jaxpr)
-        assert call.params["name"] == "gdn_rule_fwd"
+        before, call = pallas_calls(jax.make_jaxpr(f)(*args).jaxpr)
+        assert [c.params["name"] for c in (before, call)] == [
+            "gdn_conv_fwd", "gdn_rule_fwd"]
         return [(v.aval.shape, v.aval.dtype.name) for v in call.outvars]
 
     assert outputs(form) == [((2, 128, 4 * 8), "bfloat16")]
@@ -640,14 +688,15 @@ def test_the_mixer_lowered_for_a_tpu_on_two_devices(monkeypatch, program):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(kernels, "_interpret", lambda: False)
     b, s, key_heads, h, d = 2, 128, 1, 2, 128
-    shapes = [((b, s, (2 * key_heads + h) * d), jnp.bfloat16),
-              ((b, s, h * d), jnp.bfloat16), ((b, s, h), jnp.float32),
-              ((b, s, h), jnp.float32), ((d,), jnp.float32)]
+    shapes = [((b, s, (2 * key_heads + 2 * h) * d), jnp.bfloat16),
+              ((4, (2 * key_heads + h) * d), jnp.float32),
+              ((b, s, h), jnp.float32), ((b, s, h), jnp.float32),
+              ((d,), jnp.float32)]
     args = [jax.ShapeDtypeStruct(*shape) for shape in shapes]
     mesh = two_device_mesh()
     mixer = lambda *a: gdr.gated_delta_mixer(  # noqa: E731
         *a, EPSILON, key_heads=key_heads, head_block=8)
-    specs = (P(BATCH_AXES),) * 4 + (P(),)
+    specs = (P(BATCH_AXES), P(), P(BATCH_AXES), P(BATCH_AXES), P())
     if program == "shard_map":
         mixer = shard_map(mixer, mesh, in_specs=specs,
                           out_specs=P(BATCH_AXES))
@@ -658,7 +707,8 @@ def test_the_mixer_lowered_for_a_tpu_on_two_devices(monkeypatch, program):
         lambda *a: mixer(*a).astype(jnp.float32).sum(),
         argnums=(0, 1, 2, 3, 4)))
     text = step.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
-    for kernel in ("gdn_rule_fwd", "gdn_rule_bwd"):
+    for kernel in ("gdn_rule_fwd", "gdn_rule_bwd", "gdn_conv_fwd",
+                   "gdn_conv_bwd"):
         assert (kernel in text) == (program == "shard_map")
     assert ("tpu_custom_call" in text) == (program == "shard_map")
     assert ("stablehlo.while" in text) == (program == "gspmd")
@@ -669,9 +719,16 @@ def test_hybrid_train_step_hands_the_kernels_the_mixers_own_tables(
     """Does the mechanism engage: by shapes, in the one-device hybrid train
     step on the kernel path. Every `gdn_rule_fwd` reads q, k and v from ONE
     bf16 array, the convolution's output, in blocks a step's KEY heads wide
-    for q and k; z is bf16; no float32 table a value head wide, repeated or
-    not, feeds it; what it writes for ``out_proj`` is bf16. `gdn_rule_bwd`
-    writes dq and dk at the key heads' width in bf16."""
+    for q and k; z is a block of the in-projection's own bf16 output, found
+    by column; no float32 table a value head wide, repeated or not, feeds
+    it; what it writes for ``out_proj`` is bf16. `gdn_rule_bwd` writes dq
+    and dk at the key heads' width in bf16 and dz into the projection's
+    full-width cotangent. PR 41: that table is what `gdn_conv_fwd` wrote
+    from the projection's output as it stands, six forward and three
+    backward calls a step of three layers with remat; `gdn_conv_bwd` fills
+    the cotangent's other columns in place from dq, dk and dv as they
+    stand; and nothing under ``gdn`` slices, pads or concatenates an array
+    of the projection's width or of the convolution's."""
     from distributed_pytorch_training_tpu.parallel import MeshSpec, build_mesh
     from distributed_pytorch_training_tpu.parallel.sharding import shard_batch
     from distributed_pytorch_training_tpu.training.loop import (
@@ -704,28 +761,38 @@ def test_hybrid_train_step_hands_the_kernels_the_mixers_own_tables(
     batch = shard_batch({"input_ids": np.zeros((2, 64), np.int32),
                          "weight": np.ones(2, np.float32)}, mesh)
     traced = trainer._train_step.trace(state, batch, jax.random.PRNGKey(0))
-    calls = {"gdn_rule_fwd": [], "gdn_rule_bwd": []}
+    calls = {"gdn_rule_fwd": [], "gdn_rule_bwd": [], "gdn_conv_fwd": [],
+             "gdn_conv_bwd": []}
     for call in pallas_calls(traced.jaxpr.jaxpr):
         if call.params["name"] in calls:
             calls[call.params["name"]].append(call)
     layers = sum((i + 1) % model.full_attention_interval != 0
                  for i in range(model.depth))
+    assert layers == 3
     # a layer's forward, its remat, its backward
-    assert len(calls["gdn_rule_fwd"]) == 2 * layers
-    assert len(calls["gdn_rule_bwd"]) == layers
+    for pair in ("gdn_rule", "gdn_conv"):
+        assert len(calls[f"{pair}_fwd"]) == 2 * layers
+        assert len(calls[f"{pair}_bwd"]) == layers
     step_heads = kernels._heads_per_step(hv)
     conv_dim = 2 * hk * dk + hv * dv
+    width = conv_dim + hv * dv
+    made_by = {id(v): eqn for eqn in all_eqns(traced.jaxpr.jaxpr)
+               for v in eqn.outvars}
     value_wide = lambda aval: aval.shape[-1] == hv * dv or \
         aval.shape[-2:] == (hv, dv)  # noqa: E731
     for call in calls["gdn_rule_fwd"] + calls["gdn_rule_bwd"]:
         q, k, v, _, _, z, weight = call.invars[:7]
         assert q is k is v
         assert (q.aval.shape[-1], q.aval.dtype) == (conv_dim, jnp.bfloat16)
-        assert (z.aval.shape[-1], z.aval.dtype) == (hv * dv, jnp.bfloat16)
+        assert made_by[id(q)].params["name"] == "gdn_conv_fwd"
+        # z: the in-projection's output itself, what the convolution read
+        assert (z.aval.shape[-1], z.aval.dtype) == (width, jnp.bfloat16)
+        assert z is made_by[id(q)].invars[0]
         assert weight.aval.shape == (1, dv)
         blocks = call.params["grid_mapping"].block_mappings
         assert [m.block_shape[-1].block_size for m in blocks[:3]] == [
             step_heads // 2 * dk, step_heads // 2 * dk, step_heads * dv]
+        assert blocks[5].block_shape[-1].block_size == step_heads * dv
     for call in calls["gdn_rule_fwd"]:
         assert not any(value_wide(x.aval) and x.aval.dtype == jnp.float32
                        for x in call.invars)
@@ -736,3 +803,22 @@ def test_hybrid_train_step_hands_the_kernels_the_mixers_own_tables(
         assert dq.shape[-1] == dk_.shape[-1] == hk * dk
         assert dv_.shape[-1] == hv * dv
         assert {dq.dtype, dk_.dtype, dv_.dtype} == {jnp.dtype(jnp.bfloat16)}
+        dz = call.outvars[5]
+        assert (dz.aval.shape[-1], dz.aval.dtype) == (width, jnp.bfloat16)
+        # the convolution's backward reads dq, dk, dv where they stand,
+        # takes that array and returns it filled
+        [fills] = [c for c in calls["gdn_conv_bwd"] if c.invars[6] is dz]
+        assert all(a is b for a, b in zip(fills.invars[3:6],
+                                          call.outvars[:3]))
+        assert fills.params["input_output_aliases"] == ((6, 0),)
+        assert fills.outvars[0].aval == dz.aval
+    for call in calls["gdn_conv_fwd"] + calls["gdn_conv_bwd"]:
+        assert call.invars[0].aval.shape[-1] == width
+        assert made_by[id(call.invars[0])].primitive.name == "dot_general"
+    cuts = [eqn for eqn in all_eqns(traced.jaxpr.jaxpr)
+            if eqn.primitive.name in ("slice", "dynamic_slice", "pad",
+                                      "concatenate", "gather")
+            and "gdn" in str(eqn.source_info.name_stack)]
+    # nor of the convolution's: dq, dk and dv are not joined either
+    assert not [eqn for eqn in cuts for v in eqn.invars + eqn.outvars
+                if v.aval.shape[-1:] in ((width,), (conv_dim,))]
