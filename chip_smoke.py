@@ -11,9 +11,12 @@ width and weights made from a seed:
   1. kernels  the Pallas kernels, compiled by Mosaic, against their
               references on the chip: flash attention forward + gradient at
               the shapes phase 2 trains at and at BERT's, the two int8
-              codec kernels against the XLA-composed codec, and the Gated
+              codec kernels against the XLA-composed codec, the Gated
               DeltaNet mixer's convolution pair against its XLA form at the
-              hybrid cell's shape;
+              hybrid cell's shape, and the window read of the paged pool
+              (W 4, 32 query heads over 4 key/value heads of 128, pages of
+              64, a row with nothing committed) against the expanded
+              float32 form at the block-diffusion cell's shape;
   2. train    ``train.main([...])``: eight optimizer steps + validation +
               a manifest-verified checkpoint;
   3. serve    the token-granular server (SlotEngine + PagePool) from that
@@ -304,11 +307,30 @@ def phase_kernels() -> None:
               + (", z's columns kept bitwise" if kept
                  else ", z's columns CHANGED"))
 
+    def window_case(cell):
+        """The cell's own layer check (benchmark/checks/sdar.py), under the
+        cell's own limit: a standing check of the kernel on a device
+        besides the cell."""
+        from benchmark.checks.sdar import layer_checks
+        from benchmark.run import load_cell
+
+        _, _, config, mix = load_cell(cell, rehearsal=False)
+        got = layer_checks(config, mix, seed=42)
+        limit = config["correct"]["kernel_rel_diff_tol"]
+        check(got["kernel_read"] == "kernel"
+              and got["kernel_rel_diff"] <= limit,
+              f"paged_attention window read ({mix['rows']} rows x W "
+              f"{config['job']['block_length']}, read {got['kernel_read']}) "
+              f"against the expanded float32 form: "
+              f"{got['kernel_rel_diff']:.2e} of ||want|| (limit {limit})")
+
     codec_case(1, 25 * 2 ** 20 // 4)      # one 25 MB gradient bucket
     codec_case(4, 25 * 2 ** 20 // 16)     # its four multihop chunks
     codec_case(4, 100_003)                # a length no block divides
     # train_qwen3_next_s8192_1chip's: q | k | v of 16 + 16 + 32 heads of 128
     conv_case(1, 8192, 8192, 4096)
+    if jax.device_count() == 1:    # the kernel read is a one-device program
+        window_case("serve_sdar_block_diffusion_batch")
 
 
 def phase_train(n_devices: int) -> Path:

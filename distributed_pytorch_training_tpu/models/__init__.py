@@ -18,3 +18,4 @@ from . import gpt2  # noqa: F401  (registers gpt2_355m/gpt2_124m)
 from . import moe  # noqa: F401  (registers gpt2_moe)
 from . import qwen3_next  # noqa: F401  (registers qwen3_next_80b_a3b)
 from . import deepseek_v2  # noqa: F401  (registers deepseek_v2_236b_a21b)
+from . import sdar  # noqa: F401  (registers sdar_30b_a3b_chat)

@@ -207,7 +207,10 @@ class HeldExpertsMoe(nn.Module):
     the other quarters, one at a time, only where held assignments reach
     them (a `lax.cond` on the count): no assignment to a held expert is ever
     dropped, however many land on one, and the worst routing costs time,
-    not memory. ``moe_dropped_assignments`` (held assignments minus those a
+    not memory. With ``num_experts_held == num_experts`` (a whole layer on
+    one chip, models/sdar.py) every assignment is held, and the products run
+    over the whole sorted order in one pass, with no `lax.cond`.
+    ``moe_dropped_assignments`` (held assignments minus those a
     group covered) is zero by construction; it is counted anyway and checked
     by the tests and the benchmark.
 
@@ -292,7 +295,11 @@ class HeldExpertsMoe(nn.Module):
             weight_of = kept.reshape(t * k)[order]
 
         all_rows = t * k
-        rows = min(all_rows, max(8, -(-all_rows // 4)))
+        # a share walks the sorted order a quarter at a time; where every
+        # expert is held (a static fact) every assignment is, and the
+        # grouped products run over the whole order at once
+        rows = all_rows if held == self.num_experts \
+            else min(all_rows, max(8, -(-all_rows // 4)))
 
         def experts_on(start):
             """The part of the result that sorted assignments ``start ..

@@ -285,13 +285,38 @@ def _replicated(row):
     return jnp.broadcast_to(row[:, None], (row.shape[0], _LANES))
 
 
-def _causal_masked(s, q0, k0, q_axis: int):
+def _causal_masked(s, q0, k0, q_axis: int, causal_block: int = 1):
     """The scores `s` of a block whose first query is at position q0 and
     first key at k0 (queries along `q_axis`), NEG_INF where the key lies
-    after its query."""
+    after its query; with ``causal_block`` B > 1 (a power of two), after
+    the last position of its query's block of B (``k <= q | (B - 1)``).
+    Tiles and the walk's blocks are multiples of B, so which tiles are
+    dead, crossed or walked is the causal rule's (`_check_causal_block`)."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    if causal_block > 1:
+        q_pos = q_pos | (causal_block - 1)
     return jnp.where(q_pos >= k_pos, s, NEG_INF)
+
+
+def _check_causal_block(causal_block: int, causal: bool, block_q: int,
+                        block_k: int) -> None:
+    """Block-causal attention rides the causal kernel's tiling: a dead tile
+    is dead and a crossed tile crossed under either mask once every tile and
+    every block of the diagonal walk starts on a block of B (a tile the
+    causal rule calls full is full under the wider mask too; one it calls
+    crossed runs masked, which is right wherever the diagonal lies)."""
+    if causal_block == 1:
+        return
+    if not causal or causal_block < 1 or causal_block & (causal_block - 1):
+        raise ValueError(
+            f"causal_block={causal_block} needs causal=True and a power of "
+            "two")
+    if block_q % causal_block or block_k % causal_block \
+            or _FORWARD_WALK_ROWS % causal_block:
+        raise ValueError(
+            f"blocks {block_q} x {block_k} are not whole blocks of "
+            f"{causal_block} positions")
 
 
 def _run_live_tiles(step, qb, kb, block_q: int, block_k: int, causal: bool,
@@ -341,7 +366,8 @@ def _run_live_tiles(step, qb, kb, block_q: int, block_k: int, causal: bool,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs,
                 block_q: int, block_k: int, causal: bool,
-                score_scale: Optional[float], masked: bool):
+                score_scale: Optional[float], masked: bool,
+                causal_block: int = 1):
     if masked:
         kvm_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -363,7 +389,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs,
         if score_scale is not None:
             s = s * score_scale
         if q0 is not None:
-            s = _causal_masked(s, q0, k0, q_axis=0)
+            s = _causal_masked(s, q0, k0, q_axis=0,
+                               causal_block=causal_block)
         if masked:
             # key-padding: masked keys contribute nothing to any query row.
             # Safe online-softmax interaction: an all-masked block leaves m
@@ -398,14 +425,17 @@ def _folded(x):
 
 def _flash_fwd_lse(q, k, v, causal: bool, sm_scale: float,
                    block_q: Optional[int], block_k: Optional[int],
-                   kv_valid=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                   kv_valid=None, causal_block: int = 1
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (out (B, Sq, H, d), lse (BH, 1, Sq)). `kv_valid`: optional
     (B, Sk) float validity mask (1=real key, 0=pad). Blocks left ``None``
     are chosen from the shapes (`_blocks`)."""
     block_q, block_k = _blocks(block_q, block_k, q, k)
+    _check_causal_block(causal_block, causal, block_q, block_k)
     return _fwd_call(q, k, v, kv_valid, causal=causal,
                      sm_scale=float(sm_scale), block_q=block_q,
-                     block_k=block_k, interpret=_interpret())
+                     block_k=block_k, interpret=_interpret(),
+                     causal_block=causal_block)
 
 
 # `jit(inline=True)` here and on `_bwd_call`: a train step holds these
@@ -417,9 +447,10 @@ def _flash_fwd_lse(q, k, v, causal: bool, sm_scale: float,
 # the caller's scope path (a jitted call proper is lowered once for all sites
 # and its operations lose the path the region metrics read).
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "causal", "sm_scale", "block_q", "block_k", "interpret"))
+    "causal", "sm_scale", "block_q", "block_k", "interpret", "causal_block"))
 def _fwd_call(q, k, v, kv_valid, *, causal: bool, sm_scale: float,
-              block_q: int, block_k: int, interpret: bool):
+              block_q: int, block_k: int, interpret: bool,
+              causal_block: int = 1):
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[-1]
     masked = kv_valid is not None
@@ -449,7 +480,7 @@ def _fwd_call(q, k, v, kv_valid, *, causal: bool, sm_scale: float,
         functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
                           causal=causal,
                           score_scale=None if folds else sm_scale,
-                          masked=masked),
+                          masked=masked, causal_block=causal_block),
         name="flash_fwd",
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, dv), q.dtype),
@@ -715,7 +746,7 @@ def _bwd_call(q, k, v, out, lse, g, kv_valid, *, causal: bool,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 8))
 def flash_attention(
     q: jnp.ndarray,  # (B, S, H, D)
     k: jnp.ndarray,
@@ -725,6 +756,7 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     kv_valid: Optional[jnp.ndarray] = None,  # (B, Sk), 1=real key, 0=pad
+    causal_block: int = 1,
 ) -> jnp.ndarray:
     """Blockwise attention; numerically equivalent to softmax(QK^T*scale)V.
 
@@ -739,14 +771,26 @@ def flash_attention(
     (forward AND backward recompute), so padded batches keep the flash fast
     path. Rows whose keys are ALL masked emit mean(V) — the standard
     contract that the loss zero-weights padded query rows (then their
-    cotangent is exactly 0 and no gradient leaks through the garbage)."""
+    cotangent is exactly 0 and no gradient leaks through the garbage).
+
+    ``causal_block`` B > 1 (with ``causal``) widens the mask to whole blocks
+    of B positions: key j is visible to query i iff ``j // B <= i // B``
+    (generation by diffusion over blocks, models/sdar.py). B = 1 is the
+    causal program, text unchanged. Forward only: differentiating such a
+    call raises rather than compute the causal mask's gradient."""
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(q.shape[-1])
     out, _ = _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k,
-                            kv_valid)
+                            kv_valid, causal_block)
     return out
 
 
-def _vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_valid=None):
+def _vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_valid=None,
+             causal_block=1):
+    if causal_block != 1:
+        raise NotImplementedError(
+            f"flash_attention: causal_block={causal_block} has the forward "
+            "kernel only; the backward kernels mask by position, not by "
+            "block")
     if v.shape[-1] != q.shape[-1]:
         raise NotImplementedError(
             "flash_attention: values of another width than the keys "
@@ -758,7 +802,7 @@ def _vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_valid=None):
     return out, (q, k, v, out, lse, kv_valid)
 
 
-def _vjp_bwd(causal, sm_scale, block_q, block_k, residuals, g):
+def _vjp_bwd(causal, sm_scale, block_q, block_k, causal_block, residuals, g):
     q, k, v, out, lse, kv_valid = residuals
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(q.shape[-1])
     dq, dk, dv = _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q,
@@ -788,7 +832,8 @@ def _as_kv_valid(mask, batch: int, sk: int) -> Optional[jnp.ndarray]:
 
 
 def make_flash_attention_fn(causal: bool, block_q: Optional[int] = None,
-                            block_k: Optional[int] = None, mesh=None):
+                            block_k: Optional[int] = None, mesh=None,
+                            causal_block: int = 1):
     """Adapter matching models.layers' `attention_fn(q, k, v, mask, dtype)`.
 
     Causal structure is handled inside the kernel via block skipping (faster
@@ -813,7 +858,7 @@ def make_flash_attention_fn(causal: bool, block_q: Optional[int] = None,
 
     def kernel(q, k, v, kv_valid):
         return flash_attention(q, k, v, causal, None, block_q, block_k,
-                               kv_valid)
+                               kv_valid, causal_block)
 
     def attention_fn(q, k, v, mask=None, dtype=jnp.float32):
         kv_valid = _as_kv_valid(mask, q.shape[0], k.shape[1])
@@ -821,8 +866,8 @@ def make_flash_attention_fn(causal: bool, block_q: Optional[int] = None,
             from ..models.layers import dot_product_attention
 
             if causal:
-                cm = jnp.tril(jnp.ones((q.shape[1], k.shape[1]),
-                                       bool))[None, None]
+                at = jnp.arange(q.shape[1])[:, None] | (causal_block - 1)
+                cm = (jnp.arange(k.shape[1])[None, :] <= at)[None, None]
                 mask = mask.astype(bool) & cm
             return dot_product_attention(q, k, v, mask=mask, dtype=dtype)
         if mesh is None or mesh.size == 1 \

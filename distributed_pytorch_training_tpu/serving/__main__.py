@@ -439,7 +439,6 @@ def _serve(args, buckets, overrides, train_config) -> int:
     from ..utils.logging import log_main
     from .batching import RequestQueue
     from .build import build_slot_engine
-    from .continuous import ContinuousScheduler
 
     engine, _ = build_slot_engine(
         jax.devices(), args.model, buckets=buckets, rows=args.rows,
@@ -454,7 +453,7 @@ def _serve(args, buckets, overrides, train_config) -> int:
              f"({engine.paged_bytes()}B paged vs "
              f"{engine.dense_baseline_bytes()}B dense)")
     queue = RequestQueue(buckets)
-    sched = ContinuousScheduler(engine, queue)
+    sched = engine.scheduler_cls(engine, queue)
     stop = threading.Event()
     worker = threading.Thread(target=sched.run, args=(stop,),
                               kwargs={"log": log_main}, daemon=True)
@@ -497,7 +496,8 @@ def _serve(args, buckets, overrides, train_config) -> int:
                     tokens, max_new_tokens=body.get("max_new_tokens"),
                     temperature=float(body.get("temperature", 0.0)),
                     top_p=float(body.get("top_p", 1.0)),
-                    seed=body.get("seed"))
+                    seed=body.get("seed"),
+                    denoising_steps=body.get("denoising_steps"))
                 res = req.result(timeout=600.0)
             except Exception as e:  # noqa: BLE001 - one request, one reply
                 self._reply(503, {"error": f"{type(e).__name__}: {e}"})

@@ -52,6 +52,9 @@ class Result:
     queue_wait_s: float = 0.0
     prefill_s: float = 0.0
     decode_s: float = 0.0
+    # block-diffusion engines: the denoise step of its block, from 0, at
+    # which each token was unmasked (serving/block_diffusion.py)
+    unmask_steps: Optional[np.ndarray] = None
 
 
 class Request:
@@ -65,7 +68,8 @@ class Request:
                  return_prompt_logits: bool = False,
                  max_new_tokens: Optional[int] = None,
                  temperature: float = 0.0, top_p: float = 1.0,
-                 seed: Optional[int] = None):
+                 seed: Optional[int] = None,
+                 denoising_steps: Optional[int] = None):
         tokens = np.asarray(tokens, np.int32)
         if tokens.ndim != 1 or tokens.size == 0:
             raise ValueError(
@@ -87,6 +91,9 @@ class Request:
         self.temperature = float(temperature)
         self.top_p = float(top_p)
         self.seed = int(self.id if seed is None else seed)
+        # steps a block of a block-diffusion model is denoised in (None: one
+        # position a step); engines that emit a token a step ignore it
+        self.denoising_steps = denoising_steps
         self.t_submit = time.perf_counter()
         self.t_done: Optional[float] = None  # set at resolution (bench read)
         self.t_first_token: Optional[float] = None  # TTFT (prefill emits #0)
@@ -133,8 +140,9 @@ class RequestQueue:
 
     def submit(self, tokens: np.ndarray,
                return_prompt_logits: bool = False, **kw) -> Request:
-        """Enqueue one prompt (``**kw``: the per-request sampling knobs —
-        max_new_tokens/temperature/top_p/seed — `Request` validates them).
+        """Enqueue one prompt (``**kw``: the per-request knobs —
+        max_new_tokens/temperature/top_p/seed/denoising_steps — `Request`
+        validates them).
         Raises on a closed (draining) queue — the SIGTERM contract:
         accepted work completes, new work is refused — and on prompts no
         bucket fits (bucket_for's loud rejection beats a truncated

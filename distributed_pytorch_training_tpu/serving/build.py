@@ -127,6 +127,8 @@ def build_slot_engine(devices: Sequence[jax.Device], model_name: str,
     (serving/continuous.py); ``min_positions`` is derived here because the
     gathered dense view is ``pages_per_slot * page_size`` wide — page
     padding can outgrow ``max(buckets) + max_new_tokens``."""
+    from ..models import get_model
+    from .block_diffusion import BlockDiffusionEngine
     from .continuous import SlotEngine
     from .paged import PagedServeConfig
 
@@ -135,9 +137,14 @@ def build_slot_engine(devices: Sequence[jax.Device], model_name: str,
         serve_dtype=serve_dtype, page_size=page_size, kv_dtype=kv_dtype,
         n_pages=n_pages, prefix_sharing=prefix_sharing,
         prefix_skip=prefix_skip)
+    # the engine a model asks for, by what the model says of itself: one
+    # that generates by blocks is served a block a step
+    probe = get_model(model_name, **(kw.get("model_overrides") or {}))
+    by_blocks = getattr(probe, "block_length", 1) > 1
     return build_serving_engine(
         devices, model_name, buckets=buckets, rows=rows,
-        max_new_tokens=max_new_tokens, config=cfg, engine_cls=SlotEngine,
+        max_new_tokens=max_new_tokens, config=cfg,
+        engine_cls=BlockDiffusionEngine if by_blocks else SlotEngine,
         min_positions=cfg.pages_per_slot * cfg.page_size, **kw)
 
 

@@ -796,6 +796,9 @@ class ContinuousScheduler:
     queued work completes; new work is refused), `kill` is the chaos hook
     (fail everything in flight, the router resubmits elsewhere)."""
 
+    # tokens an admission's prefill emits (the ``tokens`` of ``sched_fence``)
+    prefill_emits = 1
+
     def __init__(self, engine: SlotEngine, queue: RequestQueue):
         cfg: PagedServeConfig = engine.config
         self.engine = engine
@@ -825,6 +828,8 @@ class ContinuousScheduler:
         # serial number of the scheduling iteration: what the spans of one
         # `step` share (``iter``), the five phases and their children
         self.iteration = 0                                  # guarded-by: _lock
+        # what `_advance` adds to its iteration's ``sched_fence`` span
+        self._fence_extra: Dict[str, int] = {}              # guarded-by: _lock
 
     # -- admission -----------------------------------------------------------
 
@@ -904,10 +909,7 @@ class ContinuousScheduler:
             telemetry.span_event("prefill", time.perf_counter() - t0,
                                  bucket=bucket, resumed=covered, **who)
         else:
-            bucket = self.engine.admit(slot, req.tokens, want,
-                                       req.temperature, req.top_p,
-                                       req.seed)
-            left = want - 1
+            bucket, left = self._admit_cold(slot, req, want)
             telemetry.span_event("prefill", time.perf_counter() - t0,
                                  bucket=bucket, **who)
         now = time.perf_counter()
@@ -922,6 +924,26 @@ class ContinuousScheduler:
         self._post_admit(slot, req)
         self._gauges()
         return True
+
+    def _admit_cold(self, slot: int, req: Request,
+                    want: int) -> Tuple[int, int]:   # lock-held: _lock
+        """Dispatch the whole prompt's prefill; (the bucket served, the
+        tokens the slot still has to emit once it has run). The plain
+        prefill emits token #0 itself; a block-diffusion engine's emits
+        none (serving/block_diffusion.py)."""
+        bucket = self.engine.admit(slot, req.tokens, want, req.temperature,
+                                   req.top_p, req.seed)
+        return bucket, want - 1
+
+    def _first_token_landed(self, st: _SlotState) -> bool:  # lock-held: _lock
+        """Whether the fence just passed proves the slot's first token: the
+        plain prefill emits it, so any fence after admission does."""
+        return True
+
+    def _result_extras(self, st: _SlotState, more) -> dict:
+        """Fields of a `Result` beyond the tokens and the kept logits, from
+        what else the engine's `fetch_slot` fetched (nothing, here)."""
+        return {}
 
     def _draft_admit(self, req: Request, lease: PageLease,
                      want: int) -> bool:   # lock-held: _lock
@@ -1019,7 +1041,7 @@ class ContinuousScheduler:
             st = self.running.pop(slot)
             who = dict(iter=self.iteration, slot=slot, request=st.req.id)
             t_fetch = time.perf_counter()
-            toks, last = self.engine.fetch_slot(slot)
+            toks, last, *more = self.engine.fetch_slot(slot)
             now = time.perf_counter()
             telemetry.span_event("slot_fetch", now - t_fetch, **who)
             first = st.req.t_first_token or t0
@@ -1027,7 +1049,8 @@ class ContinuousScheduler:
                          last_logits=np.asarray(last),
                          bucket=st.bucket,
                          queue_wait_s=max(0.0, first - st.req.t_submit),
-                         decode_s=max(0.0, now - first))
+                         decode_s=max(0.0, now - first),
+                         **self._result_extras(st, more))
             self.pool.release(st.lease)
             t_put = time.perf_counter()
             self.engine.set_page_row(
@@ -1091,7 +1114,8 @@ class ContinuousScheduler:
                 # landed: the honest (if slightly late) TTFT stamp
                 now = time.perf_counter()
                 for st in self.running.values():
-                    if st.req.t_first_token is None:
+                    if st.req.t_first_token is None \
+                            and self._first_token_landed(st):
                         st.req.t_first_token = now
                 marks.append(time.perf_counter())
                 completed = self._complete_finished()
@@ -1099,7 +1123,8 @@ class ContinuousScheduler:
                 # an admission's prefill emits its token #0, and this
                 # fence is where it lands; a skip admission's first token
                 # is one of the steps' own
-                first = admitted - (self.prefill_skips - skips)
+                first = (admitted - (self.prefill_skips - skips)) \
+                    * self.prefill_emits
                 work = (steps, live, tokens + first, completed)
             if telemetry.is_configured():
                 self._emit_phases(marks, took, admitted, work)
@@ -1132,7 +1157,7 @@ class ContinuousScheduler:
                              live=live)
         telemetry.span_event("sched_fence", marks[4] - marks[3],
                              wall + marks[3], iter=it, steps=steps,
-                             live=live, tokens=tokens)
+                             live=live, tokens=tokens, **self._fence_extra)
         telemetry.span_event("sched_complete", marks[5] - marks[4],
                              wall + marks[4], iter=it, completed=completed)
 
@@ -1198,8 +1223,14 @@ class ContinuousScheduler:
             return failed
 
 
+# the scheduler that drives an engine's step: each engine class names its
+# own (`SpeculativeEngine`, `BlockDiffusionEngine`), and whoever starts a
+# worker loop over an engine asks the engine (`InProcessReplica`, the CLI)
+SlotEngine.scheduler_cls = ContinuousScheduler
+
+
 def serve_continuous(engine: SlotEngine, queue: RequestQueue,
                      stop: threading.Event, log=None) -> int:
     """Drop-in worker-loop twin of ``batching.serve_forever`` for the
     continuous engine (the CLI runs one per replica thread)."""
-    return ContinuousScheduler(engine, queue).run(stop, log=log)
+    return engine.scheduler_cls(engine, queue).run(stop, log=log)

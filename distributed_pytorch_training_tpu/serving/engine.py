@@ -161,10 +161,21 @@ class InferenceEngine:
     engine ever triggered — the census the zero-recompile contract reads.
     """
 
+    # whether this engine's step commits a block of positions: what a model
+    # that generates by blocks (``block_length`` > 1) asks of its engine
+    serves_blocks = False
+
     def __init__(self, model, mesh, config: ServeConfig, params,
                  batch_stats: Any = None, rules=None):
         from ..parallel.mesh import MODEL
 
+        block = int(getattr(model, "block_length", 1))
+        if block > 1 and not self.serves_blocks:
+            raise ValueError(
+                f"{type(self).__name__} emits a token a step and this model "
+                f"generates by blocks of {block}: "
+                "serving.block_diffusion.BlockDiffusionEngine serves it "
+                "(serving.build.build_slot_engine picks it; ROADMAP R18)")
         self.model = model
         self.mesh = mesh
         self.config = config

@@ -63,16 +63,12 @@ class InProcessReplica:
 
     def __init__(self, name: str, engine, start: bool = True,
                  scheduler_cls=None):
-        from .continuous import ContinuousScheduler
-
         if scheduler_cls is None:
             # a SpeculativeEngine under the plain scheduler would decode
-            # token-at-a-time and never touch the draft — auto-pair the
-            # engine with the scheduler that drives its verify loop
-            from .speculative import SpeculativeEngine, SpeculativeScheduler
-            scheduler_cls = (SpeculativeScheduler
-                             if isinstance(engine, SpeculativeEngine)
-                             else ContinuousScheduler)
+            # token-at-a-time and never touch the draft, a block-diffusion
+            # engine would never commit a block: every engine class names
+            # the scheduler that drives its step
+            scheduler_cls = engine.scheduler_cls
         self.name = name
         self.engine = engine
         self.queue = RequestQueue(engine.config.buckets)

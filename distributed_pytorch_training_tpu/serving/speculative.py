@@ -521,6 +521,9 @@ class SpeculativeScheduler(ContinuousScheduler):
         return 1, budgeted, emitted
 
 
+SpeculativeEngine.scheduler_cls = SpeculativeScheduler
+
+
 def serve_speculative(engine: SpeculativeEngine, queue: RequestQueue,
                       stop, log=None) -> int:
     """Worker-loop twin of `serve_continuous` for the speculative
