@@ -16,7 +16,10 @@ width and weights made from a seed:
               hybrid cell's shape, and the window read of the paged pool
               (W 4, 32 query heads over 4 key/value heads of 128, pages of
               64, a row with nothing committed) against the expanded
-              float32 form at the block-diffusion cell's shape;
+              float32 form at the block-diffusion cell's shape, and the
+              grouped product of that cell's expert layer (5,632 sorted
+              rows of 2,048 by 128 experts' 2,048 x 768, group sizes by the
+              cell's routing rule) against float32 products at `highest`;
   2. train    ``train.main([...])``: eight optimizer steps + validation +
               a manifest-verified checkpoint;
   3. serve    the token-granular server (SlotEngine + PagePool) from that
@@ -75,6 +78,14 @@ FP32_REL_TOL = 1e-6
 # taps' gradient is a float32 sum over 8,192 rows in another order.
 CONV_TABLE_TOL = 2 ** -8
 CONV_TAPS_TOL = 1e-4
+# The grouped product takes bf16 rows and weights, sums in float32 and
+# rounds once: against float32 products at `highest` from the same bf16
+# values, as ||kernel - ref|| / ||ref||, it reads 1.66e-3, bf16's rounding
+# of the result, and so does `lax.ragged_dot`; with rows and weights rounded
+# to float8_e4m3 first, the nearest precision below, 0.313, most of the
+# 0.02-scaled weights lying under its least normal number (my chip run, PR
+# 43, call 2). The limit lies between, nearer the first.
+GROUPED_PRODUCT_TOL = 8e-3
 
 
 class SmokeFailure(Exception):
@@ -324,6 +335,48 @@ def phase_kernels() -> None:
               f"against the expanded float32 form: "
               f"{got['kernel_rel_diff']:.2e} of ||want|| (limit {limit})")
 
+    def grouped_product_case(tokens, hidden, width, experts, top_k):
+        """`ops.grouped_product` at the block-diffusion cell's timed shape,
+        the group sizes what the cell's routing rule (the ``top_k`` best of
+        ``experts`` by a router of scale 0.1) gives seeded rows, against
+        float32 products at `highest`, one expert at a time."""
+        from distributed_pytorch_training_tpu.ops.grouped_product import (
+            grouped_product, grouped_product_supports,
+        )
+
+        ks = jax.random.split(jax.random.PRNGKey(43), 3)
+        x = jax.random.normal(ks[0], (tokens, hidden), jnp.float32)
+        router = 0.1 * jax.random.normal(ks[1], (hidden, experts))
+        chosen = jax.lax.top_k(x @ router, top_k)[1].reshape(-1)
+        order = jnp.argsort(chosen, stable=True)
+        sizes = np.bincount(np.asarray(chosen), minlength=experts)
+        rows = x[order // top_k].astype(jnp.bfloat16)
+        weights = (0.02 * jax.random.normal(
+            ks[2], (experts, hidden, width))).astype(jnp.bfloat16)
+        check(grouped_product_supports(*rows.shape, width, rows.dtype),
+              f"grouped_product takes {rows.shape} by {weights.shape}")
+        got = np.asarray(jax.jit(grouped_product)(
+            rows, weights, jnp.asarray(sizes, jnp.int32)), np.float32)
+        # one program for every group: the longest group's rows from the
+        # group's start, of which the group's own are kept
+        longest = int(sizes.max())
+        exact = jax.jit(lambda a, w, start, g: jnp.dot(
+            jax.lax.dynamic_slice_in_dim(a, start, longest).astype(
+                jnp.float32), w[g].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        padded = jnp.pad(rows, ((0, longest), (0, 0)))
+        starts = np.cumsum(sizes) - sizes
+        want = np.concatenate([
+            np.asarray(exact(padded, weights, start, g))[:size]
+            for g, (size, start) in enumerate(zip(sizes, starts))])
+        err = float(np.linalg.norm(got - want) / np.linalg.norm(want))
+        check(np.isfinite(err) and err <= GROUPED_PRODUCT_TOL,
+              f"grouped_product ({rows.shape[0]} rows x {hidden} by "
+              f"{experts} x {hidden} x {width}, bf16, groups of "
+              f"{sizes.min()}..{sizes.max()}) against float32 products at "
+              f"highest: {err:.2e} of ||want|| (limit "
+              f"{GROUPED_PRODUCT_TOL})")
+
     codec_case(1, 25 * 2 ** 20 // 4)      # one 25 MB gradient bucket
     codec_case(4, 25 * 2 ** 20 // 16)     # its four multihop chunks
     codec_case(4, 100_003)                # a length no block divides
@@ -331,6 +384,8 @@ def phase_kernels() -> None:
     conv_case(1, 8192, 8192, 4096)
     if jax.device_count() == 1:    # the kernel read is a one-device program
         window_case("serve_sdar_block_diffusion_batch")
+        # its expert layer's products: 176 rows x W 4, the 8 best of 128
+        grouped_product_case(704, 2048, 768, 128, 8)
 
 
 def phase_train(n_devices: int) -> Path:

@@ -480,6 +480,15 @@ class DeepSeekV2LMHead(VocabPaddingMixin, nn.Module):
         return mla_paged_attention_supports(
             page_size, self.kv_lora_rank, self.qk_rope_head_dim, self.dtype)
 
+    def expert_path(self, tokens: int) -> str:
+        """How a program over ``tokens`` positions multiplies its experts:
+        `HeldExpertsMoe.expert_path` of a layer's own module."""
+        layer = HeldExpertsMoe(
+            self.n_routed_experts, self.num_experts_held,
+            self.num_experts_per_tok, self.moe_intermediate_size,
+            self.first_expert, dtype=self.dtype, parent=None)
+        return layer.expert_path(tokens, self.hidden_dim)
+
 
 @register_model("deepseek_v2_236b_a21b")
 def deepseek_v2_236b_a21b(**kw) -> DeepSeekV2LMHead:

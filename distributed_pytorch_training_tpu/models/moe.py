@@ -36,6 +36,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.grouped_product import (
+    grouped_product,
+    grouped_product_backend_supported,
+    grouped_product_supports,
+)
 from ..parallel.mesh import EXPERT
 from ..parallel.sharding import PartitionRules
 from .layers import VocabPaddingMixin
@@ -209,7 +214,8 @@ class HeldExpertsMoe(nn.Module):
     dropped, however many land on one, and the worst routing costs time,
     not memory. With ``num_experts_held == num_experts`` (a whole layer on
     one chip, models/sdar.py) every assignment is held, and the products run
-    over the whole sorted order in one pass, with no `lax.cond`.
+    over the whole sorted order in one pass, with no `lax.cond`; that pass
+    takes `ops.grouped_product`'s kernel where `expert_path` says so.
     ``moe_dropped_assignments`` (held assignments minus those a
     group covered) is zero by construction; it is counted anyway and checked
     by the tests and the benchmark.
@@ -238,6 +244,25 @@ class HeldExpertsMoe(nn.Module):
     routed_scaling_factor: float = 1.0
     # the router's initial scale (the experts' is 0.02 always)
     router_init_std: float = 0.02
+
+    def expert_path(self, tokens: int, hidden: int) -> str:
+        """How the grouped products over ``tokens`` rows of ``hidden`` run:
+        ``"kernel"``, `ops.grouped_product`, which reads an expert's weights
+        once while its rows pass, where everything it needs is visible at
+        trace time (the rule `SlotEngine.kv_path` follows): every expert
+        held, so that ONE pass covers the sorted order and nothing
+        differentiates it or walks it under a `lax.cond` (a share's does
+        both, and the kernel's transposes are checked nowhere); a TPU with
+        one device; and shapes of whole tiles. Else ``"xla"``, `lax.ragged_dot`. No option
+        selects it; the ``compile`` span of ``paged_decode`` carries it."""
+        rows = tokens * self.top_k
+        kernel = (self.num_experts_held == self.num_experts
+                  and grouped_product_backend_supported()
+                  and grouped_product_supports(rows, hidden, self.expert_dim,
+                                               self.dtype)
+                  and grouped_product_supports(rows, self.expert_dim, hidden,
+                                               self.dtype))
+        return "kernel" if kernel else "xla"
 
     @nn.compact
     def __call__(self, x):
@@ -300,6 +325,8 @@ class HeldExpertsMoe(nn.Module):
         # grouped products run over the whole order at once
         rows = all_rows if held == self.num_experts \
             else min(all_rows, max(8, -(-all_rows // 4)))
+        product = grouped_product if self.expert_path(t, d) == "kernel" \
+            else jax.lax.ragged_dot
 
         def experts_on(start):
             """The part of the result that sorted assignments ``start ..
@@ -317,7 +344,7 @@ class HeldExpertsMoe(nn.Module):
             with jax.named_scope("moe_experts"):
                 sizes = jnp.clip(ends[1:], start, start + rows) \
                     - jnp.clip(ends[:-1], start, start + rows)
-                grouped = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+                grouped = lambda a, w: product(  # noqa: E731
                     a, w.astype(self.dtype), sizes)
                 mid = nn.silu(grouped(xin, w_gate)) * grouped(xin, w_up)
                 out = grouped(mid, w_down)

@@ -327,6 +327,14 @@ class SDARLMHead(VocabPaddingMixin, nn.Module):
             window=self.block_length, num_heads=self.num_heads,
             num_kv_heads=self.num_kv_heads)
 
+    def expert_path(self, tokens: int) -> str:
+        """How a program over ``tokens`` positions multiplies its experts:
+        `HeldExpertsMoe.expert_path` of a layer's own module."""
+        layer = HeldExpertsMoe(
+            self.num_experts, self.num_experts, self.num_experts_per_tok,
+            self.moe_intermediate_size, dtype=self.dtype, parent=None)
+        return layer.expert_path(tokens, self.hidden_dim)
+
 
 @register_model("sdar_30b_a3b_chat")
 def sdar_30b_a3b_chat(**kw) -> SDARLMHead:

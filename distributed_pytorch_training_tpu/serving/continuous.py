@@ -349,6 +349,18 @@ class SlotEngine(InferenceEngine):
                   and self.model.paged_read_supports(cfg.page_size))
         return "kernel" if kernel else "gather"
 
+    @property
+    def expert_path(self) -> Optional[str]:
+        """How the decode step's expert layers multiply their grouped
+        products, as the layer's own module answers for the step's positions
+        (`HeldExpertsMoe.expert_path`): ``"kernel"``, `ops.grouped_product`,
+        or ``"xla"``, `lax.ragged_dot`; None for a model without an expert
+        layer. Static a program, so the ``compile`` span of ``paged_decode``
+        carries it beside `kv_path`."""
+        ask = getattr(self.model, "expert_path", None)
+        window = int(getattr(self.model, "block_length", 1))
+        return ask(self.config.rows * window) if ask else None
+
     def _make_paged_decode(self) -> Callable:
         """The decode step, its parts under `jax.named_scope`s (`kv_gather`,
         `model`, `kv_scatter`, `sample`, `bookkeeping`): trace-time metadata
@@ -649,6 +661,8 @@ class SlotEngine(InferenceEngine):
             path = {"kv_path": self.kv_path,
                     "cache": type(self._pool).__name__} \
                 if kind == "paged_decode" else {}
+            if path and self.expert_path:
+                path["expert_path"] = self.expert_path
             with telemetry.span("compile", program=kind, bucket=bucket,
                                 **path):
                 self._compiled[key] = lowered.compile()
