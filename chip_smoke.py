@@ -19,7 +19,11 @@ width and weights made from a seed:
               float32 form at the block-diffusion cell's shape, and the
               grouped product of that cell's expert layer (5,632 sorted
               rows of 2,048 by 128 experts' 2,048 x 768, group sizes by the
-              cell's routing rule) against float32 products at `highest`;
+              cell's routing rule) against float32 products at `highest`,
+              and the held-experts layer's row operators (`models.moe`'s
+              `take_rows` / `sum_rows`) at the hybrid cell's timed shape
+              (8,192 tokens x 10, a window of 20,480 rows of 2,048, bf16)
+              against the gather and the scatter-add they replaced;
   2. train    ``train.main([...])``: eight optimizer steps + validation +
               a manifest-verified checkpoint;
   3. serve    the token-granular server (SlotEngine + PagePool) from that
@@ -86,6 +90,14 @@ CONV_TAPS_TOL = 1e-4
 # 0.02-scaled weights lying under its least normal number (my chip run, PR
 # 43, call 2). The limit lies between, nearer the first.
 GROUPED_PRODUCT_TOL = 8e-3
+# `sum_rows` adds a token's bf16 rows in float32 and rounds once: against
+# the float32 scatter-add of the same rows, as ||got - want|| / ||want||, it
+# reads 1.16e-3, bf16's rounding of the result (the bf16 scatter-add it
+# replaced reads 1.16e-3 too: most tokens have one row in a window); with
+# the rows rounded to float8_e4m3 first (`lax.reduce_precision`), the
+# nearest precision below, 2.69e-2 (my chip run, PR 47, call 2). The limit
+# is their geometric middle.
+ROW_SUM_TOL = 6e-3
 
 
 class SmokeFailure(Exception):
@@ -377,11 +389,80 @@ def phase_kernels() -> None:
               f"highest: {err:.2e} of ||want|| (limit "
               f"{GROUPED_PRODUCT_TOL})")
 
+    def row_operators_case(tokens, top_k, hidden, experts, held):
+        """`models.moe.take_rows` / `sum_rows` at the hybrid cell's timed
+        shape: the first window of the sorted order as `HeldExpertsMoe`
+        makes it (a quarter of ``tokens * top_k`` rows, the held ones a
+        prefix) from seeded scores. `take_rows` against ``xf[token]``
+        masked, bit for bit; `sum_rows` and the bf16 scatter-add it
+        replaced against the float32 scatter-add of the same rows, and
+        `sum_rows` again on rows rounded to float8_e4m3; a call's time in
+        both forms, eight calls a program."""
+        from distributed_pytorch_training_tpu.models.moe import (
+            sum_rows, take_rows,
+        )
+
+        every, rows = tokens * top_k, tokens * top_k // 4
+        ks = jax.random.split(jax.random.PRNGKey(47), 3)
+        chosen = jax.lax.top_k(
+            jax.random.normal(ks[0], (tokens, experts)), top_k)[1]
+        order = jnp.argsort(chosen.reshape(every), stable=True).astype(
+            jnp.int32)
+        n_held = int((chosen < held).sum())
+        check(0 < n_held < rows, f"{n_held} of {every} assignments held: "
+              f"inside the first window of {rows}")
+        rank_by_choice = jnp.argsort(order).astype(jnp.int32).reshape(
+            tokens, top_k).T
+        start, stop = jnp.int32(0), jnp.int32(n_held)
+        token, in_group = order[:rows] // top_k, jnp.arange(rows) < n_held
+        xf = jax.random.normal(ks[1], (tokens, hidden), jnp.bfloat16)
+        out = jax.random.normal(ks[2], (rows, hidden), jnp.bfloat16)
+
+        def scatter_add(out, dtype):
+            return jnp.zeros((tokens, hidden), dtype).at[token].add(
+                jnp.where(in_group[:, None], out, 0).astype(dtype))
+
+        def ms(fn, first):
+            scaled = [first * (1 + i / 64) for i in range(8)]
+            many = jax.jit(lambda xs: [fn(x) for x in xs])
+            jax.block_until_ready(many(scaled))
+            t0 = time.perf_counter()
+            for _ in range(5):
+                got = many(scaled)
+            jax.block_until_ready(got)
+            return (time.perf_counter() - t0) / 40 * 1e3, got[0]
+
+        gathered = jax.jit(lambda x: take_rows(
+            x, token, rank_by_choice, start, stop))(xf)
+        check(bool((gathered == jnp.where(in_group[:, None], xf[token],
+                                          0)).all()),
+              f"take_rows ({rows} rows of {hidden} from {tokens} tokens, "
+              f"bf16, {n_held} held) is xf[token], zeros past the last "
+              "group, bit for bit")
+        want = np.asarray(jax.jit(lambda o: scatter_add(o, jnp.float32))(out))
+        t_sum, got = ms(lambda o: sum_rows(o, token, rank_by_choice, start,
+                                           stop), out)
+        t_scatter, old = ms(lambda o: scatter_add(o, jnp.bfloat16), out)
+        coarse = jax.jit(lambda o: sum_rows(jax.lax.reduce_precision(
+            o, exponent_bits=4, mantissa_bits=3), token, rank_by_choice,
+            start, stop))(out)
+        err, err_old, err_coarse = (
+            float(np.linalg.norm(np.asarray(a, np.float32) - want)
+                  / np.linalg.norm(want)) for a in (got, old, coarse))
+        check(np.isfinite(err) and err <= ROW_SUM_TOL < err_coarse,
+              f"sum_rows ({rows} rows of {hidden} into {tokens} tokens x "
+              f"{top_k}, bf16) against the float32 scatter-add: {err:.2e} "
+              f"of ||want|| (limit {ROW_SUM_TOL}; the bf16 scatter-add "
+              f"{err_old:.2e}, float8_e4m3 rows {err_coarse:.2e}); "
+              f"{t_sum:.3f} ms a call, the scatter-add {t_scatter:.3f}")
+
     codec_case(1, 25 * 2 ** 20 // 4)      # one 25 MB gradient bucket
     codec_case(4, 25 * 2 ** 20 // 16)     # its four multihop chunks
     codec_case(4, 100_003)                # a length no block divides
     # train_qwen3_next_s8192_1chip's: q | k | v of 16 + 16 + 32 heads of 128
     conv_case(1, 8192, 8192, 4096)
+    # and its expert layer's rows: the 10 best of 512, 32 of them held
+    row_operators_case(8192, 10, 2048, 512, 32)
     if jax.device_count() == 1:    # the kernel read is a one-device program
         window_case("serve_sdar_block_diffusion_batch")
         # its expert layer's products: 176 rows x W 4, the 8 best of 128

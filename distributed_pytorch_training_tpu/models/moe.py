@@ -194,6 +194,109 @@ class MoeMlp(nn.Module):
         return xin, combine_fn, frac_tokens
 
 
+# -- a table's rows <-> sorted assignment rows --------------------------------
+#
+# One 0/1 operator G and its transpose, both written as GATHERS. Sorted
+# assignment ``start + i`` reads row ``index[i]`` of a table; seen from the
+# table, the sorted positions that read row ``r`` are ``rank_of[:, r]``
+# (int32[k, m]: every row is read k times) or the one position ``rank_of[r]``
+# (int32[m]: the inverse of a permutation). G makes the window's rows, zero
+# from position ``stop`` on; its transpose sums, for each row of the table,
+# the window's rows before ``stop`` that read it. Each is the other's
+# backward, so differentiating a gather never makes a scatter-add: on a TPU
+# the scatter-add of 20,480 rows of 2,048 into 8,192 took 1.7 ms where its
+# bytes would take 0.2 (PERF.md section 5).
+#
+# Neither closes over anything, and `jax.jit` keeps each body by its shapes:
+# the dozens of call sites of a program (layers x forward, remat and
+# backward) share one jaxpr and one lowered function, which every process
+# pays for once (PERF.md section 6, PR 34). The integer operands take no
+# cotangent.
+
+@jax.jit
+def _take_rows(table, index, start, stop):
+    rows = index.shape[0]
+    inside = start + jnp.arange(rows) < stop
+    return jnp.where(inside.reshape((rows,) + (1,) * (table.ndim - 1)),
+                     jnp.take(table, index, axis=0, mode="clip"), 0)
+
+
+# What `_sum_rows` gathers at once: k x block rows, written by the gather and
+# read back by the sum. Under this size XLA keeps them in the chip's fast
+# memory beside the sorted rows; all of the hybrid cell's 81,920 x 2,048
+# (335 MB) would be written to HBM and read again (PERF.md section 6, PR 47).
+_GATHERED_AT_ONCE = 16 * 2 ** 20
+
+
+def _blocks(m: int, gathered_row_bytes: int) -> int:
+    """Into how many equal blocks the table's ``m`` rows go so that a block's
+    gathered rows fit `_GATHERED_AT_ONCE`: the least divisor of ``m`` that
+    does (``m`` itself at the worst)."""
+    return next((n for n in range(1, m) if m % n == 0
+                 and m // n * gathered_row_bytes <= _GATHERED_AT_ONCE), m)
+
+
+@jax.jit
+def _sum_rows(sorted_rows, rank_of, start, stop):
+    def gathered(rank_of):
+        inside = (rank_of >= start) & (rank_of < stop)
+        # a choice outside the window reads row 0 and is dropped by the
+        # `where` AFTER the read, never by a product with zero: a row past
+        # the last group may hold anything, and none is ever indexed
+        picked = jnp.take(sorted_rows, jnp.where(inside, rank_of - start, 0),
+                          axis=0, mode="clip")
+        return jnp.where(inside.reshape(
+            inside.shape + (1,) * (sorted_rows.ndim - 1)), picked, 0)
+
+    if rank_of.ndim == 1:
+        return gathered(rank_of)
+    # the k rows that read one row of the table: summed in float32 and
+    # rounded once, a block of the table's rows at a time. Choice-major,
+    # (k, m): an (m, k, d) table would be re-tiled to 16 choices and copied
+    k, m = rank_of.shape
+    n = _blocks(m, k * sorted_rows.dtype.itemsize
+                * int(np.prod(sorted_rows.shape[1:])))
+    summed = jax.lax.map(
+        lambda ranks: gathered(ranks).astype(jnp.float32).sum(0).astype(
+            sorted_rows.dtype),
+        rank_of.reshape(k, n, m // n).swapaxes(0, 1))
+    return summed.reshape((m,) + sorted_rows.shape[1:])
+
+
+@jax.custom_vjp
+def take_rows(table, index, rank_of, start, stop):
+    """G: ``table[index]``, the rows of sorted assignments ``start ..
+    start + len(index) - 1``, zero from position ``stop`` on."""
+    return _take_rows(table, index, start, stop)
+
+
+@jax.custom_vjp
+def sum_rows(sorted_rows, index, rank_of, start, stop):
+    """G's transpose: for each row of the table, the sum of the rows of
+    sorted assignments ``start .. stop - 1`` that read it."""
+    return _sum_rows(sorted_rows, rank_of, start, stop)
+
+
+def _take_rows_fwd(table, *operands):
+    return take_rows(table, *operands), operands
+
+
+def _take_rows_bwd(operands, cotangent):
+    return (sum_rows(cotangent, *operands), None, None, None, None)
+
+
+def _sum_rows_fwd(sorted_rows, *operands):
+    return sum_rows(sorted_rows, *operands), operands
+
+
+def _sum_rows_bwd(operands, cotangent):
+    return (take_rows(cotangent, *operands), None, None, None, None)
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
 class HeldExpertsMoe(nn.Module):
     """One chip's share of a dropless top-k expert layer (the model-configs
     guide's section 4): the router is ``num_experts`` wide and keeps its
@@ -219,6 +322,15 @@ class HeldExpertsMoe(nn.Module):
     ``moe_dropped_assignments`` (held assignments minus those a
     group covered) is zero by construction; it is counted anyway and checked
     by the tests and the benchmark.
+
+    No row is scattered, forward or backward. A window's rows come IN by the
+    row (`take_rows`: ``x[token]``, zeros past the last group) and go BACK
+    by the token (`sum_rows`): the inverse of the sorting permutation, made
+    once a layer, says where each of a token's ``top_k`` choices stands in
+    the sorted order, and the token gathers those that lie in the window
+    and sums them in float32, rounded once. Each is the other's backward;
+    the router's weights take the same pair (read where the window needs
+    them, their gradient gathered back by assignment).
 
     Counters, sown into ``"counters"`` (training/tasks.py folds them into the
     step's metrics): ``moe_held_assignments``, ``moe_dropped_assignments``,
@@ -288,6 +400,12 @@ class HeldExpertsMoe(nn.Module):
         w_down = self.param("down", init, (held, self.expert_dim, d),
                             self.param_dtype)
         xf = x.reshape(t, d)
+        all_rows = t * k
+        # a share walks the sorted order a quarter at a time; where every
+        # expert is held (a static fact) every assignment is, and the
+        # grouped products run over the whole order at once
+        rows = all_rows if held == self.num_experts \
+            else min(all_rows, max(8, -(-all_rows // 4)))
 
         with jax.named_scope("moe_route"):
             # the router in float32 over ALL experts: a top-k is a
@@ -311,36 +429,37 @@ class HeldExpertsMoe(nn.Module):
             # held experts -> 0..held-1, absent ones -> held..E-1
             local = ((top_e - self.first_expert) % self.num_experts
                      ).reshape(t * k)
-            order = jnp.argsort(local, stable=True)
+            order = jnp.argsort(local, stable=True).astype(jnp.int32)
             ends = jnp.searchsorted(local[order], jnp.arange(held + 1),
                                     side="left").astype(jnp.int32)
             group_sizes = ends[1:] - ends[:-1]
             n_held = ends[-1]
-            token_of = (order // k).astype(jnp.int32)
-            weight_of = kept.reshape(t * k)[order]
-
-        all_rows = t * k
-        # a share walks the sorted order a quarter at a time; where every
-        # expert is held (a static fact) every assignment is, and the
-        # grouped products run over the whole order at once
-        rows = all_rows if held == self.num_experts \
-            else min(all_rows, max(8, -(-all_rows // 4)))
+            # where assignment (token, choice) stands in the sorted order:
+            # the order's inverse, made once a layer, through which the
+            # token side gathers what a scatter by `order` would add
+            rank_of = jnp.argsort(order, stable=False).astype(jnp.int32)
+            rank_by_choice = rank_of.reshape(t, k).T
+            # whole windows: what pads the last one lies past `n_held`
+            order = jnp.pad(order, (0, -all_rows % rows))
+            kept = kept.reshape(all_rows)
         product = grouped_product if self.expert_path(t, d) == "kernel" \
             else jax.lax.ragged_dot
 
         def experts_on(start):
             """The part of the result that sorted assignments ``start ..
             start + rows - 1`` give, and how many of them a group covered."""
-            at = start + jnp.arange(rows)
-            in_group = at < n_held
-            token = jnp.take(token_of, at, mode="clip")
+            in_group = start + jnp.arange(rows) < n_held
+            stop = jnp.minimum(start + rows, n_held)
+            which = jax.lax.dynamic_slice(order, (start,), (rows,))
+            token = which // k
             with jax.named_scope("moe_dispatch"):
                 # rows past the last group are masked on the way in as on
-                # the way out: a grouped product leaves them unwritten, in
-                # its transpose too, and what lies there must not reach a
-                # token's gradient through the gather's scatter-add
-                xin = jnp.where(in_group[:, None], xf[token], 0).astype(
-                    self.dtype)
+                # the way out (`take_rows` gives zeros from `stop` on, and
+                # its transpose reads no row from there on): a grouped
+                # product leaves them unwritten, in its transpose too, and
+                # what lies there must not reach a token's gradient
+                xin = take_rows(xf, token, rank_by_choice, start,
+                                stop).astype(self.dtype)
             with jax.named_scope("moe_experts"):
                 sizes = jnp.clip(ends[1:], start, start + rows) \
                     - jnp.clip(ends[:-1], start, start + rows)
@@ -353,17 +472,18 @@ class HeldExpertsMoe(nn.Module):
                 # masked BEFORE the product with its weight, so that the
                 # weight's gradient (row . cotangent) never multiplies what
                 # lies there, and the weight itself is masked too
-                weight = jnp.where(
-                    in_group, jnp.take(weight_of, at, mode="clip"), 0.0)
+                weight = take_rows(kept, which, rank_of, start, stop)
                 out = jnp.where(in_group[:, None], out, 0) \
                     * weight[:, None].astype(out.dtype)
-                y = jnp.zeros((t, d), out.dtype).at[token].add(out)
+                # each token sums the rows of its own choices (float32,
+                # rounded once), where a scatter would add row by row
+                y = sum_rows(out, token, rank_by_choice, start, stop)
             return y, sizes.sum()
 
         # the first quarter of the sorted order always; the other quarters
         # only where held assignments reach them, one at a time and
         # rematerialised, so the worst routing costs time and not memory
-        y, covered = experts_on(0)
+        y, covered = experts_on(jnp.int32(0))
         if all_rows > rows:
             def rest():
                 def more(carry, start):

@@ -556,8 +556,13 @@ def lowering_cases(pa, fa, moe_module):
 # -- lowering cases end --
 
 
-# sha256 of `lowering_cases`' texts on the PARENT of PR 42 (commit ab68caa),
-# taken there with this file's own code and this installation's jax
+# sha256 of `lowering_cases`' texts, taken with this file's own code and this
+# installation's jax: `decode_read`, `train_flash`, `prefill_flash` and
+# `decode_step` on the PARENT of PR 42 (commit ab68caa), and unchanged since;
+# `hybrid_experts` and `dsv2_experts` on PR 47's own tree, which moved both
+# by design (`models.moe.take_rows` / `sum_rows`: the expert layer's rows
+# come back by gathers; until then they were ab68caa's too, 1997a4d6... and
+# 7dbb09fe...)
 PARENTS = {
     "decode_read":
         "a5140bec0bdf4c257e4a7bc71d3e4d4a"
@@ -569,11 +574,11 @@ PARENTS = {
         "db4306ee17160cfe5dca3592aae8632b"
         "a86efd5adc36d109e03fc00b9d140cd5",
     "hybrid_experts":
-        "1997a4d6b2ccf743fa1725c764b07ae1"
-        "22ed6a245bee631b5d33813327e43ecf",
+        "9db5bf2109346ee52afe90c77a6341f5"
+        "acecad10d1ba0145be67745a556ca224",
     "dsv2_experts":
-        "7dbb09fea177db0312b84901e82a5777"
-        "91211d21d176c5d9948fc24dedf2543c",
+        "34ef10be061a9440be5f44793cc1bba6"
+        "e0ccf5f2a3e2aefa735dcdec0a52bdf5",
     "decode_step":
         "304b4476f2b5f75b11a8d7e13353d430"
         "360d6ff0187f1f232f222b49e526668b",
