@@ -35,6 +35,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.grouped_product import (
     grouped_product,
@@ -297,6 +298,36 @@ take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 
+# what `HeldExpertsMoe`'s router calls the results of its sorts, for a remat
+# policy that keeps them (`save_only_these_names`): both outputs of the one
+# `top_k` (its sort goes only if neither is wanted again), the sorted order,
+# the groups' ends, the order's inverse: integers but for the probabilities,
+# 1.3 MB a layer at 8,192 tokens x 10, which a second pass could only make
+# again as they were. An identity under no policy or another
+ROUTE_NAMES = ("moe_top_p", "moe_top_e", "moe_order", "moe_ends",
+               "moe_rank_of")
+
+
+def _named_top_k(probs, k: int):
+    return tuple(map(checkpoint_name, jax.lax.top_k(probs, k),
+                     ("moe_top_p", "moe_top_e")))
+
+
+# `lax.top_k` with both results named. Its derivative is `lax.top_k`'s own
+# (the tangent's entries at the chosen indices), read through the NAMED
+# indices: lax's rule reads them off the `top_k` it differentiates, which a
+# policy that keeps names would have to run again for them
+named_top_k = jax.custom_jvp(_named_top_k, nondiff_argnums=(1,))
+
+
+@named_top_k.defjvp
+def _named_top_k_jvp(k, primals, tangents):
+    top_p, top_e = _named_top_k(*primals, k)
+    return (top_p, top_e), (
+        jnp.take_along_axis(tangents[0], top_e, axis=-1),
+        np.zeros(top_e.shape, jax.dtypes.float0))
+
+
 class HeldExpertsMoe(nn.Module):
     """One chip's share of a dropless top-k expert layer (the model-configs
     guide's section 4): the router is ``num_experts`` wide and keeps its
@@ -421,7 +452,7 @@ class HeldExpertsMoe(nn.Module):
                 stays = (best[:, :, None] == jnp.arange(self.n_group)).any(1)
                 probs = jnp.where(stays[:, :, None], in_groups,
                                   0.0).reshape(t, self.num_experts)
-            top_p, top_e = jax.lax.top_k(probs, k)
+            top_p, top_e = named_top_k(probs, k)
             kept = top_p / top_p.sum(-1, keepdims=True) \
                 if self.norm_topk_prob else top_p
             if self.routed_scaling_factor != 1.0:
@@ -430,17 +461,21 @@ class HeldExpertsMoe(nn.Module):
             local = ((top_e - self.first_expert) % self.num_experts
                      ).reshape(t * k)
             order = jnp.argsort(local, stable=True).astype(jnp.int32)
-            ends = jnp.searchsorted(local[order], jnp.arange(held + 1),
-                                    side="left").astype(jnp.int32)
+            ends = checkpoint_name(jnp.searchsorted(
+                local[order], jnp.arange(held + 1),
+                side="left").astype(jnp.int32), "moe_ends")
             group_sizes = ends[1:] - ends[:-1]
             n_held = ends[-1]
             # where assignment (token, choice) stands in the sorted order:
             # the order's inverse, made once a layer, through which the
             # token side gathers what a scatter by `order` would add
-            rank_of = jnp.argsort(order, stable=False).astype(jnp.int32)
+            rank_of = checkpoint_name(
+                jnp.argsort(order, stable=False).astype(jnp.int32),
+                "moe_rank_of")
             rank_by_choice = rank_of.reshape(t, k).T
             # whole windows: what pads the last one lies past `n_held`
-            order = jnp.pad(order, (0, -all_rows % rows))
+            order = checkpoint_name(
+                jnp.pad(order, (0, -all_rows % rows)), "moe_order")
             kept = kept.reshape(all_rows)
         product = grouped_product if self.expert_path(t, d) == "kernel" \
             else jax.lax.ragged_dot

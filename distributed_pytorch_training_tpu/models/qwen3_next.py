@@ -44,7 +44,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.flash_attention import RESIDUAL_NAMES as FLASH_RESIDUAL_NAMES
 from ..ops.gated_delta_rule import gated_delta_mixer
+from ..ops.gdn_rule_kernels import RESIDUAL_NAMES as RULE_RESIDUAL_NAMES
 from ..parallel.mesh import FSDP, MODEL
 from ..parallel.sharding import PartitionRules
 from .layers import (
@@ -53,7 +55,7 @@ from .layers import (
     dot_product_attention,
     mask_vocab_padding,
 )
-from .moe import HeldExpertsMoe
+from .moe import ROUTE_NAMES, HeldExpertsMoe
 from .registry import register_model
 
 Dtype = Any
@@ -62,6 +64,18 @@ _INIT = nn.initializers.normal(stddev=0.02)
 # forward rematerialised: with all 32 at once one sequence of 8,192 does not
 # fit a 16 GB chip (18.0 GB against 13.4, fit_check_lm, PR 27)
 RULE_HEAD_BLOCK = 8
+# what a rematerialised layer keeps from the step's forward to its backward:
+# what its kernels wrote (the rule's output, chunk-start states and inverses,
+# 402 MB a layer at 8,192; the attention layer's output and log-sum-exp, 68
+# MB) and the integers of the router's sorts (1.3 MB), each named where it
+# is made. So the backward's second pass over a layer runs neither
+# `gdn_rule_fwd` nor `flash_fwd` nor a sort again (20 ms of a 245 ms step
+# for 0.92 GB more at the peak, of the 2.7 the step had free: PERF.md
+# section 6, PR 50); everything else it makes again as before. Which of
+# these a traced program contains decides what is kept: one that takes the
+# rule's XLA form keeps nothing of the rule
+LAYER_REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    *RULE_RESIDUAL_NAMES, *FLASH_RESIDUAL_NAMES, *ROUTE_NAMES)
 
 
 def _dense(features: int, name: str, dtype, param_dtype) -> nn.Dense:
@@ -285,7 +299,7 @@ class Qwen3NextLMHead(VocabPaddingMixin, nn.Module):
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
     attention_fn: Callable = dot_product_attention
-    remat: bool = False  # jax.checkpoint each layer
+    remat: bool = False  # jax.checkpoint each layer (LAYER_REMAT_POLICY)
     # the token table's rows and the head's columns lane-aligned (151,936 is
     # a multiple already; a slice of the vocabulary need not be)
     pad_vocab_to_multiple_of: int = 128
@@ -301,7 +315,8 @@ class Qwen3NextLMHead(VocabPaddingMixin, nn.Module):
                      name="embed")(input_ids)
         sizes = LayerSizes(**{f.name: getattr(self, f.name)
                               for f in dataclasses.fields(LayerSizes)})
-        layer_cls = nn.remat(HybridLayer) if self.remat else HybridLayer
+        layer_cls = nn.remat(HybridLayer, policy=LAYER_REMAT_POLICY) \
+            if self.remat else HybridLayer
         for i in range(self.depth):
             h = layer_cls((i + 1) % self.full_attention_interval == 0, sizes,
                           name=f"layer{i}")(h)

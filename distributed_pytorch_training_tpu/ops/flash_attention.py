@@ -60,10 +60,16 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(np.finfo(np.float32).min)
+# what the differentiated forward's output and log-sum-exp are called, as
+# the residuals hold them, for a remat policy that keeps them
+# (`save_only_these_names`: the backward then reads the first pass's and
+# `flash_fwd` runs once); an identity under no policy or another
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 # The three kernels are named `flash_fwd`, `flash_bwd_dkv`, `flash_bwd_dq`:
 # each name is its `pallas_call`'s ``name`` and the innermost
@@ -797,8 +803,8 @@ def _vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, kv_valid=None,
             f"({v.shape[-1]} against {q.shape[-1]}) have the forward kernel "
             "only; the backward kernels fold q, k and v to one width")
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(q.shape[-1])
-    out, lse = _flash_fwd_lse(q, k, v, causal, scale, block_q, block_k,
-                              kv_valid)
+    out, lse = map(checkpoint_name, _flash_fwd_lse(
+        q, k, v, causal, scale, block_q, block_k, kv_valid), RESIDUAL_NAMES)
     return out, (q, k, v, out, lse, kv_valid)
 
 

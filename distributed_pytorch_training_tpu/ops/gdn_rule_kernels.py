@@ -94,9 +94,15 @@ from, ``(B, S / CHUNK, H, Dk, Dv)`` float32 (268 MB a layer at 8,192 x 32
 heads of 128 x 128), and each chunk's ``T``, packed, ``(B, S / CHUNK, H /
 PACK, CHUNK, PACK * CHUNK)`` (67 MB): a quarter of the states for ten of the
 backward's thirty products. They are outputs of the differentiated forward
-only (under the layer's remat its first pass is that one too); a forward
-that nothing differentiates (evaluation, the benchmark's rule check) is the
-same kernel without the two outputs and puts neither in HBM.
+only; a forward that nothing differentiates (evaluation, the benchmark's
+rule check) is the same kernel without the two outputs and puts neither in
+HBM. The differentiated forward names its three outputs
+(`RESIDUAL_NAMES`, `jax.ad_checkpoint.checkpoint_name`): under a
+`jax.checkpoint` whose policy saves those names (the hybrid layer's,
+models/qwen3_next.py) the first pass's outputs are the ones the backward
+reads and the kernel runs once a layer; a `pallas_call` cannot lose one
+output, so all three are kept or the call is made again. Under no policy,
+or under one that does not list them, a name is an identity.
 
 Where it runs (`gated_delta_mixer` and `gated_delta_rule` ask the three
 gates below): on a TPU, for heads in whole PACKs of sizes in whole 128-lane
@@ -123,12 +129,18 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .gdn_conv_kernels import (
     conv_silu_backward, conv_silu_forward, gdn_conv_supports,
 )
+
+# what the differentiated forward's outputs are called, for a remat policy
+# that keeps them (`save_only_these_names`): the output, the chunk-start
+# states, the packs' inverses
+RESIDUAL_NAMES = ("gdn_rule_out", "gdn_rule_starts", "gdn_rule_inverses")
 
 CHUNK = 64              # the kernels' own: ten 64-wide products invert I + A
 HEADS_PER_STEP = 8      # at most: four packs in turn a step; 16 measured the same
@@ -813,10 +825,15 @@ def _rule(q, k, v, g, beta):
     return out.reshape(v.shape)
 
 
+def _named(outputs):
+    """The differentiated forward's three outputs under `RESIDUAL_NAMES`."""
+    return tuple(map(checkpoint_name, outputs, RESIDUAL_NAMES))
+
+
 def _rule_fwd(q, k, v, g, beta):
-    out, starts, inverses = _forward(
+    out, starts, inverses = _named(_forward(
         _flat(q), _flat(k), _flat(v), g, beta, (), form=_raw_form(q, v),
-        residuals=True)
+        residuals=True))
     return out.reshape(v.shape), (q, k, v, g, beta, starts, inverses)
 
 
@@ -854,8 +871,8 @@ def _mixer(qkvz, taps, g, beta, norm_w, form):
 
 
 def _mixer_fwd(qkvz, taps, g, beta, norm_w, form):
-    qkv, (out, starts, inverses) = _mixed(qkvz, taps, g, beta, norm_w, form,
-                                          residuals=True)
+    qkv, outputs = _mixed(qkvz, taps, g, beta, norm_w, form, residuals=True)
+    out, starts, inverses = _named(outputs)
     return out, (qkvz, taps, qkv, g, beta, norm_w, starts, inverses)
 
 

@@ -725,7 +725,8 @@ def test_hybrid_train_step_hands_the_kernels_the_mixers_own_tables(
     and dk at the key heads' width in bf16 and dz into the projection's
     full-width cotangent. PR 41: that table is what `gdn_conv_fwd` wrote
     from the projection's output as it stands, six forward and three
-    backward calls a step of three layers with remat; `gdn_conv_bwd` fills
+    backward calls a step of three layers with remat (the rule's forward
+    three: the remat keeps what it wrote); `gdn_conv_bwd` fills
     the cotangent's other columns in place from dq, dk and dv as they
     stand; and nothing under ``gdn`` slices, pads or concatenates an array
     of the projection's width or of the convolution's."""
@@ -769,9 +770,12 @@ def test_hybrid_train_step_hands_the_kernels_the_mixers_own_tables(
     layers = sum((i + 1) % model.full_attention_interval != 0
                  for i in range(model.depth))
     assert layers == 3
-    # a layer's forward, its remat, its backward
+    # a layer's forward, its remat, its backward: the remat makes the
+    # convolution's table again and keeps what the rule's forward wrote
+    # (PR 50: `qwen3_next.LAYER_REMAT_POLICY`)
+    assert len(calls["gdn_conv_fwd"]) == 2 * layers
+    assert len(calls["gdn_rule_fwd"]) == layers
     for pair in ("gdn_rule", "gdn_conv"):
-        assert len(calls[f"{pair}_fwd"]) == 2 * layers
         assert len(calls[f"{pair}_bwd"]) == layers
     step_heads = kernels._heads_per_step(hv)
     conv_dim = 2 * hk * dk + hv * dv

@@ -23,6 +23,10 @@ import pytest
 from benchmark.reference import qwen3_next as reference
 from distributed_pytorch_training_tpu.models import get_model
 from distributed_pytorch_training_tpu.models import moe, qwen3_next
+from distributed_pytorch_training_tpu.ops import gated_delta_rule as gdr
+from distributed_pytorch_training_tpu.ops.flash_attention import (
+    make_flash_attention_fn,
+)
 from distributed_pytorch_training_tpu.ops.gated_delta_rule import (
     gated_delta_rule, gated_delta_rule_stepwise,
 )
@@ -115,6 +119,101 @@ def test_gradients_of_every_parameter_match_the_reference(model, params, ids):
             g = g[:200] if "embed" in name else g[:, :200]
         assert float(jnp.abs(w).max()) > 0, name     # every leaf is reached
         assert rel(g, w) < GRAD_TOL, name
+
+
+# ---------------------------------------------------------------------------
+# the layer's remat (PR 50): `qwen3_next.LAYER_REMAT_POLICY` keeps what the
+# kernels and the router's sorts made, by name
+# ---------------------------------------------------------------------------
+
+
+def on_the_kernels(patch):
+    """The rule's gates read as on a TPU's own program (the kernels still
+    interpreted, here); the attention layer takes the flash kernels."""
+    patch.setattr(gdr, "gdn_rule_backend_supported", lambda: True)
+    patch.setattr(gdr, "gdn_rule_one_device_trace", lambda: True)
+    return dict(attention_fn=make_flash_attention_fn(causal=True))
+
+
+def all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from all_eqns(inner)
+
+
+COUNTED = ("gdn_rule_fwd", "flash_fwd", "top_k", "sort")
+
+
+@pytest.fixture(scope="module")
+def gradient_census(params, ids):
+    """How often the gradient's jaxpr of the remat'd model holds each of
+    COUNTED (a kernel by its name, the router's sorts by their primitive),
+    under the model's own policy and under none."""
+    def census(policy):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(qwen3_next, "LAYER_REMAT_POLICY", policy)
+            remat = get_model("qwen3_next_80b_a3b", remat=True,
+                              **on_the_kernels(patch), **MODEL_KW)
+            jaxpr = jax.make_jaxpr(jax.grad(
+                lambda p: program_loss(remat, p, ids)))(params).jaxpr
+        found = [eqn.params["name"] if eqn.primitive.name == "pallas_call"
+                 else eqn.primitive.name for eqn in all_eqns(jaxpr)]
+        return {name: found.count(name) for name in COUNTED}
+
+    return {"the_layers_policy": census(qwen3_next.LAYER_REMAT_POLICY),
+            "no_policy": census(None)}
+
+
+@pytest.mark.parametrize("counted", COUNTED)
+@pytest.mark.parametrize("policy,passes", [("the_layers_policy", 1),
+                                           ("no_policy", 2)])
+def test_the_remat_runs_a_kernel_and_a_sort_once_a_layer(
+        gradient_census, policy, passes, counted):
+    """Three Gated DeltaNet layers, one attention layer, four routers with
+    one `top_k` and two `argsort`s each (the chip lowers the `top_k` to the
+    `sort` its census names). Under the layer's policy the backward's second
+    pass over a layer finds each of them kept; with no policy every count is
+    twice that, which is what this test would read if the names stopped
+    engaging (a jax upgrade, a value renamed)."""
+    a_step = {"gdn_rule_fwd": 3, "flash_fwd": 1, "top_k": 4, "sort": 8}
+    assert gradient_census[policy][counted] == passes * a_step[counted]
+
+
+def test_named_top_k_is_lax_top_k_with_its_own_derivative():
+    """The router's `top_k` under its names: the same values and indices,
+    ties to the lower index included, and the same gradient, bit for bit
+    (the cotangent's entries put back at the chosen indices)."""
+    probs = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(2), (40, 16)))
+    probs = probs.at[5].set(probs[5, 0])        # a row of equals
+    weight = jax.random.normal(jax.random.PRNGKey(4), (40, 3))
+    for got, want in zip(moe.named_top_k(probs, 3), jax.lax.top_k(probs, 3)):
+        np.testing.assert_array_equal(got, want)
+    grad = lambda top_k: jax.grad(  # noqa: E731
+        lambda p: (top_k(p, 3)[0] * weight).sum())(probs)
+    np.testing.assert_array_equal(grad(moe.named_top_k), grad(jax.lax.top_k))
+
+
+@pytest.mark.parametrize("rule", ["kernels", "xla"])
+def test_remat_under_the_policy_changes_no_gradient(params, ids, monkeypatch,
+                                                    rule):
+    """``remat=True`` against ``remat=False``: the same operations on the
+    same values, so every parameter's gradient to float32 rounding (two
+    compiled programs fuse differently), not to the reference's tolerance.
+    The kernel form holds every name the policy lists; the XLA form, what a
+    CPU or a multi-device program takes, holds the router's alone."""
+    kw = on_the_kernels(monkeypatch) if rule == "kernels" else {}
+
+    def gradients(remat):
+        model = get_model("qwen3_next_80b_a3b", remat=remat, **kw, **MODEL_KW)
+        return dict(jax.tree_util.tree_leaves_with_path(jax.jit(jax.grad(
+            lambda p: program_loss(model, p, ids)))(params)))
+
+    kept, plain = gradients(True), gradients(False)
+    assert kept.keys() == plain.keys()
+    for path, g in kept.items():
+        assert float(jnp.abs(plain[path]).max()) > 0
+        assert rel(g, plain[path]) < 1e-6, jax.tree_util.keystr(path)
 
 
 @pytest.mark.parametrize("layer,mixer", [(0, "gdn"), (3, "gated_attn")])

@@ -559,10 +559,14 @@ def lowering_cases(pa, fa, moe_module):
 # sha256 of `lowering_cases`' texts, taken with this file's own code and this
 # installation's jax: `decode_read`, `train_flash`, `prefill_flash` and
 # `decode_step` on the PARENT of PR 42 (commit ab68caa), and unchanged since;
-# `hybrid_experts` and `dsv2_experts` on PR 47's own tree, which moved both
+# `hybrid_experts` and `dsv2_experts` on PR 50's own tree: PR 47 moved both
 # by design (`models.moe.take_rows` / `sum_rows`: the expert layer's rows
 # come back by gathers; until then they were ab68caa's too, 1997a4d6... and
-# 7dbb09fe...)
+# 7dbb09fe...) to 9db5bf21... and 34ef10be..., and PR 50's names on the
+# router's sorts (`moe.ROUTE_NAMES`; a name lowers to no operation) moved
+# the counter in the private functions' names (`@_where_25` is
+# `@_where_26`) and nothing else: with `@name_<n>` read as `@name` both
+# texts are PR 47's letter for letter
 PARENTS = {
     "decode_read":
         "a5140bec0bdf4c257e4a7bc71d3e4d4a"
@@ -574,11 +578,11 @@ PARENTS = {
         "db4306ee17160cfe5dca3592aae8632b"
         "a86efd5adc36d109e03fc00b9d140cd5",
     "hybrid_experts":
-        "9db5bf2109346ee52afe90c77a6341f5"
-        "acecad10d1ba0145be67745a556ca224",
+        "336e5b82b421c64c5fdb34594dbb8fb4"
+        "56843edd3aa0176d18b76feb16fcfc6c",
     "dsv2_experts":
-        "34ef10be061a9440be5f44793cc1bba6"
-        "e0ccf5f2a3e2aefa735dcdec0a52bdf5",
+        "4329756dd403e2de36e661ec20160b9a"
+        "6d2d594cb1bb507f631391319fbf057f",
     "decode_step":
         "304b4476f2b5f75b11a8d7e13353d430"
         "360d6ff0187f1f232f222b49e526668b",
