@@ -534,7 +534,7 @@ def phase_serve(ckpt_dir: Path) -> None:
     def bench(kv_dtype: str, requests: int) -> dict:
         with tee_stdout() as printed:
             rc = main([
-                "bench", "--continuous", "--model", "gpt2_124m",
+                "bench", "--model", "gpt2_124m",
                 "--ckpt-dir", str(ckpt_dir), "--optimizer", "adamw",
                 # gpt2_124m's own position table, which the checkpoint
                 # holds (the CLI would size a fresh one from the buckets)

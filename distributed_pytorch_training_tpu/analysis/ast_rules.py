@@ -338,23 +338,18 @@ def check_host_sync_in_step(ctx: FileContext) -> List[Finding]:
                             "no-host-sync-in-step", "step path", "per-step")
 
 
-# The serving decode hot loops' homes and function names (serving/engine.py
-# `generate`, serving/continuous.py `_step_decode_loop` — the continuous
-# scheduler's shared-pool sibling — plus anything a refactor names
-# *_decode_loop). One host fetch per BATCH is the design (after the last
-# step, in serve_tokens / _complete_finished); a fetch inside the loop
-# stalls the device once per generated TOKEN, for EVERY slot in the pool.
-_DECODE_LOOP_FILES = ("serving/engine.py", "serving/continuous.py")
-
-
-def _is_decode_loop_name(name: str) -> bool:
-    return name == "generate" or name.endswith("_decode_loop")
+# The serving decode hot loop's home and function names
+# (serving/continuous.py `_step_decode_loop`, plus anything a refactor
+# names *_decode_loop). One host fetch per finished SLOT is the design
+# (after the burst's last step, in _complete_finished); a fetch inside
+# the loop stalls the device once per generated TOKEN, for EVERY slot in
+# the pool.
+_DECODE_LOOP_FILES = ("serving/continuous.py",)
 
 
 @rule("no-host-sync-in-decode", "ast",
-      "no .item()/float()/device_get syncs inside the serving decode loops "
-      "(serving/engine.py generate, serving/continuous.py "
-      "_step_decode_loop)",
+      "no .item()/float()/device_get syncs inside the serving decode loop "
+      "(serving/continuous.py _step_decode_loop)",
       "the decode loop runs one compiled step per generated token with "
       "every chained value (token, positions, cache) staying on device; "
       "a host fetch creeping in serializes the device per TOKEN — the "
@@ -365,7 +360,7 @@ def check_host_sync_in_decode(ctx: FileContext) -> List[Finding]:
         return []
     loops = [n for n in ast.walk(ctx.tree)
              if isinstance(n, ast.FunctionDef)
-             and _is_decode_loop_name(n.name)]
+             and n.name.endswith("_decode_loop")]
     return _scan_sync_calls(ctx, loops, "no-host-sync-in-decode",
                             "decode loop", "per-token")
 

@@ -143,12 +143,11 @@ class Contract:
     of production censuses. ``min_shards`` gates configs that only engage
     on a multi-shard mesh (zero1 / grad_sync passthrough convention).
     ``kind`` selects the evaluator: "train" lowers a Trainer step
-    (`hlo_rules._tiny_lm_setup`); "serving" lowers the inference engine's
-    KV-cache decode step (`hlo_rules.evaluate_serving_contract`) — the
-    decode-step contract of serving/ (ISSUE 10), run by the same tier-1
-    ``analysis check`` gate; "serving_paged" lowers the SlotEngine's
+    (`hlo_rules._tiny_lm_setup`); "serving_paged" lowers the SlotEngine's
     shared paged decode step (`hlo_rules.evaluate_paged_serving_contract`,
-    ISSUE 17) — the continuous-batching page-pool-donation contract;
+    ISSUE 17) — the token server's decode-step contract (no host
+    transfers, the page pool donated), run by the same tier-1 ``analysis
+    check`` gate; "serving_spec" the speculative engine's verify step;
     "elastic" lowers the SAME train step twice at
     the target world — once from a clean state, once from a state
     resharded by resilience.elastic (down N->M for ``elastic_reshard``,
@@ -286,29 +285,17 @@ CONTRACT_MATRIX: Tuple[Contract, ...] = (
              "model-axis activation psums stay exact fp32 by design",
              config=dict(fsdp_explicit=True, wire_dtype="int8_multihop"),
              min_shards=2, min_elements=64, mesh_spec="data=4,model=2"),
-    # The serving decode-step contract (ISSUE 10): the inference engine's
-    # one-token KV-cache step must carry NO host transfers (a callback in
-    # the decode loop stalls every generated token) and must DONATE the
-    # cache (without the alias table every step copies the full
-    # (rows, bucket + max_new, heads, head_dim) k/v — a per-token memory
-    # tax that compounds with batch). The zero-recompile half of the
-    # decode contract is runtime behavior, pinned by the compile-count
-    # census in tests/test_serving.py and asserted by `serving bench`.
-    Contract("serving_decode",
-             "serving KV-cache decode: no host transfers, cache donated "
-             "in place (serving/engine.py lower_decode)",
-             config=dict(serving_decode=True, donate_state=True),
-             kind="serving"),
-    # The paged continuous-batching contract (ISSUE 17): the SlotEngine's
+    # The token server's decode-step contract (ISSUE 17): the SlotEngine's
     # SHARED decode step — one program serving every slot at once — must
-    # carry no host transfers and must alias the ENTIRE page pool in
+    # carry no host transfers (a callback in the decode loop stalls every
+    # generated token) and must alias the ENTIRE page pool in
     # place: paged-pool-donated counts the alias table against the pool's
     # leaf census (paged_cache_leaves). Pinned on the int8 arm because it
     # has the most leaves to drop (k/v codes + k/v scales per block); a
     # missing scale buffer is invisible to the presence-only donation
     # rule but doubles int8 pool traffic on every generated token. The
     # zero-recompile-across-joins/leaves half is runtime behavior, pinned
-    # by tests/test_continuous.py and `serving bench --continuous`.
+    # by tests/test_continuous.py and asserted by `serving bench`.
     Contract("serving_paged",
              "paged int8 continuous-batching decode: no host transfers, "
              "full page pool (codes + scales) donated in place "

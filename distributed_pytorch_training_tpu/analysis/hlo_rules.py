@@ -1040,31 +1040,6 @@ _HOST_TRANSFER_RE = re.compile(
 _ALIAS_ENTRY_RE = re.compile(r"\{\d+\}:\s*\(\d+")
 
 
-@rule("decode-cache-donated", "hlo",
-      "the serving decode step aliases EVERY KV-cache buffer in place",
-      "the decode hot loop donates its cache (serving/engine.py); if any "
-      "per-block k/v buffer falls out of the alias table, every generated "
-      "token copies that full (rows, bucket+max_new, heads, head_dim) "
-      "buffer — a per-token memory+bandwidth tax the presence-only "
-      "donation rule cannot see (one surviving alias entry satisfies it).")
-def check_decode_cache_donated(a: StepArtifacts) -> List[Finding]:
-    if not a.config.get("serving_decode"):
-        return []
-    expect = int(a.config.get("decode_cache_leaves", 0))
-    # the table nests braces (`{0}: (28, {}, may-alias), ...`), so the
-    # region ends at the first `)` directly followed by the closing `}`
-    m = re.search(r"input_output_alias=\{(.*?\))\s*\}", a.optimized_text,
-                  re.DOTALL)
-    entries = len(_ALIAS_ENTRY_RE.findall(m.group(1))) if m else 0
-    if entries < expect:
-        return [Finding(
-            "decode-cache-donated",
-            f"decode step aliases {entries} of the {expect} KV-cache "
-            "buffers — the un-aliased ones are copied on every generated "
-            "token", a.name)]
-    return []
-
-
 @rule("paged-pool-donated", "hlo",
       "the paged decode step aliases EVERY page-pool buffer in place",
       "the slot engine's shared decode step donates the whole paged KV "
@@ -1217,7 +1192,6 @@ def check_dp_sync_present(a: StepArtifacts) -> List[Finding]:
             # guard is about the TRAIN step's reducer, not a scoping knob
             # to relax: an inference forward with an all-reduce would be
             # the bug, not the absence of one
-            or a.config.get("serving_decode")
             or a.config.get("serving_paged")
             or a.config.get("serving_spec")):
         # grad-accum keeps sync inside a scan; count it only on the plain arm
@@ -1294,38 +1268,10 @@ def replicated_large_buffers(tree: Any, min_elements: int
     return tuple(out)
 
 
-def serving_artifacts(engine, bucket: int,
-                      name: str = "serving_decode") -> StepArtifacts:
-    """StepArtifacts of one serving engine's compiled KV-cache decode step
-    — the serving sibling of the train-step snapshot. ``decode_cache_leaves``
-    carries the cache's leaf count (2 per block: k and v) so
-    `decode-cache-donated` can demand the WHOLE cache aliased, not just
-    some buffer."""
-    import jax
-
-    from ..parallel.mesh import batch_shard_count
-
-    lowered = engine.lower_decode(bucket)
-    optimized = lowered.compile().as_text()
-    try:
-        preopt = preopt_hlo_text(lowered)
-    except Exception:  # pragma: no cover - backend without HLO dialect
-        preopt = None
-    return StepArtifacts(
-        name=name,
-        optimized_text=optimized,
-        preopt_text=preopt,
-        config={"serving_decode": True, "donate_state": True,
-                "decode_cache_leaves": 2 * engine.model.depth},
-        n_shards=batch_shard_count(engine.mesh),
-        backend=jax.default_backend(),
-    )
-
-
 def paged_serving_artifacts(engine, name: str = "serving_paged"
                             ) -> StepArtifacts:
     """StepArtifacts of a SlotEngine's shared paged decode step — the
-    continuous-batching sibling of `serving_artifacts`. ``paged_cache_leaves``
+    serving sibling of the train-step snapshot. ``paged_cache_leaves``
     is the page pool's donated-leaf census — the pool is stacked across
     layers (models/layers.py PagedKV), so it is 2 buffers fp32 (k/v
     pages), 4 int8 (k/v codes + k/v scales), regardless of depth — and
@@ -1381,42 +1327,6 @@ def spec_serving_artifacts(engine, name: str = "serving_spec"
         n_shards=batch_shard_count(engine.mesh),
         backend=jax.default_backend(),
     )
-
-
-def evaluate_serving_contract(contract: Contract,
-                              mesh=None) -> StepArtifacts:
-    """Lower the tiny serving engine's decode step and snapshot artifacts —
-    the ``kind="serving"`` arm of `evaluate_contract`. The tiny engine is
-    the contract model's shape class (2-block GPT-2) behind the REAL
-    engine code path (serving/engine.py lower_decode), so what the matrix
-    checks is what serving ships."""
-    import jax
-    import numpy as np
-
-    from ..models.gpt2 import GPT2LMHead
-    from ..parallel.mesh import MeshSpec, batch_shard_count, build_mesh
-    from ..serving.engine import InferenceEngine, ServeConfig
-
-    if mesh is None:
-        mesh = build_mesh(MeshSpec(), devices=jax.devices())
-    n_shards = batch_shard_count(mesh)
-    if n_shards < contract.min_shards:
-        raise ValueError(
-            f"contract {contract.name!r} needs >= {contract.min_shards} "
-            f"batch shards (got {n_shards})")
-    model = GPT2LMHead(vocab_size=64, hidden_dim=32, depth=2, num_heads=2,
-                       max_position=32)
-    params = model.init(jax.random.PRNGKey(0), np.zeros((1, 8), np.int32),
-                        train=False)["params"]
-    engine = InferenceEngine(
-        model, mesh, ServeConfig(buckets=(8,), rows=max(n_shards, 2),
-                                 max_new_tokens=4), params)
-    artifacts = serving_artifacts(engine, bucket=8, name=contract.name)
-    return dataclasses.replace(
-        artifacts, config={**artifacts.config, **contract.config,
-                           "decode_cache_leaves":
-                           artifacts.config["decode_cache_leaves"]},
-        min_elements=contract.min_elements)
 
 
 def evaluate_paged_serving_contract(contract: Contract,
@@ -1578,11 +1488,10 @@ def evaluate_contract(contract: Contract, mesh=None) -> StepArtifacts:
     Raises ValueError when the mesh has fewer batch shards than the
     contract needs (zero1/grad_sync are identity passthroughs there —
     evaluating the contract would vacuously pass; the caller decides
-    whether that is a skip or an error). ``kind="serving"`` contracts
-    route to `evaluate_serving_contract` (the inference engine's decode
-    step instead of a Trainer step); ``kind="serving_paged"`` to
-    `evaluate_paged_serving_contract` (the SlotEngine's shared paged
-    decode step); ``kind="serving_spec"`` to
+    whether that is a skip or an error). ``kind="serving_paged"``
+    contracts route to `evaluate_paged_serving_contract` (the SlotEngine's
+    shared paged decode step instead of a Trainer step);
+    ``kind="serving_spec"`` to
     `evaluate_spec_serving_contract` (the speculative K+1-window verify
     step); ``kind="elastic"`` to `evaluate_elastic_contract`
     (the resharded-vs-clean census pin).
@@ -1592,8 +1501,6 @@ def evaluate_contract(contract: Contract, mesh=None) -> StepArtifacts:
     from ..parallel.grad_sync import build_bucket_plan
     from ..parallel.mesh import MeshSpec, batch_shard_count, build_mesh
 
-    if contract.kind == "serving":
-        return evaluate_serving_contract(contract, mesh=mesh)
     if contract.kind == "serving_paged":
         return evaluate_paged_serving_contract(contract, mesh=mesh)
     if contract.kind == "serving_spec":

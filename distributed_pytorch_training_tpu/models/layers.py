@@ -454,8 +454,8 @@ def scatter_paged_prefill(pkv, page_row: jnp.ndarray, *seqs_then_length,
 def paged_kv_bytes(pool) -> int:
     """At-rest bytes of a paged pool (every block's codes + scales for
     int8 pools, raw elements otherwise) — the serving analogue of
-    grad_sync's wire accounting, compared against the dense engine's
-    cache (`SlotEngine.dense_baseline_bytes`)."""
+    grad_sync's wire accounting, compared against a dense cache
+    (`SlotEngine.dense_baseline_bytes`)."""
     import jax
 
     return int(sum(arr.size * arr.dtype.itemsize

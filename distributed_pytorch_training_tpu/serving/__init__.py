@@ -1,24 +1,26 @@
-"""serving/ — manifest-verified batched inference on the training stack.
+"""serving/ — manifest-verified checkpoints served on the training stack.
 
 The path from a training checkpoint to a served token, built from the
 pieces the training side already ships: ``training/checkpoint.py``'s
 manifest-verified restore for the weights, ``data/pack.py``'s bucket
-ladder for the shapes, ``models/gpt2.py``'s cache-aware forward for
-prefill + KV-cache decode, the grad-sync int8 codec grid for
+ladder for the shapes, the models' cache-aware forwards for prefill and
+decode over a paged KV pool, the grad-sync int8 codec grid for
 weight-at-rest quantization, ``resilience/`` for liveness + drain, and
-``telemetry/`` for the latency story (queue_wait / prefill / decode /
-drain spans).
+``telemetry/`` for the latency story (queue_wait / slot_wait / prefill /
+drain spans and the scheduler's phases).
 
-Two batching disciplines share the stack. The iteration-granular path
-(`InferenceEngine` + `serve_forever`) forms a batch, decodes it to
-completion, forms the next. The token-granular path (`SlotEngine` +
-`ContinuousScheduler`, ISSUE 17) keeps ONE compiled decode program
-running over a fixed slot pool backed by a paged — optionally int8 —
-KV cache (`PagedServeConfig` / `PagePool`), admitting and retiring
-requests between tokens with zero recompiles. `Router` spreads requests
-over N replicas of either and resubmits on replica death with the
-request's sampling seed pinned, so a retried request samples the
-identical stream.
+A causal LM has ONE server, the token server: `SlotEngine` (or the engine
+its model asks for: `BlockDiffusionEngine`, `SpeculativeEngine`) keeps
+one compiled decode program running over a fixed slot pool backed by a
+paged — optionally int8 — KV cache (`PagedServeConfig` / `PagePool`), and
+its `ContinuousScheduler` admits and retires requests between tokens with
+zero recompiles. `Router` spreads requests over N replicas and resubmits
+on replica death with the request's sampling seed pinned, so a retried
+request samples the identical stream. Models without a cache have the
+forward engine: `InferenceEngine` (BERT's bucketed forward behind
+`serve_forever`, `serve_images` for ResNet / ViT). Both stand on
+`engine.ServedModel` (placement, int8 weights, checkpoint provenance,
+the compile census).
 
 Entry points: the ``serving`` console script (``smoke`` / ``bench`` /
 ``serve`` / ``fleet``), or the classes directly.

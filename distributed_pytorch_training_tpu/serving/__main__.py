@@ -1,5 +1,6 @@
 """``python -m distributed_pytorch_training_tpu.serving`` — serve a
-manifest-verified checkpoint through the batched inference engine.
+manifest-verified checkpoint: a causal LM through the token server, BERT
+and image models through the forward engine.
 
 Also installed as the ``serving`` console script (pyproject.toml).
 
@@ -9,20 +10,21 @@ Commands:
       when --ckpt-dir is given; random-init weights otherwise — a smoke of
       the serving PATH, loudly labeled, never of a served model), serve a
       handful of synthetic prompts, print the generated tokens and the
-      checkpoint provenance (label + manifest tree_digest).
+      checkpoint provenance (label + manifest tree_digest). A causal LM
+      goes through the token server's scheduler (slot engine + paged KV,
+      serving/continuous.py), BERT and image models through the forward
+      engine (serving/engine.py).
   bench [--requests N] [--offered-load RPS] [--json]
-      Latency/throughput at fixed offered load: a deterministic load
-      generator submits mixed-length prompts on a 1/RPS cadence while the
-      engine worker drains the queue (continuous batching); reports
-      p50/p99 latency, achieved request/token throughput, the compile
-      census (zero recompiles after warmup is the contract), and the
-      serving HLO-contract verdict (serving/loadtest.py::measure_serving).
-      --continuous switches to the TOKEN-granular arm (slot engine +
-      paged/int8 KV, serving/continuous.py) — same load schedule, so the
-      two rows are the iteration-vs-token A/B; --replicas N spreads it
-      over N in-process replicas behind the stdlib router and
-      --kill-replica injects one replica death mid-load (every request
-      must still complete, recompiles must stay 0).
+      A causal LM at fixed offered load through the token server: a
+      deterministic load generator submits mixed-length prompts on a 1/RPS
+      cadence; reports p50/p99 latency, TTFT, achieved request/token
+      throughput, paged-vs-dense KV bytes, the compile census (zero
+      recompiles after warmup is the contract), and the serving
+      HLO-contract verdict
+      (serving/loadtest.py::measure_serving_continuous).
+      --replicas N spreads it over N in-process replicas behind the
+      stdlib router and --kill-replica injects one replica death mid-load
+      (every request must still complete, recompiles must stay 0).
       --draft MODEL arms speculative decoding (draft proposes --draft-k
       tokens, target verifies the K+1 window in one forward; the row
       gains accept_ratio and the stream stays bitwise the plain arm's);
@@ -127,13 +129,10 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="smoke: comma-separated token ids")
     p.add_argument("--prompt-len", type=int, default=12,
                    help="smoke: synthetic prompt length when no --prompt")
-    # continuous / paged serving (serve, fleet, bench --continuous)
-    p.add_argument("--continuous", action="store_true",
-                   help="bench: token-granular slot-engine arm (paged KV) "
-                        "instead of the iteration-granular engine")
+    # the token server (serve, fleet, bench, smoke of a causal LM)
     p.add_argument("--replicas", type=int, default=1,
-                   help="bench --continuous: in-process replicas behind "
-                        "the router; fleet: serve children to supervise")
+                   help="bench: in-process replicas behind the router; "
+                        "fleet: serve children to supervise")
     p.add_argument("--kv-dtype", default="fp32", choices=["fp32", "int8"],
                    help="paged KV pool dtype (int8: per-row quantized "
                         "pages through the grad-sync int8 grid)")
@@ -141,18 +140,18 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="positions per KV page (divide the top bucket + "
                         "max-new for a padding-free pool)")
     p.add_argument("--kill-replica", action="store_true",
-                   help="bench --continuous --replicas>1: kill replica 0 "
+                   help="bench --replicas>1: kill replica 0 "
                         "mid-load; the router must resubmit its requests")
-    # speculative decoding + prefix-resident admission (bench --continuous)
+    # speculative decoding + prefix-resident admission (bench)
     p.add_argument("--draft", default=None, metavar="MODEL",
-                   help="bench --continuous: arm speculative decoding "
+                   help="bench: arm speculative decoding "
                         "with this (random-init, smaller) draft LM — "
                         "fp32 KV only; the emitted streams stay bitwise "
                         "the plain row's (acceptance is exact match)")
     p.add_argument("--draft-k", type=int, default=4,
                    help="draft tokens proposed per slot per verify round")
     p.add_argument("--shared-frac", type=float, default=0.0,
-                   help="bench --continuous: fraction of requests that "
+                   help="bench: fraction of requests that "
                         "share ONE page-aligned prompt — after the "
                         "primer, each admits with zero prefill dispatch "
                         "(prefill_skips + warm/cold TTFT in the row)")
@@ -171,10 +170,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="bench: offered request rate (req/s)")
     p.add_argument("--mixed-want", action="store_true",
                    help="bench: per-request decode lengths (1..max_new, "
-                        "seed-pinned) — the serving-traffic A/B workload; "
-                        "the iteration arm still decodes the full max_new "
-                        "per batch (it cannot honor per-request wants) "
-                        "and only the wanted tokens are credited")
+                        "seed-pinned); a slot retires at its want and "
+                        "only the wanted tokens are credited")
     p.add_argument("--output-dir", default="./serving_out",
                    help="telemetry stream + flight directory")
     p.add_argument("--no-telemetry", action="store_true")
@@ -215,9 +212,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                   "serve_dtype": args.serve_dtype,
                   "buckets": list(buckets)})
     # live /metrics + /healthz (telemetry/metrics_http.py): the serving
-    # replica's scrape surface — prefill/decode histograms feed the same
-    # phase metric the training loop's dispatch does, and the healthz
-    # fence counts decode progress. Off (default) starts zero threads.
+    # replica's scrape surface — the serving phases' histograms feed the
+    # same phase metric the training loop's dispatch does, and the healthz
+    # fence counts prefills as progress. Off (default) starts zero threads.
     metrics_port = telemetry.resolve_metrics_port(args.metrics_port,
                                                   tele_rank)
     if metrics_port and telemetry.is_configured():
@@ -257,8 +254,9 @@ def _run(args, buckets) -> int:
     from ..utils.config import parse_model_overrides
     from ..utils.logging import log_main
     from .batching import RequestQueue, drain, serve_forever
-    from .build import build_serving_engine
-    from .loadtest import measure_serving, measure_serving_continuous
+    from .build import build_serving_engine, build_slot_engine, has_cache
+    from .continuous import serve_continuous
+    from .loadtest import measure_serving_continuous
 
     overrides = (parse_model_overrides(args.model_overrides)
                  if args.model_overrides else None)
@@ -279,7 +277,7 @@ def _run(args, buckets) -> int:
     if args.command == "fleet":
         return _fleet(args, buckets)
 
-    if args.command == "bench" and args.continuous:
+    if args.command == "bench":
         row = measure_serving_continuous(
             model_name=args.model, n_requests=args.requests,
             offered_rps=args.offered_load, buckets=buckets, rows=args.rows,
@@ -308,7 +306,7 @@ def _run(args, buckets) -> int:
                     if row.get("prefill_skips") or row.get("tail_resumes")
                     else "")
             log_main(
-                f"serving bench [token-granular x{row['replicas']}]: "
+                f"serving bench [x{row['replicas']}]: "
                 f"{row['model']} kv={row['kv_dtype']} "
                 f"p50 {row['p50_ms']}ms p99 {row['p99_ms']}ms "
                 f"ttft p50 {row['ttft_p50_ms']}ms at "
@@ -325,41 +323,25 @@ def _run(args, buckets) -> int:
                          f"{row['contracts']['violations']}")
         return 0 if row.get("recompiles_after_warmup") == 0 else 1
 
-    if args.command == "bench":
-        row = measure_serving(
-            model_name=args.model, n_requests=args.requests,
-            offered_rps=args.offered_load, buckets=buckets, rows=args.rows,
-            max_new_tokens=args.max_new_tokens,
-            serve_dtype=args.serve_dtype, mixed_want=args.mixed_want,
-            model_overrides=overrides,
-            ckpt_dir=args.ckpt_dir, seed=args.seed,
-            optimizer=args.optimizer, momentum=args.momentum,
-            weight_decay=args.weight_decay, train_config=train_config,
-            mesh_spec=args.mesh)
-        if args.as_json:
-            print(json.dumps(row, sort_keys=True, default=str))
-        else:
-            toks = (f" ({row['tokens_per_sec']} tok/s)"
-                    if "tokens_per_sec" in row else "")
-            log_main(
-                f"serving bench: {row['model']} [{row['serve_dtype']}] "
-                f"p50 {row['p50_ms']}ms p99 {row['p99_ms']}ms at "
-                f"{row['achieved_rps']}/{row['offered_rps']} req/s{toks}, "
-                f"{row['compiles']} compiles "
-                f"({row['recompiles_after_warmup']} after warmup)")
-            if row.get("contracts", {}).get("pass") is False:
-                log_main(f"serving bench: CONTRACT VIOLATIONS: "
-                         f"{row['contracts']['violations']}")
-        return 0 if row.get("recompiles_after_warmup") == 0 else 1
-
     # -- smoke ---------------------------------------------------------------
-    engine, mesh = build_serving_engine(
-        jax.devices(), args.model, buckets=buckets, rows=args.rows,
+    # a causal LM smokes its one server, the token server; a model without
+    # a cache the forward engine and its loop
+    causal = has_cache(args.model, overrides)
+    common = dict(
+        buckets=buckets, rows=args.rows,
         max_new_tokens=args.max_new_tokens, serve_dtype=args.serve_dtype,
         model_overrides=overrides, ckpt_dir=args.ckpt_dir,
         train_config=train_config, seed=args.seed,
         optimizer=args.optimizer, momentum=args.momentum,
         weight_decay=args.weight_decay, mesh_spec=args.mesh)
+    if causal:
+        engine, mesh = build_slot_engine(
+            jax.devices(), args.model, kv_dtype=args.kv_dtype,
+            page_size=args.page_size,
+            prefix_skip=not args.no_prefix_skip, **common)
+    else:
+        engine, mesh = build_serving_engine(jax.devices(), args.model,
+                                            **common)
     if engine.checkpoint_info:
         info = engine.checkpoint_info
         log_main(f"serving: checkpoint label={info['label']} "
@@ -369,7 +351,7 @@ def _run(args, buckets) -> int:
         log_main("serving: NOTE: random-init weights (no --ckpt-dir) — "
                  "this smokes the serving path, not a trained model")
 
-    if not engine.is_token:
+    if not causal and not engine.is_token:
         rng = np.random.RandomState(args.seed)
         logits = engine.serve_images(
             rng.randint(0, 256, (2, 32, 32, 3)).astype(np.uint8),
@@ -403,23 +385,28 @@ def _run(args, buckets) -> int:
 
     prev = signal.signal(signal.SIGTERM, on_sigterm)
     try:
-        worker = threading.Thread(target=serve_forever,
-                                  args=(engine, queue, stop),
-                                  kwargs={"log": log_main}, daemon=True)
+        worker = threading.Thread(
+            target=serve_continuous if causal else serve_forever,
+            args=(engine, queue, stop), kwargs={"log": log_main},
+            daemon=True)
         worker.start()
         reqs = [queue.submit(p) for p in prompts]
         for req, prm in zip(reqs, prompts):
             res = req.result(timeout=600.0)
+            took = (f"first token after {res.queue_wait_s * 1e3:.1f}ms, "
+                    f"decode {res.decode_s * 1e3:.1f}ms" if causal
+                    else f"forward {res.prefill_s * 1e3:.1f}ms")
             log_main(
                 f"serving smoke: prompt[{len(prm)} tok] bucket={res.bucket} "
                 f"-> {res.tokens.tolist() if res.tokens.size else '[]'} "
-                f"(prefill {res.prefill_s * 1e3:.1f}ms, decode "
-                f"{res.decode_s * 1e3:.1f}ms)")
+                f"({took})")
         stop.set()
         worker.join(timeout=60.0)
-        # drain is idempotent here (queue already empty) — it exists so a
-        # SIGTERM mid-smoke still completes accepted work before exit
-        drain(engine, queue, log=log_main)
+        if not causal:
+            # drain is idempotent here (queue already empty) — it exists so
+            # a SIGTERM mid-smoke still completes accepted work before exit
+            # (the scheduler's own loop drains before it returns)
+            drain(engine, queue, log=log_main)
     finally:
         signal.signal(signal.SIGTERM, prev)
     log_main(f"serving smoke: ok ({engine.compiles} compiles)")

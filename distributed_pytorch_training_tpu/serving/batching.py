@@ -1,29 +1,30 @@
-"""Request queue + continuous batch assembly for the inference engine.
+"""The request queue both engines sit behind, and the forward engine's loop.
 
 The serving hot path is shaped by one constraint: XLA compiles per input
-SHAPE, so the engine may only ever see a small static set of shapes (one
+SHAPE, so an engine may only ever see a small static set of shapes (one
 per bucket in the ladder). Everything ragged about real traffic — arrival
 times, prompt lengths, burst sizes — is absorbed HERE, on the host:
 
+* ``Request`` / ``Result``: one submitted prompt, waitable, and what an
+  engine hands back for it.
 * ``RequestQueue`` is the thread-safe front door. Producers (RPC handlers,
   the bench's load generator) ``submit`` token prompts and block on the
-  returned ``Request`` until the engine fills its result.
-* ``next_batch`` drains the queue into ONE bucket-compatible group:
-  the oldest request picks the bucket (``data.pack.bucket_for`` — smallest
-  rung that fits), and every queued request that fits the same rung rides
-  along, up to the engine's row budget. This is continuous batching at
-  iteration granularity: a request never waits for a "full" batch — it
-  joins the very next engine cycle — and a long prompt never blocks a
-  burst of short ones behind a shape it doesn't share.
-* ``serve_forever`` is the engine worker loop the CLI runs on a thread:
+  returned ``Request`` until an engine fills its result. It is drained two
+  ways: ``take`` pops requests FIFO and bucket-blind for the token
+  server's scheduler (serving/continuous.py, a causal LM's one server);
+  ``next_batch`` pops ONE bucket-compatible group for the forward engine
+  (`engine.InferenceEngine`, BERT): the oldest request picks the bucket
+  (``data.pack.bucket_for`` — smallest rung that fits), and every queued
+  request that fits the same rung rides along, up to the engine's rows.
+* ``serve_forever`` / ``drain`` are the forward engine's worker loop:
   pop a group, ``engine.serve_tokens`` it, fill results, repeat; on stop,
   DRAIN — finish everything already queued (the SIGTERM contract: accepted
   work completes, new work is refused), under a ``drain`` telemetry span.
+  (The token server's loop is `ContinuousScheduler.run` / ``.drain``.)
 
 Per-request ``queue_wait`` (submit -> popped) is emitted as a telemetry
 span so the latency story decomposes: queue_wait is the load/provisioning
-share, prefill/decode the compute share (``telemetry summary`` buckets all
-four).
+share, prefill the compute share (``telemetry summary`` buckets them).
 """
 
 from __future__ import annotations
@@ -224,7 +225,7 @@ class RequestQueue:
 
 def serve_forever(engine, queue: RequestQueue,
                   stop: threading.Event, log=None) -> int:
-    """The engine worker loop: drain the queue through the engine until
+    """The forward engine's worker loop: drain the queue through it until
     ``stop`` is set AND the queue is empty (stop means drain, not abandon).
     Returns the number of requests served. A failed batch fails exactly its
     own requests (their ``result()`` re-raises); the loop itself survives —
@@ -261,7 +262,7 @@ def serve_forever(engine, queue: RequestQueue,
 def drain(engine, queue: RequestQueue, log=None) -> int:
     """Serve everything still queued, then return (the SIGTERM path).
     Wrapped in the ``drain`` telemetry span so shutdown latency is on the
-    record next to queue_wait/prefill/decode."""
+    record next to queue_wait/prefill."""
     stop = threading.Event()
     stop.set()
     with telemetry.span("drain", pending=len(queue)):

@@ -1,8 +1,9 @@
 """How a serving engine is built from a model's name: the checkpoint
 template, the position table sized from buckets or pages, mesh validation
-and the serve rules — one code path for the three engines, behind every
-subcommand of the CLI (`serving/__main__.py`) and its load test
-(`serving/loadtest.py`)."""
+and the serve rules — one code path for every engine (the forward engine
+of BERT and image models, the token server's three of a causal LM),
+behind every subcommand of the CLI (`serving/__main__.py`) and its load
+test (`serving/loadtest.py`)."""
 
 from __future__ import annotations
 
@@ -16,6 +17,17 @@ import numpy as np
 from ..models.registry import is_lm_model
 
 
+def has_cache(model_name: str, model_overrides: Optional[dict] = None) -> bool:
+    """Whether the model is a causal LM (it keeps a KV cache: `init_cache`).
+    Those are the token server's (`build_slot_engine`); every other model
+    is the forward engine's. Asked by `smoke` and `bench` before any engine
+    is built; the two constructors refuse the wrong kind on their own."""
+    from ..models import get_model
+
+    return hasattr(get_model(model_name, **(model_overrides or {})),
+                   "init_cache")
+
+
 def build_serving_engine(devices: Sequence[jax.Device], model_name: str,
                          buckets: Sequence[int] = (16, 32), rows: int = 8,
                          max_new_tokens: int = 8, serve_dtype: str = "fp32",
@@ -27,8 +39,11 @@ def build_serving_engine(devices: Sequence[jax.Device], model_name: str,
                          mesh_spec: Optional[str] = None,
                          config=None, engine_cls=None,
                          min_positions: int = 0):
-    """(engine, mesh) for a serving config on a pure-DP mesh: the one way
-    the CLI's `serve`, `smoke` and `bench` build an engine. Without
+    """(engine, mesh) for a serving config: the one place a checkpoint
+    template, a mesh and an engine class meet, behind the CLI's `serve`,
+    `smoke` and `bench`. Called bare it builds the forward engine
+    (`InferenceEngine`: BERT, image models; it refuses a causal LM, whose
+    builder is `build_slot_engine`). Without
     ``ckpt_dir`` the weights are random-init (a smoke of the serving
     path, not a served model — the row says so); with it, the
     newest manifest-verified checkpoint restores through the same template
@@ -120,7 +135,7 @@ def build_slot_engine(devices: Sequence[jax.Device], model_name: str,
                       page_size: int = 8, prefix_sharing: bool = True,
                       n_pages: int = 0, prefix_skip: bool = True,
                       serve_dtype: str = "fp32", **kw):
-    """(SlotEngine, mesh) — the token-granular sibling of
+    """(SlotEngine, mesh): a causal LM's engine, through
     `build_serving_engine` (same checkpoint templates, mesh validation and
     sizing; ``**kw`` forwards model_overrides/ckpt_dir/train_config/...).
     The engine decodes over a paged, optionally int8 KV pool

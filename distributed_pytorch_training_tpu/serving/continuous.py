@@ -1,9 +1,7 @@
-"""Token-granular continuous batching over a paged KV cache.
-
-PR 9's engine batches at ITERATION granularity: a group of requests
-enters prefill together, decodes together, and exits together — a short
-request waits for the longest batch-mate, and a new arrival waits for the
-whole cycle. This module rebuilds the decode loop around SLOTS:
+"""Token-granular continuous batching over a paged KV cache: the server
+of a causal LM. Requests join and leave the running batch between tokens,
+so a short request never waits for its longest batch-mate and an arrival
+never waits for a cycle to end. The decode loop is built around SLOTS:
 
 * `SlotEngine` owns ONE compiled decode step over a fixed pool of
   ``rows`` slots plus one compiled prefill per bucket rung. A request is
@@ -28,8 +26,8 @@ whole cycle. This module rebuilds the decode loop around SLOTS:
   ``fold_in(request_key, q)`` — a function of the request alone, so the
   emitted stream is identical regardless of slot assignment, join order,
   or batch company (the determinism satellite pins this).
-  ``temperature=0`` short-circuits to argmax — bitwise the PR 9 greedy
-  path — and a step none of whose live rows samples runs the argmax
+  ``temperature=0`` short-circuits to argmax, and a step none of whose
+  live rows samples runs the argmax
   alone (`sample_tokens` branches on the device).
 * `ContinuousScheduler` is the host loop: admit from the queue
   (``RequestQueue.take`` — FIFO, bucket-blind), run the decode step,
@@ -50,7 +48,7 @@ count — each device decodes its own slots and only the freshly written
 k/v rows all-gather back into the pool (tokens, (L, rows, H, D) — tiny).
 Everything is DONATED through both compiled programs, so each step
 updates in place — the ``serving_paged`` HLO contract (analysis/) pins
-the alias table the same way ``serving_decode`` pins the dense cache's.
+the alias table.
 """
 
 from __future__ import annotations
@@ -80,7 +78,7 @@ from ..parallel.mesh import batch_shard_count
 from ..parallel.sharding import batch_sharding, replicated
 from ..utils.locktrace import named_lock
 from .batching import Request, RequestQueue, Result
-from .engine import InferenceEngine
+from .engine import ServedModel
 from .paged import PagedServeConfig, PageLease, PagePool
 
 
@@ -96,7 +94,7 @@ def sample_tokens(logits: jnp.ndarray, keys: jnp.ndarray,
     OWN key (``keys`` (rows, 2) uint32), so a row's token is a function of
     (its logits, its key, its knobs) alone — batch-mates, slot index, and
     pool size are invisible (the determinism contract). ``temperature <= 0``
-    selects plain argmax — bitwise the dense engine's greedy path.
+    selects plain argmax.
 
     One program, two branches, chosen ON THE DEVICE by the temperatures
     it is handed: when no row has ``temperature > 0`` the argmax is the
@@ -134,22 +132,33 @@ def sample_tokens(logits: jnp.ndarray, keys: jnp.ndarray,
     return jax.lax.cond(jnp.any(temperatures > 0.0), nucleus, greedy_only)
 
 
-class SlotEngine(InferenceEngine):
+class SlotEngine(ServedModel):
     """The compiled half of continuous batching: one paged decode step
     over the whole slot pool, one B=1 paged prefill per bucket, state
     donated and chained device-to-device. ``compiles`` (inherited) is
-    still the census the zero-recompile contract reads: after `warmup`,
+    the census the zero-recompile contract reads: after `warmup`,
     admissions, decode steps, and completions never compile."""
+
+    # whether this engine's step commits a block of positions: what a model
+    # that generates by blocks (``block_length`` > 1) asks of its engine
+    serves_blocks = False
 
     def __init__(self, model, mesh, config: PagedServeConfig, params,
                  batch_stats: Any = None, rules=None):
         if not isinstance(config, PagedServeConfig):
             raise ValueError(
                 "SlotEngine needs a PagedServeConfig (page_size/kv_dtype "
-                "knobs) — plain ServeConfig drives the dense engine")
+                "knobs) — plain ServeConfig drives the forward engine")
+        block = int(getattr(model, "block_length", 1))
+        if block > 1 and not self.serves_blocks:
+            raise ValueError(
+                f"{type(self).__name__} emits a token a step and this model "
+                f"generates by blocks of {block}: "
+                "serving.block_diffusion.BlockDiffusionEngine serves it "
+                "(serving.build.build_slot_engine picks it; ROADMAP R18)")
         super().__init__(model, mesh, config, params,
                          batch_stats=batch_stats, rules=rules)
-        if not self.is_lm:
+        if not hasattr(model, "init_cache"):
             raise ValueError("continuous batching decodes causal LMs only")
         if self.padded_len > model.max_position:
             raise ValueError(
@@ -177,11 +186,6 @@ class SlotEngine(InferenceEngine):
             False if config.fused_quantize is None and mesh.size > 1
             else config.fused_quantize)
         self.reset_state()
-
-    def _validate_rows(self, n_shards: int) -> None:
-        """Slot rows shard over the batch shards when divisible and fall
-        back to replicated otherwise — the slot count is a scheduling
-        knob, never a hard layout constraint; any rows >= 1 works."""
 
     def _row_sharding(self, ndim: int):
         """Sharding for a (rows, ...) slot-state array: leading dim over
@@ -647,9 +651,6 @@ class SlotEngine(InferenceEngine):
                 self._rep_aval((2,), jnp.uint32), scalar_f, scalar_f)
 
     def _executable(self, kind: str, bucket: int):
-        if kind not in ("paged_prefill", "paged_decode", "paged_skip",
-                        "paged_resume"):
-            return super()._executable(kind, bucket)
         key = (kind, bucket)
         if key not in self._compiled:
             lowered = {
@@ -663,10 +664,7 @@ class SlotEngine(InferenceEngine):
                 if kind == "paged_decode" else {}
             if path and self.expert_path:
                 path["expert_path"] = self.expert_path
-            with telemetry.span("compile", program=kind, bucket=bucket,
-                                **path):
-                self._compiled[key] = lowered.compile()
-            self.compiles += 1
+            self._compile(kind, bucket, lowered, **path)
         return self._compiled[key]
 
     def warmup(self) -> int:
@@ -762,14 +760,13 @@ class SlotEngine(InferenceEngine):
 
     def paged_bytes(self) -> int:
         """At-rest bytes of the live paged pool (codes + scales when
-        int8); compare `kv_cache_bytes` (inherited) for the dense fp32
+        int8); compare `dense_baseline_bytes` for the dense fp32
         baseline the >= 3x cut is measured against."""
         return paged_kv_bytes(self._pool)
 
     def dense_baseline_bytes(self) -> int:
-        """What the PR 9 dense engine would hold at this config, fp32: the
-        model's own dense cache (`init_cache`) for every row at full
-        length."""
+        """What a dense cache would hold at this config, fp32: the
+        model's own (`init_cache`) for every row at full length."""
         cfg: PagedServeConfig = self.config
         cache = jax.eval_shape(lambda: self.model.init_cache(
             cfg.rows, max(cfg.buckets) + cfg.max_new_tokens))
@@ -1196,7 +1193,7 @@ class ContinuousScheduler:
 
     def drain(self, log=None) -> int:
         """Finish everything queued + in flight, then return — wrapped in
-        the ``drain`` span like the iteration-granular path."""
+        the ``drain`` span like the forward engine's `batching.drain`."""
         stop = threading.Event()
         stop.set()
         # span attrs are a racy diagnostic snapshot, deliberately taken
@@ -1245,6 +1242,6 @@ SlotEngine.scheduler_cls = ContinuousScheduler
 
 def serve_continuous(engine: SlotEngine, queue: RequestQueue,
                      stop: threading.Event, log=None) -> int:
-    """Drop-in worker-loop twin of ``batching.serve_forever`` for the
-    continuous engine (the CLI runs one per replica thread)."""
+    """The token server's worker loop, with the signature of the forward
+    engine's ``batching.serve_forever`` (`serving smoke` runs either)."""
     return engine.scheduler_cls(engine, queue).run(stop, log=log)

@@ -1,9 +1,10 @@
-"""The load tests behind the CLI's `bench` subcommand: a generator that
-submits seeded mixed-length prompts at a FIXED offered rate to an engine it
-builds through `serving/build.py`, and the row it prints (latency
-percentiles, achieved rate, compile census, contract verdict).
-`chip_smoke.py` phase 3 serves a trained checkpoint through it. The numbers
-that decide a PR come from `benchmark/` (BENCHMARK.json), not from here."""
+"""The load test behind the CLI's `bench` subcommand: a generator that
+submits seeded mixed-length prompts at a FIXED offered rate to the token
+server (engines built through `serving/build.py`, behind the `Router`), and
+the row it prints (latency percentiles, achieved rate, compile census,
+contract verdict). `chip_smoke.py` phase 3 serves a trained checkpoint
+through it. The numbers that decide a PR come from `benchmark/`
+(BENCHMARK.json), not from here."""
 
 from __future__ import annotations
 
@@ -13,158 +14,7 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
-from .build import build_serving_engine, build_slot_engine, build_spec_engine
-
-
-def measure_serving(model_name: str = "gpt2_124m", n_requests: int = 24,
-                    offered_rps: float = 16.0,
-                    buckets: Sequence[int] = (16, 32), rows: int = 8,
-                    max_new_tokens: int = 8, serve_dtype: str = "fp32",
-                    mixed_want: bool = False,
-                    devices: Optional[Sequence[jax.Device]] = None,
-                    model_overrides: Optional[dict] = None,
-                    ckpt_dir: Optional[str] = None, seed: int = 0,
-                    optimizer: str = "auto", momentum: float = 0.9,
-                    weight_decay: float = 5e-4,
-                    train_config=None,
-                    mesh_spec: Optional[str] = None) -> dict:
-    """Serving latency/throughput at FIXED offered load — the row that
-    `serving bench` prints.
-
-    A load generator submits ``n_requests`` mixed-length prompts on a
-    deterministic 1/``offered_rps`` cadence into the request queue while
-    the engine worker drains it (continuous batching); per-request latency
-    is submit -> result. Reports p50/p99 latency, achieved request and
-    token throughput, the engine's compile census
-    (``recompiles_after_warmup`` MUST be 0 — the contract the acceptance
-    test asserts), and the served checkpoint's provenance when one was
-    loaded. Offered load is what the schedule ASKS for; ``achieved_rps``
-    is what the engine absorbed — an overloaded engine shows the gap
-    honestly instead of averaging it away.
-
-    ``mixed_want=True`` is the serving-traffic workload of the
-    continuous-batching A/B: each request WANTS a per-request number of
-    tokens (1..max_new, same rng stream as the token-granular row). The
-    iteration engine has no per-request decode length — every batch
-    member decodes the full ``max_new_tokens`` — so ``tokens_per_sec``
-    counts only the WANTED tokens: the decode cycles spent past a
-    request's want are the convoy waste this mode exists to measure,
-    not throughput to credit.
-    """
-    import threading
-
-    from .batching import RequestQueue, serve_forever
-
-    devices = list(devices) if devices is not None else jax.devices()
-    engine, mesh = build_serving_engine(
-        devices, model_name, buckets=buckets, rows=rows,
-        max_new_tokens=max_new_tokens, serve_dtype=serve_dtype,
-        model_overrides=model_overrides, ckpt_dir=ckpt_dir, seed=seed,
-        optimizer=optimizer, momentum=momentum,
-        weight_decay=weight_decay, train_config=train_config,
-        mesh_spec=mesh_spec)
-    if not engine.is_token:
-        # the load generator submits token prompts; an image engine would
-        # crash mid-warmup with a confusing traceback instead of this
-        raise ValueError(
-            f"serving bench drives token models (gpt2/bert); {model_name} "
-            "serves images — use `serving smoke` or engine.serve_images")
-
-    # warmup: compile every bucket AND execute once per bucket, so the
-    # timed window measures steady state — then pin the compile census
-    engine.warmup()
-    rng = np.random.RandomState(seed)
-    # prompt ids from the SERVED model's vocab (overridden CI models
-    # shrink it below the family default lm_vocab reports)
-    vocab = int(getattr(engine.model, "vocab_size", 0)) or 256
-    for b in engine.config.buckets:
-        engine.serve_tokens([rng.randint(0, max(vocab, 2), b)
-                             .astype(np.int32)])
-    compiles_warm = engine.compiles
-
-    lens = [int(rng.randint(1, max(engine.config.buckets) + 1))
-            for _ in range(n_requests)]
-    prompts = [rng.randint(0, max(vocab, 2), n).astype(np.int32)
-               for n in lens]
-    # drawn AFTER the prompts so both A/B rows (this and
-    # measure_serving_continuous) see identical prompt AND want streams
-    wants = ([int(rng.randint(1, max_new_tokens + 1))
-              for _ in range(n_requests)] if mixed_want
-             else [max_new_tokens] * n_requests)
-    queue = RequestQueue(engine.config.buckets)
-    stop = threading.Event()
-    worker = threading.Thread(target=serve_forever,
-                              args=(engine, queue, stop), daemon=True)
-    worker.start()
-    gap = 1.0 / max(offered_rps, 1e-9)
-    reqs = []
-    t_start = time.perf_counter()
-    for i, p in enumerate(prompts):
-        # fixed offered load: submit on schedule, never "when ready"
-        lag = t_start + i * gap - time.perf_counter()
-        if lag > 0:
-            time.sleep(lag)
-        reqs.append(queue.submit(p))
-    for r in reqs:
-        r.result(timeout=600.0)
-    stop.set()
-    worker.join(timeout=60.0)
-
-    lat_ms = np.array([(r.t_done - r.t_submit) * 1e3 for r in reqs])
-    window_s = max(max(r.t_done for r in reqs) - t_start, 1e-9)
-    recompiles = engine.compiles - compiles_warm
-    row = {
-        "mode": "serving",
-        "model": model_name,
-        "serve_dtype": serve_dtype,
-        "buckets": list(engine.config.buckets),
-        "rows": rows,
-        "max_new_tokens": max_new_tokens,
-        "n_requests": n_requests,
-        "mixed_want": mixed_want,
-        "offered_rps": offered_rps,
-        "achieved_rps": round(n_requests / window_s, 2),
-        "p50_ms": round(float(np.percentile(lat_ms, 50)), 2),
-        "p99_ms": round(float(np.percentile(lat_ms, 99)), 2),
-        "mean_ms": round(float(lat_ms.mean()), 2),
-        # only generating (causal-LM) engines produce tokens; a bert
-        # embedding bench must not report a throughput for tokens that
-        # were never generated. Under mixed_want only the WANTED tokens
-        # count — the engine decoded max_new for everyone regardless
-        **({"tokens_per_sec": round(sum(wants) / window_s, 1)}
-           if engine.is_lm else {}),
-        "compiles": engine.compiles,
-        "recompiles_after_warmup": recompiles,
-        "checkpoint": engine.checkpoint_info,
-    }
-    if serve_dtype == "int8":
-        from .engine import int8_weight_bytes
-
-        row["weight_bytes"] = int8_weight_bytes(engine._served)
-    # per-arm contract verdict, exactly like the training rows: the decode
-    # step of the largest bucket must keep its promises (no host
-    # transfers, cache donated). Decode exists only for causal LMs; a
-    # bert arm records the skip instead of a spurious error. Best-effort
-    # — observability never kills a measurement.
-    if engine.is_lm:
-        try:
-            from ..analysis.hlo_rules import (
-                check_artifacts, serving_artifacts,
-            )
-
-            artifacts = serving_artifacts(
-                engine, max(engine.config.buckets), name="bench-serving")
-            findings = check_artifacts(artifacts)
-            row["contracts"] = {
-                "pass": not findings,
-                "violations": [f.as_dict() for f in findings]}
-        except Exception as e:  # noqa: BLE001
-            row["contracts"] = {"pass": None,
-                                "error": f"{type(e).__name__}: {e}"}
-    else:
-        row["contracts"] = {"pass": None,
-                            "skipped": "no decode step (not a causal LM)"}
-    return row
+from .build import build_slot_engine, build_spec_engine, has_cache
 
 
 def measure_serving_continuous(model_name: str = "gpt2_124m",
@@ -189,10 +39,15 @@ def measure_serving_continuous(model_name: str = "gpt2_124m",
                                weight_decay: float = 5e-4,
                                train_config=None,
                                mesh_spec: Optional[str] = None) -> dict:
-    """Token-granular serving at fixed offered load — the continuous-
-    batching row next to `measure_serving`'s iteration-granular one (same
-    load schedule, same prompts, so the two rows are an apples-to-apples
-    A/B on tok/s and tail latency).
+    """Serving a causal LM at FIXED offered load — the row that `serving
+    bench` prints. A load generator submits ``n_requests`` mixed-length
+    prompts on a deterministic 1/``offered_rps`` cadence; per-request
+    latency is submit -> result. Offered load is what the schedule ASKS
+    for; ``achieved_rps`` is what the server absorbed — an overloaded
+    server shows the gap honestly instead of averaging it away.
+    ``mixed_want`` gives each request its own number of tokens to ask for
+    (1..max_new, seed-pinned): a slot retires at its want, and only the
+    wanted tokens are emitted and credited.
 
     ``replicas`` in-process slot engines sit behind the stdlib `Router`
     (least-depth dispatch, resubmit-on-death); ``kill_replica=True``
@@ -203,8 +58,7 @@ def measure_serving_continuous(model_name: str = "gpt2_124m",
     paged pool's HBM bytes against the dense fp32 baseline
     (``kv_bytes_ratio`` — the int8-paged >= 3x claim is a recorded
     number, not prose) and per-request TTFT percentiles (prefill emits
-    token #0, so TTFT is an admission-latency instrument the
-    iteration-granular engine cannot improve on).
+    token #0, so TTFT is an admission-latency instrument).
 
     ``draft_model`` arms speculative decoding (fp32-only): each replica
     becomes a SpeculativeEngine + SpeculativeScheduler pair, and the row
@@ -218,6 +72,13 @@ def measure_serving_continuous(model_name: str = "gpt2_124m",
     """
     from .router import InProcessReplica, Router
 
+    if not has_cache(model_name, model_overrides):
+        # before any engine is built: the token server would refuse it
+        # only after placing its weights
+        raise ValueError(
+            f"serving bench drives causal LMs through the token server; "
+            f"{model_name} has no cache — `serving smoke` serves it "
+            "through the forward engine (serving.engine.InferenceEngine)")
     if draft_model is not None and kv_dtype != "fp32":
         # fail at the bench boundary with the bench's vocabulary, not
         # three layers down in SpeculativeEngine.__init__
@@ -262,10 +123,8 @@ def measure_serving_continuous(model_name: str = "gpt2_124m",
             for _ in range(n_requests)]
     prompts = [rng.randint(0, max(vocab, 2), n).astype(np.int32)
                for n in lens]
-    # same rng order as measure_serving (lens, prompts, wants): identical
-    # want stream on both sides of the A/B. HERE the wants are honored —
     # a slot retires at its want and the freed capacity admits the next
-    # request, which is the continuous-batching win being measured.
+    # request
     wants = ([int(rng.randint(1, max_new_tokens + 1))
               for _ in range(n_requests)] if mixed_want
              else [max_new_tokens] * n_requests)
@@ -276,8 +135,8 @@ def measure_serving_continuous(model_name: str = "gpt2_124m",
     # (``prefill_skips`` is the census, the warm/cold TTFT split below is
     # the latency receipt). The shared indices are rng-spread over the
     # schedule so warm requests face the same queue depths cold ones do —
-    # the extra draws come AFTER the lens/prompts/wants stream, so the
-    # A/B against measure_serving stays intact.
+    # the extra draws come AFTER the lens/prompts/wants stream, which a
+    # ``shared_frac`` therefore leaves as it was.
     shared_idx: set = set()
     if shared_frac > 0:
         n_shared = int(round(shared_frac * n_requests))
@@ -323,7 +182,7 @@ def measure_serving_continuous(model_name: str = "gpt2_124m",
 
     # submit -> completion wall latency AT THE ROUTER (a resubmitted
     # request's clock keeps running through its replica's death — the retry
-    # is paid, not hidden), same stamps measure_serving reads (Request.t_done)
+    # is paid, not hidden)
     lat_ms = np.array([(d - s) * 1e3 for s, d in zip(sub_at, done_at)])
     ttft_ms = np.array([res.queue_wait_s * 1e3 for res in results])
     window_s = max(max(done_at) - t_start, 1e-9)

@@ -1,6 +1,6 @@
 """Paged KV cache: the host-side page allocator + serving config.
 
-The HBM ceiling of the dense engine is its cache SHAPE: (rows,
+The HBM ceiling of a dense cache is its SHAPE: (rows,
 bucket + max_new, heads, head_dim) per block, live for every slot whether
 it serves a request or not, fp32 always. The paged cache breaks the shape
 into fixed-size pages (models/layers.py `PagedKV`) and makes residency a

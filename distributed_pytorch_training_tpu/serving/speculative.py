@@ -348,9 +348,7 @@ class SpeculativeEngine(SlotEngine):
                 "draft_propose": self.lower_draft_propose,
                 "spec_verify": self.lower_spec_verify,
             }[kind]()
-            with telemetry.span("compile", program=kind, bucket=bucket):
-                self._compiled[key] = lowered.compile()
-            self.compiles += 1
+            self._compile(kind, bucket, lowered)
         return self._compiled[key]
 
     def warmup(self) -> int:

@@ -7,7 +7,7 @@ Commands:
   summary <stream.jsonl> [--json]
       Per-phase step-time split (data_wait / step_dispatch / device_sync /
       save_blocked / eval / restore, the serving phases queue_wait /
-      prefill / decode / drain, and the elastic phases elastic_replan /
+      prefill / slot_wait / drain, and the elastic phases elastic_replan /
       elastic_reshard; `compile` spans show in the spans table but are
       not summed — a lazy compile nests inside the span that triggered
       it), throughput, wire-byte totals, and

@@ -10,7 +10,7 @@ never touches the JSONL and never blocks an emit:
   through ``step_dispatch`` spans;
 * ``dpt_epoch`` — the last completed epoch (``epoch_time_s`` counters);
 * ``dpt_phase_seconds`` — one histogram per canonical phase
-  (data_wait / step_dispatch / ... / prefill / decode), fixed buckets;
+  (data_wait / step_dispatch / ... / prefill / slot_wait), fixed buckets;
 * ``dpt_wire_bytes_total{name,tier,axis}`` — the per-tier wire counters
   (grad_sync's emit_wire_accounting rows; the DCN tier is one more
   label value, not new code);
@@ -19,7 +19,7 @@ never touches the JSONL and never blocks an emit:
   queue depth, EF norm);
 * ``dpt_last_progress_age_seconds`` — seconds since the step fence last
   ADVANCED (a new high-water `step`, a `steps` counter, or a serving
-  prefill/decode span).
+  prefill span).
 
 ``/healthz`` is the progress-fence liveness probe: 200 while the last
 step advance is younger than ``stale_after_s`` (the server's start time
@@ -67,10 +67,6 @@ _PHASES = (SPAN_NAMES + SERVING_SPAN_NAMES + ELASTIC_SPAN_NAMES
 # dispatches to multi-second compiles/stalls.
 _BUCKETS_S = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
               1.0, 2.5, 5.0, 10.0, 30.0)
-
-# step_dispatch feeds the fence only when its `step` ADVANCES (or is
-# unstamped); the serving phases always count — see _MetricsState.observe.
-_PROGRESS_SPANS = ("step_dispatch", "prefill", "decode")
 
 
 def resolve_metrics_port(cli_port: Optional[int], rank: int = 0) -> int:
@@ -157,7 +153,7 @@ class _MetricsState:
                     # a re-dispatch of an already-seen step (a restart
                     # loop replaying from a checkpoint) is NOT progress:
                     # the fence must ADVANCE to keep /healthz green
-                elif name in ("prefill", "decode"):
+                elif name == "prefill":
                     # serving progress: every served phase counts
                     self.last_progress = time.monotonic()
             elif kind == "counter":

@@ -77,10 +77,11 @@ SPAN_NAMES = ("data_wait", "step_dispatch", "device_sync", "eval",
               "save_blocked", "restore")
 
 # The serving phases (serving/): how long a request queued, the prefill
-# and decode dispatch walls, and the shutdown drain. `telemetry summary`
-# buckets these exactly like the training phases — a serving stream's
-# latency story decomposes instead of lumping into "unaccounted".
-# The continuous-batching path (ISSUE 17) adds two host-side phases:
+# dispatch wall (the forward engine's whole forward), and the shutdown
+# drain. `telemetry summary` buckets these exactly like the training
+# phases — a serving stream's latency story decomposes instead of lumping
+# into "unaccounted".
+# The token server (ISSUE 17) adds two host-side phases:
 # `slot_wait` (popped from the queue -> admitted into a slot — the
 # pool/page-pressure share of latency, distinct from queue_wait's
 # load share) and `router_dispatch` (the multi-replica router's pick +
@@ -90,7 +91,7 @@ SPAN_NAMES = ("data_wait", "step_dispatch", "device_sync", "eval",
 # `prefill_skip` (a prefix-resident admission that dispatched NO
 # prefill — its near-zero wall IS the TTFT win, and its count is the
 # zero-dispatch census the skip test pins).
-SERVING_SPAN_NAMES = ("queue_wait", "prefill", "decode", "drain",
+SERVING_SPAN_NAMES = ("queue_wait", "prefill", "drain",
                       "slot_wait", "router_dispatch", "draft_decode",
                       "spec_verify", "prefill_skip")
 
@@ -103,7 +104,7 @@ SERVING_SPAN_NAMES = ("queue_wait", "prefill", "decode", "drain",
 # engine's per-program AOT instrument — with the persistent compile cache
 # on it collapses to cache-load time, the restart-downtime win) is
 # deliberately NOT in this accounting list: a lazy compile runs INSIDE
-# the prefill/decode/step_dispatch span that triggered it, so summing it
+# the prefill/step_dispatch span that triggered it, so summing it
 # as its own phase would double-count the same wall time; it stays
 # visible in the summary's spans table under its own name.
 ELASTIC_SPAN_NAMES = ("elastic_replan", "elastic_reshard", "elastic_grow",

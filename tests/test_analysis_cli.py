@@ -28,21 +28,19 @@ def test_analysis_check_json_exits_0_on_repo(capsys, devices):
                              "zero1_int8_hier",
                              "fsdp", "fsdp_accum", "fsdp_int8_mh",
                              "fsdp_tp", "fsdp_tp_int8_mh",
-                             "serving_decode", "serving_paged",
-                             "serving_spec",
+                             "serving_paged", "serving_spec",
                              "control_replan",
                              "elastic_reshard",
                              "elastic_grow"}
     assert all(s == "pass" for s in statuses.values()), statuses
     # both engines actually ran, incl. the fsdp rules (ISSUE 7), the
-    # serving decode-step rules (ISSUE 10), the elastic census pins in
+    # serving decode-loop rule (ISSUE 10), the elastic census pins in
     # BOTH directions (ISSUEs 11 + 12), the 2-D TP x FSDP rules
     # (ISSUE 13), the two-tier hier wire rules (ISSUE 16), and the paged
     # serving pool donation rule (ISSUE 17)
     kinds = {r for r in report["rules_run"]}
     assert "shard-map-shim-only" in kinds and "zero1-collectives" in kinds
     assert "fsdp-layer-gather-bound" in kinds
-    assert "decode-cache-donated" in kinds
     assert "no-host-sync-in-decode" in kinds
     assert "elastic-reshard-census" in kinds
     assert "elastic-grow-census" in kinds

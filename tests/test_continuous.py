@@ -1100,6 +1100,23 @@ class TestPagedContract:
         found = check_artifacts(poisoned, rules=["paged-pool-donated"])
         assert len(found) == 1
 
+    def test_mutation_host_transfer_in_decode_flags(self, slot_engine):
+        """The train step's no-host-transfer rule binds on the decode
+        step's artifacts: a callback smuggled into its text is flagged
+        with NO rule relaxation."""
+        import dataclasses as dc
+
+        from distributed_pytorch_training_tpu.analysis.hlo_rules import (
+            check_artifacts, paged_serving_artifacts,
+        )
+
+        artifacts = paged_serving_artifacts(slot_engine)
+        poisoned = dc.replace(
+            artifacts, optimized_text=artifacts.optimized_text +
+            '\n  custom-call(), custom_call_target="xla_python_cpu_callback"')
+        found = check_artifacts(poisoned, rules=["no-host-transfer"])
+        assert len(found) == 1
+
 
 # ---------------------------------------------------------------------------
 # Router unit semantics (no devices)
@@ -1490,7 +1507,7 @@ class TestFleetAcceptance:
 
 @pytest.mark.slow
 def test_cli_bench_continuous_exits_zero(tmp_path):
-    """`serving bench --continuous --mixed-want` runs the offered-load
+    """`serving bench --mixed-want` runs the offered-load
     row end to end and exits 0 iff recompiles_after_warmup == 0 (the
     hard gate the fleet bench arms reuse)."""
     import os
@@ -1500,7 +1517,7 @@ def test_cli_bench_continuous_exits_zero(tmp_path):
     out = subprocess.run(
         [sys.executable, "-m",
          "distributed_pytorch_training_tpu.serving", "bench",
-         "--continuous", "--mixed-want",
+         "--mixed-want",
          "--model", "gpt2_124m",
          "--model-overrides", "hidden_dim=32,depth=2,num_heads=2",
          "--buckets", "8,16", "--rows", "8", "--max-new-tokens", "4",

@@ -1,4 +1,5 @@
-"""serving/ — manifest-verified batched inference engine (ISSUE 10).
+"""serving/ — what every engine stands on, the forward engine, and the
+CLI's load test (ISSUE 10; one server for a causal LM since ISSUE 51).
 
 Pins, in order:
 * the cache-aware GPT-2 forward leaves the no-cache training path
@@ -6,15 +7,19 @@ Pins, in order:
 * prefill logits match the full-context forward BITWISE in fp32; decode
   logits, and mixed-length batches vs solo forwards, within 8 eps of the
   largest logit (`assert_logits_match`: other XLA:CPU programs);
-* fp32 served logits are bitwise the (compiled, sharded) eval forward —
-  the acceptance criterion;
-* zero recompiles across >= 20 mixed-length requests within the bucket
-  ladder (the compile-count census);
-* int8 weight serving reuses the wire-codec grid (bound + grid match);
-* the request queue / continuous batcher / drain semantics;
-* the serving decode HLO contract + the two new analysis rules
-  (mutation-tested, per the checker's own standard);
-* `measure_serving` (the CLI's `bench` row) and the slow CLI e2e.
+* a causal LM has ONE server: the forward engine refuses it by name of
+  its builder, and a token engine carries nothing of the forward engine;
+* int8 weight serving reuses the wire-codec grid (bound + grid match) and
+  serves through the token engine;
+* a manifest-verified checkpoint serves through the token engine, torn
+  ones skipped as a resume would skip them;
+* the request queue, the forward engine's loop and its drain, on BERT;
+* the `no-host-sync-in-decode` AST rule (mutation-tested);
+* `measure_serving_continuous` (the CLI's `bench` row), `serving smoke` in
+  process, and the slow CLI e2e.
+
+The token engine's own pins (bitwise streams, zero recompiles, the
+``serving_paged`` contract) are tests/test_continuous.py's.
 """
 
 import json
@@ -22,7 +27,6 @@ import subprocess
 import sys
 import textwrap
 import threading
-import time
 from pathlib import Path
 
 import jax
@@ -30,21 +34,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_pytorch_training_tpu.data.pack import pack_token_rows
+from distributed_pytorch_training_tpu.models import get_model
 from distributed_pytorch_training_tpu.models.gpt2 import GPT2LMHead
-from distributed_pytorch_training_tpu.parallel.sharding import shard_batch
 from distributed_pytorch_training_tpu.serving import (
-    InferenceEngine, QuantizedLeaf, RequestQueue, ServeConfig,
-    dequantize_params, drain, int8_weight_bytes, quantize_params,
-    serve_forever,
+    ContinuousScheduler, InferenceEngine, PagedServeConfig, QuantizedLeaf,
+    RequestQueue, ServeConfig, SlotEngine, dequantize_params, drain,
+    int8_weight_bytes, quantize_params, serve_forever,
 )
 
 VOCAB = 97
+TINY = dict(hidden_dim=32, depth=2, num_heads=2, vocab_size=VOCAB)
 
 
 def tiny_model(**kw):
-    cfg = dict(vocab_size=VOCAB, hidden_dim=32, depth=2, num_heads=2,
-               max_position=64)
+    cfg = dict(max_position=64, **TINY)
     cfg.update(kw)
     return GPT2LMHead(**cfg)
 
@@ -58,11 +61,19 @@ def tiny(mesh8):
 
 
 @pytest.fixture(scope="module")
-def engine(mesh8, tiny):
-    model, params = tiny
-    eng = InferenceEngine(
-        model, mesh8,
-        ServeConfig(buckets=(8, 16), rows=8, max_new_tokens=4), params)
+def bert(mesh8):
+    """A tiny BERT: the token model WITHOUT a cache, the forward engine's."""
+    model = get_model("bert_base", mlp_dim=64, max_position=64, **TINY)
+    params = model.init(jax.random.PRNGKey(0), np.zeros((1, 8), np.int32),
+                        train=False)["params"]
+    return model, params
+
+
+@pytest.fixture(scope="module")
+def engine(mesh8, bert):
+    model, params = bert
+    eng = InferenceEngine(model, mesh8, ServeConfig(buckets=(8, 16), rows=8),
+                          params)
     eng.warmup()
     return eng
 
@@ -70,6 +81,21 @@ def engine(mesh8, tiny):
 def prompts(ns, seed=0):
     rng = np.random.RandomState(seed)
     return [rng.randint(0, VOCAB, n).astype(np.int32) for n in ns]
+
+
+def paged_cfg(**kw):
+    cfg = dict(buckets=(8,), rows=8, max_new_tokens=2, page_size=4)
+    cfg.update(kw)
+    return PagedServeConfig(**cfg)
+
+
+def serve_all(eng, seqs, **kw):
+    """Every prompt through a fresh scheduler over ``eng``, drained."""
+    q = RequestQueue(eng.config.buckets)
+    sched = ContinuousScheduler(eng, q)
+    reqs = [q.submit(p, **kw) for p in seqs]
+    sched.drain()
+    return [r.result(timeout=300.0) for r in reqs]
 
 
 # ---------------------------------------------------------------------------
@@ -261,76 +287,86 @@ class TestCacheForward:
 
 
 # ---------------------------------------------------------------------------
-# The engine: acceptance pins
+# One server for a causal LM, a forward engine for the rest
 # ---------------------------------------------------------------------------
 
 
 class TestEngine:
-    def test_served_logits_bitwise_eval_forward(self, mesh8, tiny, engine):
-        """ACCEPTANCE: fp32 served logits == the compiled, sharded eval
-        forward, bitwise, for the same (padded) inputs."""
+    def test_forward_engine_refuses_a_causal_lm(self, mesh8, tiny):
+        """A model with a cache has one server; the refusal names the
+        builder of its engine."""
         model, params = tiny
+        with pytest.raises(ValueError, match="build_slot_engine"):
+            InferenceEngine(model, mesh8, ServeConfig(buckets=(8,), rows=8),
+                            params)
+
+    def test_token_engine_carries_nothing_of_the_forward_engine(self):
+        from distributed_pytorch_training_tpu.serving.block_diffusion import (
+            BlockDiffusionEngine,
+        )
+        from distributed_pytorch_training_tpu.serving.engine import (
+            ServedModel,
+        )
+        from distributed_pytorch_training_tpu.serving.speculative import (
+            SpeculativeEngine,
+        )
+
+        assert SlotEngine.__mro__ == (SlotEngine, ServedModel, object)
+        for cls in (SlotEngine, BlockDiffusionEngine, SpeculativeEngine):
+            for name in ("serve_tokens", "lower_decode", "generate",
+                         "serve_images", "kv_cache_bytes"):
+                assert not hasattr(cls, name), (cls.__name__, name)
+        # and the base knows neither of its engines' models
+        assert not hasattr(ServedModel, "serves_blocks")
+
+    def test_forward_engine_serves_the_eval_forward_bitwise(self, mesh8,
+                                                            bert, engine):
+        """fp32 served logits == the compiled, sharded eval forward,
+        bitwise, for the same (padded) inputs; nothing is generated; a
+        request served alone and packed with company reads the same."""
+        from distributed_pytorch_training_tpu.data.pack import (
+            pack_token_rows,
+        )
+        from distributed_pytorch_training_tpu.parallel.sharding import (
+            shard_batch,
+        )
+
+        model, _params = bert
         seqs = prompts((3, 8, 5))
-        ids, lengths, _ = pack_token_rows(seqs, 8, engine.config.rows)
-        ev = jax.jit(
+        ids, _lengths, _ = pack_token_rows(seqs, 8, engine.config.rows)
+        ev = np.asarray(jax.jit(
             lambda p, i: model.apply({"params": p}, i, train=False)
-        )(engine._served, shard_batch(ids, mesh8))
-        ev = np.asarray(ev)
-        for i, res in enumerate(engine.serve_tokens(
-                seqs, return_prompt_logits=True)):
+        )(engine._served, shard_batch(ids, mesh8)))
+        before = engine.compiles
+        packed = engine.serve_tokens(seqs, return_prompt_logits=True)
+        for i, res in enumerate(packed):
             L = len(seqs[i])
-            assert res.prompt_logits.shape == (L, VOCAB)
+            assert res.tokens.size == 0
             assert (res.prompt_logits == ev[i, :L]).all(), f"request {i}"
             np.testing.assert_array_equal(res.last_logits, ev[i, L - 1])
-
-    def test_zero_recompiles_across_20_mixed_requests(self, engine):
-        """ACCEPTANCE: >= 20 mixed-length requests inside the bucket
-        ladder reuse the warmup executables — the compile census stays
-        flat."""
-        rng = np.random.RandomState(7)
-        # execution warmup (compiles already done by the fixture's warmup)
-        engine.serve_tokens(prompts((4,)))
-        before = engine.compiles
-        for i in range(20):
-            n = int(rng.randint(1, 17))
-            res = engine.serve_tokens(
-                [rng.randint(0, VOCAB, n).astype(np.int32)])
-            assert res[0].tokens.shape == (4,)
+        solo = engine.serve_tokens([seqs[0]], return_prompt_logits=True)[0]
+        np.testing.assert_array_equal(solo.prompt_logits,
+                                      packed[0].prompt_logits)
         assert engine.compiles == before, "a request triggered a recompile"
 
-    def test_packed_batch_equals_solo_serve(self, engine):
-        """No cross-request leakage: a request served alone and served
-        packed with unrelated company produces identical logits and
-        tokens."""
-        seqs = prompts((5, 8, 2), seed=11)
-        solo = engine.serve_tokens([seqs[0]], return_prompt_logits=True)[0]
-        packed = engine.serve_tokens(seqs, return_prompt_logits=True)[0]
-        np.testing.assert_array_equal(solo.prompt_logits,
-                                      packed.prompt_logits)
-        np.testing.assert_array_equal(solo.tokens, packed.tokens)
-
-    def test_greedy_tokens_consistent_with_logits(self, engine):
-        res = engine.serve_tokens(prompts((6,)),
-                                  return_prompt_logits=True)[0]
-        assert res.tokens[0] == int(np.argmax(res.last_logits))
-
-    def test_config_validation(self, mesh8, tiny):
+    def test_config_validation(self, mesh8, tiny, bert):
         model, params = tiny
-        with pytest.raises(ValueError, match="divide over the mesh"):
-            InferenceEngine(model, mesh8,
-                            ServeConfig(buckets=(8,), rows=3), params)
-        with pytest.raises(ValueError, match="max_position"):
-            InferenceEngine(
-                model, mesh8,
-                ServeConfig(buckets=(64,), rows=8, max_new_tokens=8),
-                params)
         with pytest.raises(ValueError, match="serve_dtype"):
             ServeConfig(serve_dtype="fp16")
+        with pytest.raises(ValueError, match="divide over the mesh"):
+            InferenceEngine(bert[0], mesh8,
+                            ServeConfig(buckets=(8,), rows=3), bert[1])
+        with pytest.raises(ValueError, match="PagedServeConfig"):
+            SlotEngine(model, mesh8, ServeConfig(buckets=(8,), rows=8),
+                       params)
+        with pytest.raises(ValueError, match="max_position"):
+            SlotEngine(model, mesh8,
+                       paged_cfg(buckets=(64,), max_new_tokens=8), params)
+        with pytest.raises(ValueError, match="causal LMs only"):
+            SlotEngine(bert[0], mesh8, paged_cfg(), bert[1])
         with pytest.raises(ValueError, match="exceeds the largest bucket"):
-            InferenceEngine(
-                model, mesh8, ServeConfig(buckets=(8,), rows=8,
-                                          max_new_tokens=4),
-                params).serve_tokens(prompts((9,)))
+            SlotEngine(model, mesh8, paged_cfg(), params).admit(
+                0, prompts((9,))[0], 2, 0.0, 1.0, 0)
 
 
 class TestInt8Serving:
@@ -399,15 +435,21 @@ class TestInt8Serving:
             np.asarray(dequantize_params(served)), w)
 
     def test_int8_engine_serves_and_saves_bytes(self, mesh8, tiny):
+        """int8 WEIGHTS through the token engine: it serves, at under 1/2.5
+        of the float32 bytes, and its logits are the float32 engine's
+        within the codec's reach."""
         model, params = tiny
-        eng = InferenceEngine(
+        eng = SlotEngine(
             model, mesh8,
-            ServeConfig(buckets=(8,), rows=8, max_new_tokens=2,
-                        serve_dtype="int8", quantize_min_elements=64),
-            params)
-        res = eng.serve_tokens(prompts((5,)), return_prompt_logits=True)[0]
-        assert res.prompt_logits.shape == (5, VOCAB)
-        assert np.isfinite(res.prompt_logits).all()
+            paged_cfg(serve_dtype="int8", quantize_min_elements=64), params)
+        (res,) = serve_all(eng, prompts((5,)))
+        assert res.tokens.shape == (2,)
+        assert np.isfinite(res.last_logits).all()
+        (ref,) = serve_all(SlotEngine(model, mesh8, paged_cfg(), params),
+                           prompts((5,)))
+        assert not np.array_equal(res.last_logits, ref.last_logits)
+        np.testing.assert_allclose(res.last_logits, ref.last_logits,
+                                   atol=0.05 * np.abs(ref.last_logits).max())
         acct = int8_weight_bytes(eng._served)
         fp32_bytes = sum(4 * l.size
                          for l in jax.tree_util.tree_leaves(params))
@@ -453,23 +495,24 @@ class TestCheckpointServing:
 
         model = tiny_model()
         state = self._save_state(mesh8, model, tmp_path, labels=(1,))
-        eng = InferenceEngine.from_checkpoint(
-            str(tmp_path), model, mesh8,
-            ServeConfig(buckets=(8,), rows=8, max_new_tokens=2),
+        eng = SlotEngine.from_checkpoint(
+            str(tmp_path), model, mesh8, paged_cfg(),
             sgd(0.1), np.zeros((1, 8), np.int32))
         info = eng.checkpoint_info
         assert info["label"] == 1 and info["verified"]
         assert isinstance(info["tree_digest"], str) \
             and len(info["tree_digest"]) == 64
-        # served logits come from the RESTORED params, bitwise
+        # served weights ARE the restored params, and what they serve is
+        # what an engine built over those params serves, bitwise
+        jax.tree_util.tree_map(np.testing.assert_array_equal,
+                               jax.device_get(eng._served),
+                               jax.device_get(state.params))
         seqs = prompts((6,))
-        ids, _, _ = pack_token_rows(seqs, 8, 8)
-        ev = jax.jit(lambda p, i: model.apply(
-            {"params": p}, i, train=False))(
-            state.params, shard_batch(ids, mesh8))
-        res = eng.serve_tokens(seqs, return_prompt_logits=True)[0]
-        np.testing.assert_array_equal(res.prompt_logits,
-                                      np.asarray(ev)[0, :6])
+        (res,) = serve_all(eng, seqs)
+        (ref,) = serve_all(
+            SlotEngine(model, mesh8, paged_cfg(), state.params), seqs)
+        np.testing.assert_array_equal(res.last_logits, ref.last_logits)
+        np.testing.assert_array_equal(res.tokens, ref.tokens)
 
     def test_torn_newest_falls_back_to_previous(self, mesh8, tmp_path):
         """Serving inherits the manifest-verified restore exactly: a torn
@@ -483,9 +526,8 @@ class TestCheckpointServing:
         victims = [p for p in (tmp_path / "2").rglob("*")
                    if p.is_file() and p.stat().st_size > 64]
         victims[0].write_bytes(b"torn")
-        eng = InferenceEngine.from_checkpoint(
-            str(tmp_path), model, mesh8,
-            ServeConfig(buckets=(8,), rows=8, max_new_tokens=2),
+        eng = SlotEngine.from_checkpoint(
+            str(tmp_path), model, mesh8, paged_cfg(),
             sgd(0.1), np.zeros((1, 8), np.int32))
         assert eng.checkpoint_info["label"] == 1
 
@@ -493,14 +535,13 @@ class TestCheckpointServing:
         from distributed_pytorch_training_tpu.training.optim import sgd
 
         with pytest.raises(FileNotFoundError, match="no restorable"):
-            InferenceEngine.from_checkpoint(
-                str(tmp_path / "empty"), tiny_model(), mesh8,
-                ServeConfig(buckets=(8,), rows=8, max_new_tokens=2),
+            SlotEngine.from_checkpoint(
+                str(tmp_path / "empty"), tiny_model(), mesh8, paged_cfg(),
                 sgd(0.1), np.zeros((1, 8), np.int32))
 
 
 # ---------------------------------------------------------------------------
-# Queue + continuous batching + drain
+# The queue, and the forward engine's loop and drain (on BERT)
 # ---------------------------------------------------------------------------
 
 
@@ -544,9 +585,10 @@ class TestBatching:
             t.start()
         for t in threads:
             t.join()
+        assert len(reqs) == 9
         for r in reqs:
             res = r.result(timeout=120.0)
-            assert res.tokens.shape == (engine.config.max_new_tokens,)
+            assert res.last_logits.shape == (VOCAB,)
             assert r.t_done is not None
         stop.set()
         worker.join(timeout=30.0)
@@ -558,7 +600,7 @@ class TestBatching:
         served = drain(engine, q)
         assert served == 2
         for r in pending:
-            assert r.result(timeout=1.0).tokens.size
+            assert np.isfinite(r.result(timeout=1.0).last_logits).all()
         with pytest.raises(RuntimeError, match="closed"):
             q.submit(np.ones(4, np.int32))
 
@@ -583,117 +625,56 @@ class TestBatching:
         with pytest.raises(RuntimeError, match="injected"):
             bad.result(timeout=60.0)
         good = q.submit(np.ones(4, np.int32))
-        assert good.result(timeout=60.0).tokens.size
+        assert good.result(timeout=60.0).last_logits.shape == (VOCAB,)
         stop.set()
         worker.join(timeout=30.0)
 
 
 # ---------------------------------------------------------------------------
-# The decode-step contract + the new analysis rules (mutation-tested)
+# The decode loop's AST rule (mutation-tested); the decode-step HLO contract
+# is ``serving_paged`` (tests/test_continuous.py::TestPagedContract)
 # ---------------------------------------------------------------------------
 
 
 class TestServingContract:
-    def test_serving_decode_contract_passes_on_mesh(self, mesh8):
-        from distributed_pytorch_training_tpu.analysis.hlo_rules import (
-            check_artifacts, evaluate_contract,
-        )
-        from distributed_pytorch_training_tpu.analysis.contracts import (
-            get_contract,
-        )
-
-        artifacts = evaluate_contract(get_contract("serving_decode"),
-                                      mesh=mesh8)
-        findings = check_artifacts(artifacts)
-        assert findings == [], [str(f) for f in findings]
-
-    def test_live_engine_artifacts_pass(self, engine):
-        from distributed_pytorch_training_tpu.analysis.hlo_rules import (
-            check_artifacts, serving_artifacts,
-        )
-
-        artifacts = serving_artifacts(engine, 16)
-        assert check_artifacts(artifacts) == []
-        assert artifacts.config["decode_cache_leaves"] == 4
-
-    def test_mutation_missing_alias_entries_flag(self):
-        from distributed_pytorch_training_tpu.analysis.hlo_rules import (
-            StepArtifacts, check_artifacts,
-        )
-
-        partial = StepArtifacts(
-            name="mut", optimized_text=(
-                "HloModule decode, input_output_alias={ {0}: (28, {}, "
-                "may-alias) }, entry_computation_layout={()}"),
-            config={"serving_decode": True, "donate_state": True,
-                    "decode_cache_leaves": 4})
-        found = check_artifacts(partial, rules=["decode-cache-donated"])
-        assert len(found) == 1 and "1 of the 4" in found[0].message
-        absent = StepArtifacts(
-            name="mut2", optimized_text="HloModule decode",
-            config={"serving_decode": True, "donate_state": True,
-                    "decode_cache_leaves": 4})
-        assert check_artifacts(absent, rules=["decode-cache-donated"])
-        # non-serving artifacts are out of scope
-        train = StepArtifacts(name="t", optimized_text="HloModule x",
-                              config={"donate_state": False})
-        assert check_artifacts(train, rules=["decode-cache-donated"]) == []
-
-    def test_mutation_host_transfer_in_decode_flags(self, engine):
-        """The existing no-host-transfer rule binds on serving artifacts:
-        a callback smuggled into the decode text is flagged with NO rule
-        relaxation."""
-        import dataclasses as dc
-
-        from distributed_pytorch_training_tpu.analysis.hlo_rules import (
-            check_artifacts, serving_artifacts,
-        )
-
-        artifacts = serving_artifacts(engine, 8)
-        poisoned = dc.replace(
-            artifacts, optimized_text=artifacts.optimized_text +
-            '\n  custom-call(), custom_call_target="xla_python_cpu_callback"')
-        found = check_artifacts(poisoned, rules=["no-host-transfer"])
-        assert len(found) == 1
-
     def test_mutation_ast_host_sync_in_decode_flags(self, tmp_path):
         from distributed_pytorch_training_tpu.analysis.ast_rules import (
             run_ast_rules,
         )
 
-        path = tmp_path / "serving" / "engine.py"
+        path = tmp_path / "serving" / "continuous.py"
         path.parent.mkdir(parents=True)
         path.write_text(textwrap.dedent("""
             import jax
 
-            def generate(self, cache, tok):
-                for _ in range(4):
-                    tok = jax.device_get(tok)
+            def _step_decode_loop(self, steps):
+                for _ in range(steps):
+                    tok = jax.device_get(self.tok)
                 return tok
 
-            def serve_tokens(self, seqs):
-                return jax.device_get(seqs)  # legal: after the loop
+            def _complete_finished(self, slots):
+                return jax.device_get(slots)  # legal: after the loop
         """))
         found = run_ast_rules(files=[path],
                               rules=["no-host-sync-in-decode"])
-        assert len(found) == 1 and "generate" in found[0].message
+        assert len(found) == 1 and "_step_decode_loop" in found[0].message
 
     def test_ast_rule_scopes_to_decode_loop_only(self, tmp_path):
         from distributed_pytorch_training_tpu.analysis.ast_rules import (
             run_ast_rules,
         )
 
-        path = tmp_path / "serving" / "engine.py"
+        path = tmp_path / "serving" / "continuous.py"
         path.parent.mkdir(parents=True)
         path.write_text(textwrap.dedent("""
             import jax
 
-            def serve_tokens(self, seqs):
-                return jax.device_get(seqs)
+            def _complete_finished(self, slots):
+                return jax.device_get(slots)
         """))
         assert run_ast_rules(files=[path],
                              rules=["no-host-sync-in-decode"]) == []
-        # and the real engine passes its own rule
+        # and the real scheduler passes its own rule
         assert run_ast_rules(rules=["no-host-sync-in-decode"]) == []
 
 
@@ -711,12 +692,12 @@ class TestServingTelemetry:
         events = [{"kind": "meta", "name": "stream", "schema": 1,
                    "run_id": "r"}]
         for name, ms in (("queue_wait", 5.0), ("prefill", 20.0),
-                         ("decode", 60.0), ("drain", 2.0)):
+                         ("slot_wait", 60.0), ("drain", 2.0)):
             events.append({"kind": "span", "name": name, "t0": 0.0,
                            "dur_ms": ms})
         s = summarize(events)
         assert set(s["step_split_pct"]) == {"queue_wait", "prefill",
-                                            "decode", "drain"}
+                                            "slot_wait", "drain"}
         assert abs(sum(s["step_split_pct"].values()) - 100.0) < 0.1
 
     def test_engine_emits_serving_spans(self, engine, tmp_path):
@@ -736,60 +717,131 @@ class TestServingTelemetry:
         events, bad = read_stream(str(stream))
         assert bad == 0
         names = {e["name"] for e in events if e.get("kind") == "span"}
-        assert {"queue_wait", "prefill", "decode", "drain"} <= names
+        assert {"queue_wait", "prefill", "drain"} <= names
+        assert "decode" not in names    # the forward engine generates none
 
 
 # ---------------------------------------------------------------------------
-# The bench row (fixed offered load) — the acceptance instrument
+# The bench row (fixed offered load) and `serving smoke`, in process
 # ---------------------------------------------------------------------------
+
+# the row `serving bench --json` prints; `chip_smoke.py` phase 3 reads it
+BENCH_ROW_KEYS = {
+    "achieved_rps", "backend", "buckets", "checkpoint", "compiles",
+    "completed", "contracts", "dense_kv_bytes", "draft", "granularity",
+    "kv_bytes_ratio", "kv_dtype", "max_new_tokens", "mean_ms", "mixed_want",
+    "mode", "model", "n_requests", "offered_rps", "p50_ms", "p99_ms",
+    "page_size", "paged_kv_bytes", "per_replica", "prefill_skips",
+    "prefix_skip", "recompiles_after_warmup", "replica_deaths", "replicas",
+    "rows", "shared_frac", "tail_resumes", "tokens", "tokens_per_sec",
+    "ttft_p50_ms", "ttft_p99_ms"}
+
+BENCH_N, BENCH_NEW, BENCH_BUCKETS = 12, 4, (8, 16)
+
+
+def bench_wants(seed=0):
+    """The per-request wants `mixed_want` draws: the load test's own rng
+    stream (lengths, prompts, then wants)."""
+    rng = np.random.RandomState(seed)
+    lens = [int(rng.randint(1, max(BENCH_BUCKETS) + 1))
+            for _ in range(BENCH_N)]
+    for n in lens:
+        rng.randint(0, VOCAB, n)
+    return [int(rng.randint(1, BENCH_NEW + 1)) for _ in range(BENCH_N)]
+
+
+def holds_schema(row):
+    assert set(row) == BENCH_ROW_KEYS
+    assert row["mode"] == "serving_continuous"
+    assert row["p50_ms"] > 0 and row["p99_ms"] >= row["p50_ms"]
+    assert row["achieved_rps"] > 0 and row["tokens_per_sec"] > 0
+    assert row["tokens"] == BENCH_N * BENCH_NEW
+    assert row["contracts"]["pass"] is True, row["contracts"]
+    assert row["checkpoint"] is None  # random-init smoke, says so
+
+
+def credits_the_wanted_tokens(row):
+    wants = bench_wants()
+    assert len(set(wants)) > 1
+    assert row["tokens"] == sum(wants) < BENCH_N * BENCH_NEW
+
+
+def skips_resident_prefills(row):
+    assert row["prefill_skips"] > 0
+    assert "ttft_warm_p50_ms" in row and "ttft_cold_p50_ms" in row
+
+
+def cuts_the_pool_by_three(row):
+    assert row["kv_bytes_ratio"] >= 3
+    assert row["kv_codec"] == "xla"     # a mesh of eight: no Mosaic codec
 
 
 class TestMeasureServing:
-    def test_bench_row_schema_and_zero_recompiles(self, mesh8, devices):
+    @pytest.mark.parametrize("kw,holds", [
+        (dict(), holds_schema),
+        (dict(mixed_want=True), credits_the_wanted_tokens),
+        (dict(shared_frac=0.5), skips_resident_prefills),
+        (dict(kv_dtype="int8"), cuts_the_pool_by_three),
+    ], ids=["schema", "mixed_want", "shared_frac", "int8_pages"])
+    def test_bench_row(self, mesh8, devices, kw, holds):
         from distributed_pytorch_training_tpu.serving.loadtest import (
-            measure_serving,
+            measure_serving_continuous,
         )
 
-        row = measure_serving(
-            model_name="gpt2_124m", n_requests=20, offered_rps=200.0,
-            buckets=(8, 16), rows=8, max_new_tokens=2,
-            devices=devices,
-            model_overrides=dict(hidden_dim=32, depth=2, num_heads=2,
-                              vocab_size=VOCAB, max_position=32))
-        assert row["mode"] == "serving"
-        assert row["n_requests"] == 20
+        row = measure_serving_continuous(
+            model_name="gpt2_124m", n_requests=BENCH_N, offered_rps=200.0,
+            buckets=BENCH_BUCKETS, rows=8, max_new_tokens=BENCH_NEW,
+            page_size=4, devices=devices,
+            model_overrides=dict(max_position=32, **TINY), **kw)
+        assert row["completed"] == row["n_requests"] == BENCH_N
         assert row["recompiles_after_warmup"] == 0
-        assert row["p50_ms"] > 0 and row["p99_ms"] >= row["p50_ms"]
-        assert row["achieved_rps"] > 0 and row["tokens_per_sec"] > 0
-        assert row["contracts"]["pass"] is True, row["contracts"]
-        assert row["checkpoint"] is None  # random-init smoke, says so
+        holds(row)
 
-    def test_bench_rejects_image_models_upfront(self, devices):
-        from distributed_pytorch_training_tpu.serving.loadtest import (
-            measure_serving,
-        )
+    @pytest.mark.parametrize("model_name", ["resnet18", "bert_base"])
+    def test_bench_refuses_a_model_without_a_cache_upfront(
+            self, devices, model_name, monkeypatch):
+        """Before any engine is built: the builders are never reached."""
+        from distributed_pytorch_training_tpu.serving import loadtest
 
-        with pytest.raises(ValueError, match="serves images"):
-            measure_serving(model_name="resnet18", n_requests=1,
-                            devices=devices)
+        def unreachable(*a, **kw):
+            raise AssertionError("an engine was built")
 
-    def test_bert_bench_reports_no_phantom_tokens(self, mesh8, devices):
-        """A bert (embedding) bench generates nothing: the row must not
-        report a tokens_per_sec, and the decode contract reads as skipped
-        rather than error."""
-        from distributed_pytorch_training_tpu.serving.loadtest import (
-            measure_serving,
-        )
+        monkeypatch.setattr(loadtest, "build_slot_engine", unreachable)
+        with pytest.raises(ValueError, match="has no cache"):
+            loadtest.measure_serving_continuous(
+                model_name=model_name, n_requests=1, devices=devices)
 
-        row = measure_serving(
-            model_name="bert_base", n_requests=4, offered_rps=200.0,
-            buckets=(8,), rows=8, max_new_tokens=2, devices=devices,
-            model_overrides=dict(hidden_dim=32, depth=2, num_heads=2,
-                              mlp_dim=64, vocab_size=97, max_position=64))
-        assert "tokens_per_sec" not in row
-        assert row["recompiles_after_warmup"] == 0
-        assert row["contracts"]["pass"] is None
-        assert "skipped" in row["contracts"]
+
+def smoke_span_names(tmp_path, model_name, more_overrides=""):
+    from distributed_pytorch_training_tpu.serving.__main__ import main
+    from distributed_pytorch_training_tpu.telemetry.__main__ import (
+        read_stream,
+    )
+
+    assert main(["smoke", "--model", model_name, "--model-overrides",
+                 "hidden_dim=32,depth=2,num_heads=2,vocab_size=97"
+                 + more_overrides,
+                 "--buckets", "8,16", "--rows", "8", "--max-new-tokens", "2",
+                 "--prompt-len", "6", "--output-dir", str(tmp_path)]) == 0
+    events, bad = read_stream(str(tmp_path / "telemetry_rank0.jsonl"))
+    assert bad == 0
+    return [e["name"] for e in events if e.get("kind") == "span"]
+
+
+class TestSmokeInProcess:
+    def test_a_causal_lm_goes_through_the_scheduler(self, mesh8, tmp_path):
+        names = smoke_span_names(tmp_path, "gpt2_124m")
+        # three prompts over two rungs, each admitted by a prefill, every
+        # iteration fenced: the token server's stream, not a batch loop's
+        assert names.count("prefill") == 3
+        assert "sched_fence" in names and "slot_wait" in names
+        assert "decode" not in names
+
+    def test_a_model_without_a_cache_goes_through_the_forward_engine(
+            self, mesh8, tmp_path):
+        names = smoke_span_names(tmp_path, "bert_base", ",mlp_dim=64")
+        assert {"queue_wait", "prefill", "drain"} <= set(names)
+        assert not any(n.startswith("sched_") for n in names)
 
 
 class TestImageServing:
@@ -866,7 +918,7 @@ class TestServingCLI:
              "distributed_pytorch_training_tpu.serving", "smoke",
              "--model", "gpt2_124m",
              "--model-overrides",
-             "hidden_dim=32,depth=2,num_heads=2",
+             "hidden_dim=32,depth=2,num_heads=2,max_position=64",
              "--ckpt-dir", str(tmp_path / "ckpt"),
              "--buckets", "8,16", "--rows", "8", "--max-new-tokens", "2",
              "--output-dir", str(tmp_path / "out")],
@@ -880,4 +932,4 @@ class TestServingCLI:
         assert stream.exists()
         names = {json.loads(l).get("name")
                  for l in stream.read_text().splitlines() if l.strip()}
-        assert {"queue_wait", "prefill", "decode"} <= names
+        assert {"queue_wait", "prefill", "sched_fence"} <= names

@@ -684,7 +684,7 @@ class TestRouterMidPostDeath:
 
 @pytest.mark.slow
 def test_cli_bench_draft_and_shared_frac(tmp_path):
-    """`serving bench --continuous --draft ... --shared-frac 0.5` runs
+    """`serving bench --draft ... --shared-frac 0.5` runs
     the speculative + prefix-skip row end to end, reports accept_ratio
     and the warm/cold TTFT split, and exits 0 iff
     recompiles_after_warmup == 0 (the same hard gate as the plain arm)."""
@@ -696,7 +696,7 @@ def test_cli_bench_draft_and_shared_frac(tmp_path):
     out = subprocess.run(
         [sys.executable, "-m",
          "distributed_pytorch_training_tpu.serving", "bench",
-         "--continuous", "--json",
+         "--json",
          "--model", "gpt2_124m",
          "--model-overrides",
          "vocab_size=64,hidden_dim=32,depth=2,num_heads=2",

@@ -814,11 +814,14 @@ def test_tp_checkpoint_roundtrip_bitwise(mesh_tp, tmp_path):
 
 def test_serving_engine_tp_mesh_matches_1d(devices):
     """`--mesh data=2,model=2` serving: the served weights shard over the
-    model axis via the GSPMD rules and the generated greedy tokens match
-    the 1-D engine's (multi-chip serving of big models — the ISSUE-13
+    model axis via the GSPMD rules and the token server's greedy tokens
+    match the 1-D engine's (multi-chip serving of big models — the ISSUE-13
     motivation's serving half)."""
+    from distributed_pytorch_training_tpu.serving import (
+        ContinuousScheduler, RequestQueue,
+    )
     from distributed_pytorch_training_tpu.serving.build import (
-        build_serving_engine,
+        build_slot_engine,
     )
 
     overrides = dict(vocab_size=VOCAB, hidden_dim=32, depth=2, num_heads=2)
@@ -826,21 +829,24 @@ def test_serving_engine_tp_mesh_matches_1d(devices):
                np.arange(9, dtype=np.int32) % VOCAB]
 
     def tokens(mesh_spec):
-        engine, mesh = build_serving_engine(
+        engine, mesh = build_slot_engine(
             devices[:4], "gpt2_124m", buckets=(16,), rows=4,
-            max_new_tokens=4, model_overrides=overrides,
+            max_new_tokens=4, page_size=4, model_overrides=overrides,
             mesh_spec=mesh_spec)
         if mesh_spec:
             wte = engine._served["wte"]["embedding"]
             assert not wte.sharding.is_fully_replicated
-        return [r.tokens.tolist() for r in engine.serve_tokens(prompts)]
+        queue = RequestQueue(engine.config.buckets)
+        reqs = [queue.submit(p) for p in prompts]
+        ContinuousScheduler(engine, queue).drain()
+        return [r.result(timeout=300.0).tokens.tolist() for r in reqs]
 
     assert tokens("data=2,model=2") == tokens(None)
 
 
 def test_serving_engine_rejects_model_axis_without_rules(devices):
-    from distributed_pytorch_training_tpu.serving.engine import (
-        InferenceEngine, ServeConfig,
+    from distributed_pytorch_training_tpu.serving import (
+        PagedServeConfig, SlotEngine,
     )
 
     mesh = build_mesh(MeshSpec(data=2, model=2), devices=devices[:4])
@@ -848,9 +854,9 @@ def test_serving_engine_rejects_model_axis_without_rules(devices):
     params = model.init(jax.random.PRNGKey(0),
                         np.zeros((1, SEQ), np.int32), train=False)["params"]
     with pytest.raises(ValueError, match="partition rules"):
-        InferenceEngine(model, mesh,
-                        ServeConfig(buckets=(8,), rows=4,
-                                    max_new_tokens=2), params)
+        SlotEngine(model, mesh,
+                   PagedServeConfig(buckets=(8,), rows=4, max_new_tokens=2,
+                                    page_size=2), params)
 
 
 # --- ring attention on the TP mesh ------------------------------------------
